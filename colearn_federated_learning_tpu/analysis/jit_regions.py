@@ -12,8 +12,7 @@ a gate that must stay CPU-only and fast):
 - decorators: ``@jax.jit``, ``@jit``, ``@jax.pmap``, ``@pmap``,
   ``@partial(jax.jit, ...)`` / ``@functools.partial(jax.jit, ...)``;
 - call sites: ``jax.jit(f)``, ``jit(f)``, ``pmap(f)``,
-  ``shard_map(f, ...)`` (both ``jax.shard_map`` and the
-  ``utils/jax_compat`` shim import the same name) — where ``f`` is a
+  ``shard_map(f, ...)`` / ``jax.shard_map(f, ...)`` — where ``f`` is a
   lambda or a Name that resolves to a function defined in this file;
 - nesting: everything lexically inside a traced function is traced.
 """
@@ -31,7 +30,7 @@ def _call_traces(func: ast.expr) -> bool:
     if isinstance(func, ast.Name):
         return func.id in TRACER_NAMES
     if isinstance(func, ast.Attribute):
-        # jax.jit / jax.pmap / jax_compat.shard_map / jax.experimental...
+        # jax.jit / jax.pmap / jax.shard_map / jax.experimental...
         return func.attr in TRACER_NAMES
     return False
 

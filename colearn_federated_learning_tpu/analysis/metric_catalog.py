@@ -136,7 +136,11 @@ COUNTERS = (
     "fleetsim.bytes_up_saved_est_total",  # uplink-codec savings estimate
     # runtime observability plane (telemetry/runtime.py, telemetry/flight.py)
     "telemetry.compile_total",       # labeled {fn=<name>}: distinct XLA sigs
-    "telemetry.recompile_total",     # labeled {fn,reason=shape|dtype|structure}
+    # labeled {fn,reason=shape|dtype|structure|placement}
+    "telemetry.recompile_total",
+    # ops/attention.py: flash kernels traced, labeled
+    # {mode=mosaic|interpret} — which of the two lowerings a run used
+    "ops.flash_trace_total",
     "flight.dumps_total",            # flight-recorder dump writes
     "export.scrapes_total",          # /metrics + /snapshot.json hits
     "export.events_written_total",   # JSONL event-stream lines

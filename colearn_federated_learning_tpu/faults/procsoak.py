@@ -319,7 +319,7 @@ def run_proc_soak(
 
     env = dict(os.environ)
     env["PYTHONUNBUFFERED"] = "1"      # round records must stream, not batch
-    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"       # a CPU tool: many processes, no chip
 
     fleet = _Fleet(workdir, env)
     # Hard wall-clock backstop: a hung federation (the exact bug class
@@ -721,7 +721,7 @@ def _run_async_fleet(
 
     env = dict(os.environ)
     env["PYTHONUNBUFFERED"] = "1"
-    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"       # a CPU tool: many processes, no chip
     witness_dir = os.path.join(workdir, "lockwitness")
     if lock_witness:
         # Every fleet process runs its locks through
@@ -1386,7 +1386,7 @@ def _run_ckpt_fleet(
 
     env = dict(os.environ)
     env["PYTHONUNBUFFERED"] = "1"
-    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"       # a CPU tool: many processes, no chip
     # The coordinator's sharded-server placement needs >= tp_size XLA
     # host devices; match the test suite's 8-device CPU layout (workers
     # ignore the extra devices).

@@ -2,7 +2,7 @@
 
 Every round of the dense planes moves a full model delta per client, so
 the wire plane's best uplink reduction is whatever the codec squeezes
-out of O(model) floats (topk8: 12.62x, PERF.md §7).  LoRA (Hu et al.,
+out of O(model) floats (topk8: 12.62x, PERF.md §6).  LoRA (Hu et al.,
 arXiv 2106.09685 — pattern only) changes the OBJECT being federated:
 each targeted weight W keeps a frozen base and trains a rank-r pair
 ``B (m, r)`` / ``A (r, n)`` whose product is the update,

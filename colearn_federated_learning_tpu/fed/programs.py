@@ -30,7 +30,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from colearn_federated_learning_tpu.utils.jax_compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from colearn_federated_learning_tpu.fed import strategies
@@ -471,7 +470,7 @@ def _build_mesh_round(ln):
     x_spec = P(ax, None, ln.seq_axis) if ln.sp else P(ax)
     c_spec = P(ax) if ln.scaffold else P()
     sel_spec = P(ax) if ln.scaffold else P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(), P(), x_spec, P(ax), P(ax), P(ax), sel_spec,
@@ -480,7 +479,13 @@ def _build_mesh_round(ln):
         axis_names=manual_axes(ln),
         check_vma=False,
     )
-    return jax.jit(sharded, donate_argnums=donate_argnums(ln))
+    # The state goes back out placed as it came in.  Left to itself the
+    # partitioner may lay a replicated leaf over the auto ``model`` axis
+    # (MoE routers), and jit would compile the whole program again for
+    # the second round's new argument placement.
+    state_shardings = jax.tree.map(lambda l: l.sharding, ln.server_state)
+    return jax.jit(sharded, donate_argnums=donate_argnums(ln),
+                   out_shardings=(state_shardings, None, None))
 
 
 def build_round_fn(ln):
@@ -539,7 +544,7 @@ def build_client_eval_fn(ln):
 
     ax = ln.client_axis
     x_spec = P(ax, None, ln.seq_axis) if ln.sp else P(ax)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         vmapped, mesh=ln.mesh,
         in_specs=(P(), x_spec, P(ax), P(ax)),
         out_specs=(P(ax), P(ax)),
@@ -619,7 +624,7 @@ def build_personalized_eval_fn(ln, steps: int, lr: float):
         return jax.jit(vmapped)
     ax = ln.client_axis
     x_spec = P(ax, None, ln.seq_axis) if ln.sp else P(ax)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         vmapped, mesh=ln.mesh,
         in_specs=(P(), x_spec, P(ax), P(ax), P(ax)),
         out_specs=(P(ax), P(ax), P(ax)),
@@ -672,7 +677,7 @@ def build_similarity_fn(ln, steps: int):
 
     x_spec = (P(ax, None, ln.seq_axis) if ln.sp
               else P(ax))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         sim_body,
         mesh=ln.mesh,
         in_specs=(P(), x_spec, P(ax), P(ax), P(ax), P()),

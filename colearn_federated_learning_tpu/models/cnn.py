@@ -19,7 +19,7 @@ def space_to_depth(x: jnp.ndarray, block: int = 2) -> jnp.ndarray:
     (N, H, W, C) -> (N, H/b, W/b, C·b²).  MFU lever for the stem conv —
     CIFAR's 3 input channels waste the MXU's 128-lane contraction dim,
     while 12 channels over 4x fewer positions tile it 4x better with the
-    same receptive-field economics (PERF.md §1)."""
+    same receptive-field economics (PERF.md §5b)."""
     n, h, w, c = x.shape
     x = x.reshape(n, h // block, block, w // block, block, c)
     return x.transpose(0, 1, 3, 2, 4, 5).reshape(

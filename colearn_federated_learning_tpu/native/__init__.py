@@ -34,9 +34,10 @@ def load() -> Optional[ctypes.CDLL]:
 
             from colearn_federated_learning_tpu.native import build as build_mod
 
-            if build_mod.needs_build():
+            lib_path = build_mod.lib_path()
+            if not lib_path.exists():
                 build_mod.build()
-            lib = ctypes.CDLL(str(build_mod.LIB))
+            lib = ctypes.CDLL(str(lib_path))
             lib.cl_abi_version.restype = ctypes.c_int
             if lib.cl_abi_version() != build_mod.ABI_VERSION:
                 # The versioned filename makes this near-impossible (a new
@@ -45,10 +46,10 @@ def load() -> Optional[ctypes.CDLL]:
                 # re-opening the original path would hand back the stale
                 # handle this process already holds.
                 build_mod.build()
-                fresh = build_mod.LIB.with_name(
-                    f"{build_mod.LIB.stem}.pid{os.getpid()}.so"
+                fresh = lib_path.with_name(
+                    f"{lib_path.stem}.pid{os.getpid()}.so"
                 )
-                shutil.copy2(build_mod.LIB, fresh)
+                shutil.copy2(lib_path, fresh)
                 lib = ctypes.CDLL(str(fresh))
                 # The dlopen handle keeps the inode alive; unlink so the
                 # per-process copies never accumulate in _build.

@@ -33,34 +33,38 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from colearn_federated_learning_tpu import telemetry
+
 _NEG = -1e30
+
 
 @functools.lru_cache(maxsize=None)
 def _tpu_generation() -> int:
-    """TPU generation of the default backend's first device (0 = unknown
-    or not a TPU).  Drives the VMEM cap and block-size defaults: v4/v5/v6
-    carry ≥128 MB physical VMEM, v2/v3 far less."""
+    """TPU generation of the default backend's first device; 0 when the
+    default backend is not a TPU (the kernels then run interpreted).
+    Drives the VMEM cap and block-size defaults: v4/v5/v6 carry ≥128 MB
+    physical VMEM, v2/v3 far less.  A TPU whose ``device_kind`` names no
+    generation is an error: guessing would quietly hand it the small
+    configuration."""
     import re
 
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
+    if jax.default_backend() != "tpu":
         return 0
+    kind = jax.devices()[0].device_kind
     m = re.search(r"v(\d+)", kind.lower())
-    return int(m.group(1)) if m else 0
+    if m is None:
+        raise RuntimeError(
+            f"cannot read a TPU generation from device_kind {kind!r}; "
+            "ops/attention.py sizes its blocks and VMEM cap by generation"
+        )
+    return int(m.group(1))
 
 
 def _default_block() -> int:
     """512 on v4+ (and in interpret mode, where it only shortens the Python
-    loop); 128 on v2/v3 — or any TPU whose generation we cannot parse —
-    because the 512 configuration needs the raised VMEM cap that
-    ``_tpu_params`` only grants to known v4+ hardware."""
-    gen = _tpu_generation()
-    if gen >= 4:
-        return 512
-    if gen == 0 and jax.default_backend() != "tpu":
-        return 512
-    return 128
+    loop); 128 on v2/v3, because the 512 configuration needs the raised
+    VMEM cap that ``_tpu_params`` only grants to v4+ hardware."""
+    return 128 if 0 < _tpu_generation() < 4 else 512
 
 
 def _tpu_params():
@@ -152,6 +156,11 @@ def _blocks(q, k, v, kv_mask, block_q, block_k, interpret):
     Lk = k.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    # Which of the two ran is not visible in the result, so count it (at
+    # trace time): a chip run that traced an interpreted kernel is a fault.
+    telemetry.get_registry().counter(
+        "ops.flash_trace_total",
+        labels={"mode": "interpret" if interpret else "mosaic"}).inc()
     if block_q is None:
         block_q = _default_block()
     if block_k is None:

@@ -12,7 +12,6 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-from colearn_federated_learning_tpu.utils.jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -29,7 +28,7 @@ def make_sp_apply(model, mesh: Mesh, seq_axis: str = "seq") -> Callable:
     def fwd(params, ids):
         return model.apply({"params": params}, ids, train=False)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         fwd,
         mesh=mesh,
         in_specs=(P(), P(None, seq_axis)),
@@ -60,7 +59,7 @@ def make_sp_loss_grad(model, loss_fn: Callable, mesh: Mesh,
         grads = jax.tree.map(lambda g: jax.lax.pmean(g, seq_axis), grads)
         return loss, grads
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(None, seq_axis), P()),

@@ -9,9 +9,12 @@ answers the production questions the spans cannot:
   expected compile (``telemetry.compile_total{fn=...}``); every later
   NEW signature is a recompile, counted with an attributed reason —
   ``telemetry.recompile_total{fn=...,reason=shape|dtype|structure}`` —
-  so "the coordinator silently recompiles every round" is a visible
-  counter, and fleetsim's one-compile-per-sweep claim is a tested
-  invariant instead of a docstring.
+  and so is a call that repeats a signature yet grows the jitted
+  function's own executable cache (``reason=placement``: the arguments
+  moved to another sharding or device), so "the coordinator silently
+  recompiles every round" is a visible counter, and fleetsim's
+  one-compile-per-sweep claim is a tested invariant instead of a
+  docstring.
 - **What does one round cost?**  :func:`compiled_cost` runs XLA's own
   ``cost_analysis`` on the AOT-compiled executable (cached per
   signature, so asking twice is free) — the automated replacement for
@@ -97,7 +100,8 @@ class CompileTracker:
     ``tracker(...)`` forwards to the wrapped fn; attribute access
     (``.lower``, ``.trace`` …) passes through, so code holding the
     tracker can keep using the jit AOT surface.  ``compiles`` is the
-    number of distinct signatures — the executable count a correct
+    number of distinct signatures, plus every executable jit built for
+    a signature it had already seen — the executable count a correct
     static-shape pipeline holds at exactly 1 per sweep shape.
     """
 
@@ -108,31 +112,43 @@ class CompileTracker:
         self._registry = registry
         self._sigs: list = []
         self._sig_set: set = set()
+        self._jit_entries = 0
+        self._placement_recompiles = 0
         self._cost_cache: dict = {}
         self._lock = threading.Lock()
 
     # -- introspection --------------------------------------------------
     @property
     def compiles(self) -> int:
-        return len(self._sigs)
+        return len(self._sigs) + self._placement_recompiles
 
     @property
     def recompiles(self) -> int:
-        return max(0, len(self._sigs) - 1)
+        return max(0, self.compiles - 1)
 
     def _reg(self) -> MetricsRegistry:
         return self._registry if self._registry is not None else (
             get_registry())
 
     def _note(self, sig) -> None:
+        # A jitted fn counts its own executables; shape/dtype/structure
+        # are not all it keys them on.
+        cache_size = getattr(self._fn, "_cache_size", None)
+        entries = cache_size() if cache_size is not None else 0
         with self._lock:
+            grew = entries > self._jit_entries
+            self._jit_entries = max(entries, self._jit_entries)
             if sig in self._sig_set:
-                return
-            reason = None
-            if self._sigs:
-                reason = _recompile_reason(self._sigs, sig)
-            self._sig_set.add(sig)
-            self._sigs.append(sig)
+                if not grew:
+                    return
+                reason = "placement"
+                self._placement_recompiles += 1
+            else:
+                reason = None
+                if self._sigs:
+                    reason = _recompile_reason(self._sigs, sig)
+                self._sig_set.add(sig)
+                self._sigs.append(sig)
         reg = self._reg()
         reg.counter("telemetry.compile_total",
                     labels={"fn": self.name}).inc()
@@ -142,8 +158,10 @@ class CompileTracker:
 
     # -- call surface ---------------------------------------------------
     def __call__(self, *args, **kwargs):
-        self._note(abstract_signature(args, kwargs))
-        return self._fn(*args, **kwargs)
+        sig = abstract_signature(args, kwargs)
+        out = self._fn(*args, **kwargs)
+        self._note(sig)
+        return out
 
     def __getattr__(self, attr):
         return getattr(self._fn, attr)

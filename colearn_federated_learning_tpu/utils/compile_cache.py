@@ -1,21 +1,32 @@
-"""Host-keyed persistent XLA compile cache.
+"""Where the persistent XLA compile cache lives.
 
-One shared implementation of the scheme that previously lived as three
-diverging copies (tests/conftest.py, __graft_entry__.py,
-scripts/run_baseline_configs.py): persist compiled executables under a
-directory keyed by the host's CPU feature set — XLA:CPU AOT results
-loaded on a host with different features can SIGILL — so the first run
-pays the compile (a full-size BERT round program costs ~15 min on one
-CPU core) and every later run on the same host loads it in seconds.
+Every entry point (``cli.main``, ``bench.py``, ``chip_smoke.py``,
+``__graft_entry__.py``, the scripts, ``tests/conftest.py``) calls
+:func:`enable_compile_cache` once, before its first compile:
 
-Best-effort by design: cache setup must never break the caller, so every
-failure path degrades to "no persistent cache".
+- ``JAX_COMPILATION_CACHE_DIR`` set: the cache is placed from outside.
+  jax read the variable at import, so nothing is assigned here.
+- unset: ``<checkout>/.jax_cache/<host_key>`` — a fixed path, because
+  the path is part of the cache key and a directory that moves never
+  hits.  The per-host component is there because an XLA:CPU AOT result
+  loaded on a CPU with other features can SIGILL; it is derived from
+  ``/proc/cpuinfo`` and so is stable on a machine.  The path is handed
+  to jax through the variable itself, which also reaches child processes
+  that pass through no entry point of ours (test helpers); a jax that
+  was imported before the call has already read its environment, so it
+  is told directly as well.  Subcommands that never import jax (lint,
+  broker, sentinel) therefore do not pay for the import here.
 """
 
 from __future__ import annotations
 
 import hashlib
 import os
+import sys
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def host_key() -> str:
@@ -35,24 +46,13 @@ def host_key() -> str:
     return hashlib.sha1("".join(feats).encode()).hexdigest()[:10]
 
 
-def enable_host_keyed_cache(root: str, dirname: str = ".jax_cache",
-                            export_env: bool = False) -> str | None:
-    """Point jax's persistent compilation cache at <root>/<dirname>/<hostkey>.
-
-    ``export_env=True`` additionally exports JAX_COMPILATION_CACHE_DIR /
-    JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS so spawned subprocesses
-    (multi-process tests, CLI federation children) share the cache.
-    Returns the cache path, or None if setup failed.
-    """
-    try:
-        import jax
-
-        cache = os.path.join(root, dirname, host_key())
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        if export_env:
-            os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
-            os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "1.0"
-        return cache
-    except Exception:
-        return None
+def enable_compile_cache() -> str:
+    """Turn the persistent compile cache on; returns its directory."""
+    placed = os.environ.get(ENV_VAR)
+    if placed:
+        return placed
+    cache = os.path.join(_CHECKOUT, ".jax_cache", host_key())
+    os.environ[ENV_VAR] = cache
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_compilation_cache_dir", cache)
+    return cache

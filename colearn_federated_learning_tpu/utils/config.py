@@ -13,11 +13,12 @@ import dataclasses
 import warnings
 from typing import Optional
 
-# Measured dense/flash crossover (PERF.md §1b): at seq 128 the Pallas flash
-# kernel LOSES to dense (1.55 vs 2.12 rounds/sec on the config-#4 BERT) —
-# tiling overhead only pays for itself once the O(L^2) score matrix stops
-# fitting in VMEM, around L≈1-2k on v5-lite.  Below this length the guard
-# warns; dense is both faster and numerically identical.
+# Dense/flash crossover (PERF.md §5): at seq 128 the Pallas flash kernel
+# LOSES to dense on the config-#4 BERT (round 3, an earlier installation:
+# 1.55 vs 2.12 rounds/sec; first contact on today's: 1.34 vs 1.73 at
+# cohort 9) — tiling overhead only pays for itself once the O(L^2) score
+# matrix stops fitting in VMEM, around L≈1-2k on v5-lite.  Below this
+# length the guard warns; dense is both faster and numerically identical.
 FLASH_SEQ_CROSSOVER = 1024
 
 
@@ -34,9 +35,9 @@ def validate_experiment(config: "ExperimentConfig") -> None:
         warnings.warn(
             f"attn_impl='flash' at seq_len={m.seq_len}: dense attention is "
             f"measured FASTER below seq_len~{FLASH_SEQ_CROSSOVER} (PERF.md "
-            "§1b: 2.12 vs 1.55 rounds/sec at L=128 on the config-#4 BERT); "
-            "use attn_impl='dense' unless you are measuring the kernel "
-            "itself",
+            "§5: at L=128 on the config-#4 BERT flash ran at about three "
+            "quarters of dense's rounds/sec); use attn_impl='dense' unless "
+            "you are measuring the kernel itself",
             # Attribute to validate_experiment's caller (engine __init__):
             # the call depth from user code varies (direct construction vs
             # from_config), so no fixed level reaches the user frame — the
@@ -185,7 +186,7 @@ class ModelConfig:
     # trades recompute FLOPs for activation HBM — how deep models fit
     # long local training on a chip.
     remat: bool = False
-    # CNN MFU levers (PERF.md §1: the north-star CNN sits near 25% MFU
+    # CNN MFU levers (PERF.md §5b: the north-star CNN sits near 25% MFU
     # with an op-mix explanation — the 3-channel stem conv wastes the
     # MXU's 128-lane contraction dim and GroupNorm is bandwidth-bound):
     # - stem="space_to_depth": fold 2x2 spatial patches into channels

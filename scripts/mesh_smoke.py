@@ -19,15 +19,13 @@ than replicated.  One JSON row per mode plus a ``compare`` row with
 the ratio < 1 and gather-bytes-avoided > 0, so a regression that quietly
 re-replicates the server fails `colearn slo`.
 
-Usage (CPU):
+Usage (a CPU tool: the eight devices are virtual):
     JAX_PLATFORMS=cpu python scripts/mesh_smoke.py [--tp-size 4]
-    JAX_PLATFORMS=cpu python scripts/mesh_smoke.py --check-multichip
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -42,39 +40,6 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_MULTICHIP_KEYS = {"n_devices", "rc", "ok", "skipped", "tail"}
-
-
-def check_multichip_records() -> int:
-    """Schema-check the committed MULTICHIP_r*.json records (the TPU-pod
-    dryrun artifacts): every row carries exactly the keys downstream
-    tooling reads.  Returns a process exit code."""
-    paths = sorted(glob.glob(os.path.join(_REPO, "MULTICHIP_r*.json")))
-    if not paths:
-        print("FAIL: no MULTICHIP_r*.json records found", file=sys.stderr)
-        return 1
-    bad = 0
-    for p in paths:
-        try:
-            with open(p) as f:
-                row = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"FAIL: {os.path.basename(p)}: unreadable ({e})",
-                  file=sys.stderr)
-            bad += 1
-            continue
-        missing = _MULTICHIP_KEYS - set(row)
-        if missing:
-            print(f"FAIL: {os.path.basename(p)}: missing keys "
-                  f"{sorted(missing)}", file=sys.stderr)
-            bad += 1
-            continue
-        if not isinstance(row["n_devices"], int) or row["n_devices"] < 1:
-            print(f"FAIL: {os.path.basename(p)}: bad n_devices "
-                  f"{row['n_devices']!r}", file=sys.stderr)
-            bad += 1
-    print(f"multichip schema: {len(paths) - bad}/{len(paths)} records ok")
-    return 1 if bad else 0
 
 
 def bert_config(tp_size: int):
@@ -99,9 +64,11 @@ def run_smoke(tp_size: int, out_path: str) -> int:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    from colearn_federated_learning_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
 
+    enable_compile_cache()
     from colearn_federated_learning_tpu.comm.aggregation import (
         StreamingFolder,
     )
@@ -220,12 +187,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tp-size", type=int, default=4)
     ap.add_argument("--out", default=os.path.join(
         _REPO, "results", "mesh_bench.jsonl"))
-    ap.add_argument("--check-multichip", action="store_true",
-                    help="only schema-check the committed "
-                         "MULTICHIP_r*.json records and exit")
     args = ap.parse_args(argv)
-    if args.check_multichip:
-        return check_multichip_records()
     return run_smoke(args.tp_size, args.out)
 
 

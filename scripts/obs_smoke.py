@@ -578,6 +578,7 @@ def main(argv=None) -> int:
         return 2
     if not names:
         names = ["classic", "tree", "async", "learning"]
+    # A CPU tool: its many processes must not contend for one chip.
     env = dict(os.environ, PYTHONUNBUFFERED="1", JAX_PLATFORMS="cpu")
     failures: list[str] = []
 

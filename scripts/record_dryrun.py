@@ -6,7 +6,11 @@ Usage: python scripts/record_dryrun.py [N ...]   (default: 8 32)
 
 Writes results/dryrun_multichip.json: one record per N with ok/rc/wall
 seconds.  Subprocess per N because the virtual device count is fixed at
-backend init.
+backend init.  The record is of the VIRTUAL-device structure check, so
+each child is pinned to the CPU: on a machine with chips it neither
+takes one nor reports a chip result under this file's "cpu" label (for
+the real-chip dry run call ``dryrun_multichip`` directly there).  This
+parent never imports jax.
 """
 
 import json
@@ -20,8 +24,10 @@ OUT = os.path.join(REPO, "results", "dryrun_multichip.json")
 
 
 def run_one(n: int) -> dict:
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    # O0: the dry run checks sharding/collective structure, not codegen
+    # quality, and O0 halves XLA:CPU compile time.
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_backend_optimization_level=0")
     t0 = time.perf_counter()
     r = subprocess.run(
         [sys.executable, "-c",

@@ -27,10 +27,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from colearn_federated_learning_tpu.utils.jax_compat import (  # noqa: E402
-    shard_map,
-)
-
 from colearn_federated_learning_tpu.fed.engine import (  # noqa: E402
     FederatedLearner,
 )
@@ -48,8 +44,8 @@ print(pid, "MESHLAYOUT",
       ",".join(str(d.process_index) for d in mesh.devices.ravel()),
       flush=True)
 
-f = jax.jit(shard_map(lambda x: jax.lax.psum(x, "clients"),
-                      mesh=mesh, in_specs=P("clients"), out_specs=P()))
+f = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "clients"),
+                          mesh=mesh, in_specs=P("clients"), out_specs=P()))
 xs = jax.device_put(jnp.arange(8, dtype=jnp.float32),
                     NamedSharding(mesh, P("clients")))
 print(pid, "PSUM", float(np.asarray(f(xs).addressable_data(0))), flush=True)

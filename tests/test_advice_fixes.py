@@ -129,8 +129,20 @@ def test_round_timeout_is_shared_not_per_future():
                 w.stop()
 
 
-# ---- 5. versioned native library filename ---------------------------------
-def test_native_lib_filename_carries_abi_version():
+# ---- 5. native library filename keyed on ABI version + source content ----
+def test_native_lib_filename_carries_abi_version_and_source_digest(
+        monkeypatch, tmp_path):
     from colearn_federated_learning_tpu.native import build as build_mod
 
-    assert f"v{build_mod.ABI_VERSION}" in build_mod.LIB.name
+    name = build_mod.lib_path().name
+    assert f"v{build_mod.ABI_VERSION}_" in name
+    assert build_mod.lib_path().name == name          # deterministic
+    # A copied tree can carry a newer .so than its sources (git does not
+    # keep mtimes): only a change of CONTENT may select another binary,
+    # and it must.
+    edited = tmp_path / "gather.cpp"
+    edited.write_bytes(build_mod.SOURCES[0].read_bytes() + b"\n// edit\n")
+    monkeypatch.setattr(build_mod, "SOURCES",
+                        [edited, *build_mod.SOURCES[1:]])
+    assert build_mod.lib_path().name != name
+    assert not build_mod.lib_path().exists()

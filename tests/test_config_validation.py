@@ -1,7 +1,7 @@
 """Config-validation guards (utils/config.validate_experiment).
 
 Pins VERDICT r4 weak #5: ``attn_impl="flash"`` below the measured
-dense/flash crossover (~L=1k, PERF.md §1b) is a user footgun — dense is
+dense/flash crossover (~L=1k, PERF.md §5) is a user footgun — dense is
 faster there — so construction warns.  The warning must fire exactly for
 the below-crossover case and stay silent for dense and for long sequences,
 and it must be a WARNING, not an error: the combination executes correctly

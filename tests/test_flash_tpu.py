@@ -2,9 +2,10 @@
 
 The main suite runs on a virtual CPU mesh (conftest forces the platform),
 where Pallas runs in interpret mode — these tests only execute when the
-process actually sits on a TPU, i.e. when run OUTSIDE the suite:
+process actually sits on a TPU, i.e. when run OUTSIDE the suite, on the
+chip machine:
 
-    JAX_PLATFORMS='' python -m pytest tests/test_flash_tpu.py -p no:cacheprovider --noconftest
+    python -m pytest tests/test_flash_tpu.py -p no:cacheprovider --noconftest
 
 They validate that the (8, 128)-tiled kernels compile and match the dense
 oracle forward AND backward on hardware.
@@ -74,3 +75,42 @@ def test_flash_causal_bf16_on_tpu():
                           v.astype(jnp.float32), causal=True)
     np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
                                np.asarray(ref), rtol=2e-2, atol=2e-2)
+
+
+def test_flash_long_bf16_forward_backward_on_tpu():
+    """L = 2048, D = 64, bf16 with a padding mask: past
+    ``FLASH_SEQ_CROSSOVER``, where the kernel is meant to be used — four
+    k-blocks per row at the default 512 block, head dim below the 128
+    lanes.  Errors are taken against the largest oracle entry: bf16
+    operands carry 2^-8 relative rounding into every product, so
+    near-zero entries have no meaningful relative error of their own."""
+    from colearn_federated_learning_tpu.ops.attention import flash_attention
+    from colearn_federated_learning_tpu.parallel.ring import dense_attention
+
+    q, k, v, mask = _rand(jax.random.PRNGKey(2), B=2, L=2048, H=4, D=64)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) ** 2)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask, interpret=False)
+
+    def dense(q, k, v):
+        return dense_attention(q, k, v, mask)
+
+    def rel_err(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        return np.abs(a - b).max() / np.abs(b).max()
+
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        ref = dense(*f32)
+        gd = jax.grad(loss(dense), argnums=(0, 1, 2))(*f32)
+    out = jax.jit(flash)(q, k, v)
+    gf = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert rel_err(out, ref) < 2e-2
+    for a, b in zip(gf, gd):
+        assert rel_err(a, b) < 2e-2

@@ -45,6 +45,23 @@ def test_sharded_full_participation_matches_vmap(mesh8):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5)
 
 
+def test_sharded_round_program_compiles_once(mesh8):
+    """The mesh round program is built once: the initial server state is
+    placed where the program leaves it (replicated over the mesh), so
+    round 1 does not compile a second executable for a new argument
+    placement — on the chip that was a second full compile, invisible to
+    a shape/dtype signature."""
+    learner = FederatedLearner(tiny_config(rounds=3), mesh=mesh8)
+    hist = learner.fit(rounds=3)
+    assert learner._round_fn.compiles == 1
+    assert learner._round_fn._cache_size() == 1
+    assert all("recompiles" not in rec for rec in hist)
+    # Each device received only its own clients' block of the data.
+    x = learner._device_data[0]
+    assert {s.data.shape[0] for s in x.addressable_shards} == {
+        x.shape[0] // 8}
+
+
 def test_sharded_privacy_path_runs(mesh8):
     cfg = tiny_config(rounds=2, dp_clip=1.0, dp_noise_multiplier=0.1,
                       secure_agg=True)

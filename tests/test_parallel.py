@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from colearn_federated_learning_tpu.utils.jax_compat import shard_map
 
 from colearn_federated_learning_tpu.parallel import factor_devices, make_mesh
 from colearn_federated_learning_tpu.parallel.ring import (
@@ -56,7 +55,7 @@ def _rand_qkvm(key, B, L, H, D, frac_pad=0.25):
 
 
 def _run_ring(mesh, q, k, v, mask, **kw):
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, m: ring_attention(q, k, v, m, axis_name="seq", **kw),
         mesh=mesh,
         in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq"), P(None, "seq")),
@@ -89,7 +88,7 @@ def test_ring_causal_matches_dense(cpu_devices):
 def test_ring_no_mask(cpu_devices):
     mesh = _seq_mesh(cpu_devices, 4)
     q, k, v, _ = _rand_qkvm(jax.random.PRNGKey(2), B=1, L=16, H=1, D=4)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name="seq"),
         mesh=mesh,
         in_specs=(P(None, "seq"),) * 3,

@@ -69,6 +69,29 @@ def test_compile_tracker_attributes_recompile_reasons():
     assert f.recompiles == 2
 
 
+def test_compile_tracker_sees_placement_recompiles(mesh8):
+    # Same shape, dtype and structure — but jit keys its executables on
+    # argument placement too, and builds a second one when the operand
+    # arrives laid over a mesh.  A signature alone cannot see that.
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    reg = fresh_registry()
+    f = runtime.CompileTracker(jax.jit(lambda x: x + 1), name="t",
+                               registry=reg)
+    x = jnp.ones((8,))
+    f(x)
+    f(x)
+    assert (f.compiles, f.recompiles) == (1, 0)
+    f(jax.device_put(x, NamedSharding(mesh8, P("clients"))))
+    assert (f.compiles, f.recompiles) == (2, 1)
+    snap = reg.snapshot()
+    assert snap["telemetry.recompile_total{fn=t,reason=placement}"] == 1
+    # A plain callable has no executable cache to consult.
+    g = runtime.CompileTracker(lambda x: x, name="g", registry=reg)
+    g(x), g(x)
+    assert g.compiles == 1
+
+
 def test_compile_tracker_forwards_calls_and_attrs():
     f = runtime.CompileTracker(jax.jit(lambda x: x + 1), name="t",
                                registry=fresh_registry())

@@ -15,24 +15,12 @@ from colearn_federated_learning_tpu.fed.engine import FederatedLearner
 from colearn_federated_learning_tpu.models import registry as model_registry
 from colearn_federated_learning_tpu.parallel import tp as tp_lib
 from colearn_federated_learning_tpu.parallel.mesh import make_mesh
-from colearn_federated_learning_tpu.utils.jax_compat import (
-    HAS_NATIVE_SHARD_MAP,
-)
 from colearn_federated_learning_tpu.utils.config import (
     DataConfig,
     ExperimentConfig,
     FedConfig,
     ModelConfig,
     RunConfig,
-)
-
-
-# Running a round with a GSPMD-auto ``model`` axis (auto != {}) aborts the
-# interpreter at the C++ level under jax<0.6 experimental shard_map on the
-# CPU backend; spec/build-only tests are unaffected.
-requires_native_shard_map = pytest.mark.skipif(
-    not HAS_NATIVE_SHARD_MAP,
-    reason="partial-manual shard_map (auto model axis) aborts under jax<0.6",
 )
 
 
@@ -103,7 +91,6 @@ def test_indivisible_dims_replicate():
     assert specs["TransformerBlock_0"]["Dense_0"]["kernel"] == P()
 
 
-@requires_native_shard_map
 def test_tp_round_matches_vmap(cpu_devices):
     cfg = _bert_cfg()
     mesh = make_mesh(("clients", "model"), (4, 2), devices=cpu_devices[:8])
@@ -117,6 +104,9 @@ def test_tp_round_matches_vmap(cpu_devices):
     assert m_tp["completed"] == m_ref["completed"] == 8
     np.testing.assert_allclose(m_tp["train_loss"], m_ref["train_loss"],
                                rtol=1e-4)
+    # The second round reused the first round's executable: the state
+    # came back placed as it went in.
+    assert tp_learner._round_fn.compiles == 1 and "recompiles" not in m_tp
 
     # Params: TP-sharded leaves are genuinely distributed ...
     q = tp_learner.server_state.params["TransformerBlock_0"][
@@ -141,7 +131,6 @@ def test_tp_round_matches_vmap(cpu_devices):
     assert abs(lt - lr_) < 1e-4 and abs(at - ar_) < 1e-6
 
 
-@requires_native_shard_map
 def test_tp_composes_with_privacy(cpu_devices):
     # DP clip+noise and secure-agg masks run per-client INSIDE the manual
     # clients axis while params stay TP-sharded — the composition the
@@ -154,7 +143,6 @@ def test_tp_composes_with_privacy(cpu_devices):
     assert np.isfinite(m["train_loss"])
 
 
-@requires_native_shard_map
 def test_dp_sp_tp_composition(cpu_devices):
     # The full 3-D mesh: manual clients (FedAvg psum) x manual seq (ring
     # attention) x auto model (TP) — one jit program, same trajectory as
@@ -196,7 +184,6 @@ def test_from_config_builds_tp_mesh(cpu_devices):
     assert learner.mesh.shape["clients"] == len(jax.devices()) // 2
 
 
-@requires_native_shard_map
 def test_tp_checkpoint_roundtrip(cpu_devices, tmp_path):
     # Checkpoint/resume with TP-sharded server state: the restore targets
     # the LIVE sharded arrays, so shardings must survive the roundtrip.

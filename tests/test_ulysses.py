@@ -23,12 +23,11 @@ from colearn_federated_learning_tpu.utils.config import (
     ModelConfig,
     RunConfig,
 )
-from colearn_federated_learning_tpu.utils.jax_compat import shard_map
 
 
 def _run_sharded(fn, mesh, args, specs, out_spec):
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=specs,
-                             out_specs=out_spec, check_vma=False))(*args)
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                                 out_specs=out_spec, check_vma=False))(*args)
 
 
 @pytest.mark.parametrize("causal", [False, True])
