@@ -138,6 +138,13 @@ COUNTERS = (
     "telemetry.compile_total",       # labeled {fn=<name>}: distinct XLA sigs
     # labeled {fn,reason=shape|dtype|structure|placement}
     "telemetry.recompile_total",
+    # labeled {fn}: seconds the calls that added an executable blocked
+    # (trace + build or cache load + enqueue); a float-valued counter
+    "telemetry.compile_seconds",
+    # labeled {fn}: jax's persistent-cache events during a tracked call —
+    # the program was loaded / was built and written
+    "telemetry.cache_hit_total",
+    "telemetry.cache_miss_total",
     # ops/attention.py: flash kernels traced, labeled
     # {mode=mosaic|interpret} — which of the two lowerings a run used
     "ops.flash_trace_total",
@@ -152,6 +159,7 @@ COUNTERS = (
 # Gauges -------------------------------------------------------------------
 GAUGES = (
     "engine.h2d_transfer_s",
+    "engine.from_config_s",          # the from_config span (learner build)
     "local.steps_per_round",
     "fleetsim.devices",
     "fleetsim.chunk_size",

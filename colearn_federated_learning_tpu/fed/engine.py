@@ -138,6 +138,22 @@ class FederatedLearner:
         dataset's train split, overriding ``config.data.partition`` —
         callers that already know exactly who owns which rows (clustered
         FL preserving member shards) inject them here."""
+        # The process-wide tracer: span() always times, so the phase
+        # durations reach the metrics JSONL whatever happens, and always
+        # annotates an open jax profile; spans are kept only inside a
+        # recording window, which fit() opens (telemetry/lifecycle.py).
+        self.tracer = telemetry.get_tracer()
+        self._span_owner = object()      # whose spans the tracer's buffer holds
+        self.last_trace_path: Optional[str] = None
+        # What building a learner compiles, it compiles eagerly (model
+        # init, placement): cache hits and misses count under this name.
+        with self.tracer.span("from_config") as sp, telemetry.tracked_call(
+                "engine.from_config"):
+            self._build(config, dataset, mesh, partitions)
+        telemetry.get_registry().gauge("engine.from_config_s").set(
+            sp.duration_s)
+
+    def _build(self, config, dataset, mesh, partitions):
         self.config = config
         self.mesh = mesh
         c = config
@@ -359,7 +375,7 @@ class FederatedLearner:
                     f"cohort_size={self.cohort_size} is not a multiple of the "
                     f"{d}-way client axis; using {adjusted} "
                     f"({self.cohort_per_device}/device)",
-                    stacklevel=2,
+                    stacklevel=3,      # the caller of FederatedLearner(...)
                 )
             self.cohort_size = adjusted
         if (self.robust and c.fed.aggregator in ("trimmed_mean", "krum")
@@ -435,21 +451,17 @@ class FederatedLearner:
         )
         self.base_key = prng.experiment_key(c.run.seed)
         # CompileTracker fingerprints every call's abstract signature: the
-        # expected first compile lands in telemetry.compile_total, any
-        # LATER new signature is a recompile with an attributed reason
+        # expected first compile lands in telemetry.compile_total with the
+        # seconds it blocked in telemetry.compile_seconds, any LATER new
+        # signature is a recompile with an attributed reason
         # (telemetry.recompile_total{fn,reason}) — a coordinator silently
         # recompiling every round becomes a visible counter + round-record
         # field.  Attribute access (.lower, for the perf script's AOT
         # path) passes through to the jitted fn.
         self._round_fn = telemetry.CompileTracker(
             programs.build_round_fn(self), name="engine.round")
-        self._eval_fn = self._build_eval_fn()
-        self._flops_per_round: Optional[float] = None
-        # Recording stays off until fit() opens a trace window (trace_dir);
-        # span() still yields timed spans either way, so run_round's phase
-        # durations are always available to the metrics JSONL.
-        self.tracer = telemetry.Tracer(process="engine", enabled=False)
-        self.last_trace_path: Optional[str] = None
+        self._eval_fn = telemetry.CompileTracker(
+            self._build_eval_fn(), name="engine.eval")
         self._device_data = self._place_data()
         self.history: list[dict] = []
         self._ckpt = None
@@ -592,6 +604,13 @@ class FederatedLearner:
         is a per-round host⇄device exchange by design.)  Call
         :meth:`finalize_history` after a ``sync=False`` loop to materialize
         the floats."""
+        out, phases = self._dispatch_round(sync)
+        with self.tracer.span("bookkeeping", round=len(self.history)):
+            return self._record_round(out, phases)
+
+    def _dispatch_round(self, sync: bool) -> tuple[dict, tuple]:
+        """Enqueue the round program and read its metrics: the round's
+        metrics and the spans its record takes its phase fields from."""
         r = len(self.history)
         if self.scaffold:
             # Gather the cohort's variates from the host store; scatter the
@@ -608,16 +627,15 @@ class FederatedLearner:
                         lambda l: jax.device_put(jnp.asarray(l), sh), c_cohort
                     )
         else:
-            # The non-scaffold cohort is sampled INSIDE the jit program, so
-            # its cost is part of the fused client_update span.
+            # The non-scaffold cohort is sampled INSIDE the jit program.
             sel, rows, sel_dev, c_cohort = None, None, None, None
             sample_sp = None
         # The round program is ONE fused jit call (sample → local SGD →
-        # aggregate → server update); phases inside it can't be split
-        # without extra device barriers, so it gets a single span — made
-        # honest by a barrier only while a trace window is open (blocking
-        # every round would serialise the sync=False pipeline).
-        with self.tracer.span("client_update", round=r,
+        # aggregate → server update), and the call returns when it is
+        # enqueued: the span is what the host spends getting a round onto
+        # the device, recording or not.  When the device ran it is in the
+        # jax profile, where this span is an annotation on the same clock.
+        with self.tracer.span("enqueue", round=r,
                               cohort=self.cohort_size) as update_sp:
             self.server_state, metrics, new_c = self._round_fn(
                 self.server_state,
@@ -628,8 +646,6 @@ class FederatedLearner:
                 c_cohort,
                 self._dp_clip,
             )
-            if self.tracer.enabled:
-                jax.block_until_ready(self.server_state.params)
         if self.adaptive_clip:
             # Feed the adapted clip into the next round as a device scalar
             # (no host round-trip; sync=False rounds keep pipelining).
@@ -651,7 +667,13 @@ class FederatedLearner:
                        for k, v in jax.device_get(metrics).items()}
             else:
                 out = dict(metrics)      # device scalars; sync deferred
-        out["round"] = r
+        return out, (update_sp, sync_sp, sample_sp)
+
+    def _record_round(self, out: dict, phases: tuple) -> dict:
+        """Make the round's metrics its record and append it to the
+        history; the caller holds the ``bookkeeping`` span open."""
+        update_sp, sync_sp, sample_sp = phases
+        out["round"] = len(self.history)
         out["phase_update_s"] = update_sp.duration_s
         out["phase_sync_s"] = sync_sp.duration_s
         if sample_sp is not None:
@@ -898,73 +920,64 @@ class FederatedLearner:
         run."""
         if rounds is None:
             rounds = max(0, self.config.fed.rounds - len(self.history))
-        run = self.config.run
-        eval_every = max(1, run.eval_every)
-        log_every = max(1, run.log_every)
-        ckpt_every = max(0, run.checkpoint_every)
-        want_ckpt = bool(run.checkpoint_dir)
         last_round = len(self.history) + rounds - 1  # fit() may be called again
-        telem = telemetry.RoundTelemetry(run, self.tracer)
-        # FLOPs capture is opt-in with the trace window (the AOT compile
-        # behind cost_analysis does not share the jit cache, so it is a
-        # real one-time cost) and cached across fit() calls.
-        if telem.tracing and self._flops_per_round is None:
-            self._flops_per_round = self.round_cost_analysis().get(
-                "flops_per_round")
+        telem = telemetry.RoundTelemetry(self.config.run, self.tracer,
+                                         owner=self._span_owner)
+        # The spans tile the call: what the host does between two
+        # operations of the device has a name (PERF.md section 3).
         try:
-            for _ in range(rounds):
-                t0 = time.perf_counter()
-                telem.before_round(len(self.history))
-                with self.tracer.span("round", round=len(self.history)):
-                    rec = self.run_round()
-                    if telem.profiling and not self.tracer.enabled:
-                        # The jax trace window must contain the round's
-                        # device work — only synchronise while actually
-                        # profiling (blocking every round would serialise
-                        # the async dispatch pipeline; the span tracer
-                        # already put up its own barrier in run_round).
-                        jax.block_until_ready(self.server_state.params)
-                    telem.after_round(rec["round"])
-                    rec["round_time_s"] = time.perf_counter() - t0
-                    # Both keys appear only when their source exists —
-                    # memory_stats() is empty on CPU, flops capture is
-                    # trace-window opt-in — so default-run records stay
-                    # byte-identical (tested layout contract).
-                    stats = telemetry.sample_device_memory()
-                    if stats.get("bytes_in_use"):
-                        rec["hbm_used_gb"] = round(
-                            stats["bytes_in_use"] / 2**30, 3)
-                    if self._flops_per_round:
-                        rec["flops_per_round"] = self._flops_per_round
-                    if (rec["round"] % eval_every == 0
-                            or rec["round"] == last_round):
-                        with self.tracer.span("evaluate") as ev_sp:
-                            loss, acc = self.evaluate()
-                        rec["eval_loss"], rec["eval_acc"] = loss, acc
-                        rec["phase_eval_s"] = ev_sp.duration_s
-                    if log_fn is not None and (
-                        rec["round"] % log_every == 0
-                        or rec["round"] == last_round
-                    ):
-                        log_fn(rec)
-                    # With a checkpoint_dir, the final round ALWAYS
-                    # checkpoints even when no periodic cadence is
-                    # configured, so --resume works.
-                    if want_ckpt and (
-                        (ckpt_every and (rec["round"] + 1) % ckpt_every == 0)
-                        or rec["round"] == last_round
-                    ):
-                        with self.tracer.span("checkpoint") as ck_sp:
-                            self.save_checkpoint()
-                        rec["phase_checkpoint_s"] = ck_sp.duration_s
-                telemetry.get_registry().histogram(
-                    "engine.round_time_s").observe(rec["round_time_s"])
-                # end_round AFTER the round span closed — an early window
-                # flush must include the final traced round.
-                telem.end_round(rec["round"])
+            with self.tracer.span("fit", rounds=rounds):
+                for _ in range(rounds):
+                    t0 = time.perf_counter()
+                    telem.before_round(len(self.history))
+                    with self.tracer.span("round", round=len(self.history)):
+                        rec = self._fit_round(t0, telem, last_round, log_fn)
+                    # end_round AFTER the round span closed — an early
+                    # window flush must include the final traced round.
+                    telem.end_round(rec["round"])
         finally:
             # An exception mid-window (eval/log/ckpt) must not leave the
             # process-global jax profiler trace running, and whatever spans
             # were recorded still reach disk.
             self.last_trace_path = telem.close()
         return self.history
+
+    def _fit_round(self, t0: float, telem, last_round: int, log_fn) -> dict:
+        """One round of ``fit()``: ``run_round`` with the loop's own
+        bookkeeping under the same span, then evaluation, logging and
+        checkpoint where their cadence says so."""
+        run = self.config.run
+        eval_every = max(1, run.eval_every)
+        log_every = max(1, run.log_every)
+        ckpt_every = max(0, run.checkpoint_every)
+        out, phases = self._dispatch_round(sync=True)
+        r = len(self.history)
+        with self.tracer.span("bookkeeping", round=r):
+            rec = self._record_round(out, phases)
+            telem.after_round(r)
+            rec["round_time_s"] = time.perf_counter() - t0
+            # The key appears only when its source exists —
+            # memory_stats() is empty on CPU — so default-run records
+            # stay byte-identical (tested layout contract).
+            stats = telemetry.sample_device_memory()
+            if stats.get("bytes_in_use"):
+                rec["hbm_used_gb"] = round(stats["bytes_in_use"] / 2**30, 3)
+            telemetry.get_registry().histogram(
+                "engine.round_time_s").observe(rec["round_time_s"])
+        if r % eval_every == 0 or r == last_round:
+            with self.tracer.span("evaluate", round=r) as ev_sp:
+                loss, acc = self.evaluate()
+            rec["eval_loss"], rec["eval_acc"] = loss, acc
+            rec["phase_eval_s"] = ev_sp.duration_s
+        if log_fn is not None and (r % log_every == 0 or r == last_round):
+            with self.tracer.span("log", round=r):
+                log_fn(rec)
+        # With a checkpoint_dir, the final round ALWAYS checkpoints even
+        # when no periodic cadence is configured, so --resume works.
+        if run.checkpoint_dir and (
+            (ckpt_every and (r + 1) % ckpt_every == 0) or r == last_round
+        ):
+            with self.tracer.span("checkpoint", round=r) as ck_sp:
+                self.save_checkpoint()
+            rec["phase_checkpoint_s"] = ck_sp.duration_s
+        return rec

@@ -9,7 +9,7 @@ Public surface:
 - :mod:`.export` — Chrome-trace/Perfetto JSON writer/loader and the
   ``colearn trace-summary`` text breakdown;
 - :class:`RoundTelemetry` — the per-round lifecycle driver shared by the
-  span tracer window and the jax profiler window;
+  span tracer window and the jax profiler window (:class:`RoundProfiler`);
 - :mod:`.runtime` — XLA introspection (:class:`CompileTracker` recompile
   detection, AOT cost analysis, HBM gauges) and live export (Prometheus
   endpoint, JSONL event stream, ``colearn top`` renderer);
@@ -48,6 +48,7 @@ from colearn_federated_learning_tpu.telemetry.export import (  # noqa: F401
     write_tracer,
 )
 from colearn_federated_learning_tpu.telemetry.lifecycle import (  # noqa: F401
+    RoundProfiler,
     RoundTelemetry,
 )
 from colearn_federated_learning_tpu.telemetry.runtime import (  # noqa: F401
@@ -57,6 +58,7 @@ from colearn_federated_learning_tpu.telemetry.runtime import (  # noqa: F401
     compiled_cost,
     prometheus_text,
     sample_device_memory,
+    tracked_call,
 )
 from colearn_federated_learning_tpu.telemetry.health import (  # noqa: F401
     DeviceHealth,

@@ -14,7 +14,14 @@ answers the production questions the spans cannot:
   moved to another sharding or device), so "the coordinator silently
   recompiles every round" is a visible counter, and fleetsim's
   one-compile-per-sweep claim is a tested invariant instead of a
-  docstring.
+  docstring.  A call that adds an executable also adds the seconds it
+  blocked to ``telemetry.compile_seconds{fn=...}``, and while a tracked
+  call runs, jax's own persistent-cache events on that thread count as
+  ``telemetry.cache_hit_total{fn=...}`` (the program was loaded) or
+  ``telemetry.cache_miss_total{fn=...}`` (it was built and written), so
+  set-up time has a name: which program, built or loaded, how long.
+  :func:`tracked_call` gives the same attribution to a block that
+  compiles eagerly (``engine.from_config``).
 - **What does one round cost?**  :func:`compiled_cost` runs XLA's own
   ``cost_analysis`` on the AOT-compiled executable (cached per
   signature, so asking twice is free) — the automated replacement for
@@ -35,6 +42,8 @@ client, no agent, no thread unless an exporter is explicitly started.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import os
 import re
@@ -54,6 +63,7 @@ __all__ = [
     "compiled_cost",
     "prometheus_text",
     "sample_device_memory",
+    "tracked_call",
 ]
 
 
@@ -93,6 +103,48 @@ def _recompile_reason(prev_sigs, sig) -> str:
     return "shape"
 
 
+# ------------------------------------------------- cache hits and misses --
+# jax reports its persistent compilation cache through process-wide
+# monitoring events that say nothing of which program they concern.  The
+# tracked call in progress on the thread that compiles says it.
+_tracked = threading.local()
+
+
+def _on_cache_event(event: str, **_) -> None:
+    call = getattr(_tracked, "call", None)
+    if call is None:
+        return                      # not in a call of the program
+    fn, registry = call
+    reg = registry if registry is not None else get_registry()
+    if event == "/jax/compilation_cache/cache_hits":
+        reg.counter("telemetry.cache_hit_total", labels={"fn": fn}).inc()
+    elif event == "/jax/compilation_cache/cache_misses":
+        reg.counter("telemetry.cache_miss_total", labels={"fn": fn}).inc()
+
+
+@functools.cache
+def _listen_for_cache_events() -> None:
+    """One listener for the life of the process, registered by the first
+    tracked call."""
+    from jax import monitoring
+
+    monitoring.register_event_listener(_on_cache_event)
+
+
+@contextlib.contextmanager
+def tracked_call(fn: str, registry: Optional[MetricsRegistry] = None):
+    """While the block runs on this thread, jax's persistent-cache hits and
+    misses are counted under ``fn``.  Events outside any such block (a
+    caller's own programs) are not this program's and are not counted."""
+    _listen_for_cache_events()
+    previous = getattr(_tracked, "call", None)
+    _tracked.call = (fn, registry)
+    try:
+        yield
+    finally:
+        _tracked.call = previous
+
+
 class CompileTracker:
     """Transparent wrapper around a (jitted) callable that counts the
     distinct call signatures it has seen.
@@ -130,7 +182,8 @@ class CompileTracker:
         return self._registry if self._registry is not None else (
             get_registry())
 
-    def _note(self, sig) -> None:
+    def _note(self, sig) -> bool:
+        """Count the call just made; True if it added an executable."""
         # A jitted fn counts its own executables; shape/dtype/structure
         # are not all it keys them on.
         cache_size = getattr(self._fn, "_cache_size", None)
@@ -140,7 +193,7 @@ class CompileTracker:
             self._jit_entries = max(entries, self._jit_entries)
             if sig in self._sig_set:
                 if not grew:
-                    return
+                    return False
                 reason = "placement"
                 self._placement_recompiles += 1
             else:
@@ -155,12 +208,20 @@ class CompileTracker:
         if reason is not None:
             reg.counter("telemetry.recompile_total",
                         labels={"fn": self.name, "reason": reason}).inc()
+        return True
 
     # -- call surface ---------------------------------------------------
     def __call__(self, *args, **kwargs):
         sig = abstract_signature(args, kwargs)
-        out = self._fn(*args, **kwargs)
-        self._note(sig)
+        with tracked_call(self.name, self._registry):
+            t0 = time.perf_counter()
+            out = self._fn(*args, **kwargs)
+            blocked_s = time.perf_counter() - t0
+        if self._note(sig):
+            # Tracing, building or loading, and the enqueue: what the
+            # caller waited for this executable.
+            self._reg().counter("telemetry.compile_seconds",
+                                labels={"fn": self.name}).inc(blocked_s)
         return out
 
     def __getattr__(self, attr):

@@ -6,6 +6,12 @@ time plus a 2-round ``jax.profiler`` window.  This tracer answers *where*
 a round spends its time: nested spans with monotonic-clock durations and
 wall-clock anchors, cheap enough to leave on in production paths.
 
+Where jax is installed every span is also a ``jax.profiler``
+``TraceAnnotation`` of the same name, so in any jax profile the spans sit
+on the ``/host:CPU`` plane on the profiler's own clock, beside the device's
+operations (≈ 0.3 µs a span while no profiler session is open).  The
+import is made on first use; without jax the spans are timed all the same.
+
 Design points:
 
 - ``tracer.span("aggregate", round=3)`` is a context manager; nesting is
@@ -24,12 +30,14 @@ Design points:
   metadata and the coordinator adopts them into its own buffer.
 
 Wall-clock (``time.time``) anchors position spans on a shared timeline
-across processes on one machine; durations always come from
-``time.perf_counter`` so individual spans are immune to clock steps.
+across processes on one machine; start, end and duration always come from
+``time.perf_counter_ns`` so individual spans are immune to clock steps.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import itertools
 import os
 import threading
@@ -44,6 +52,23 @@ _id_counter = itertools.count(1)
 _id_lock = threading.Lock()
 
 
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, or a context manager that does
+    nothing where jax is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return contextlib.nullcontext
+    return TraceAnnotation
+
+
+def profiler_session_open() -> bool:
+    """Whether a jax profiler session is recording in this process."""
+    is_enabled = getattr(_annotation(), "is_enabled", None)
+    return bool(is_enabled is not None and is_enabled())
+
+
 def new_id() -> str:
     """Process-unique 64-bit-style hex id (pid-salted so ids minted by a
     coordinator and an in-process loopback worker never collide)."""
@@ -55,7 +80,9 @@ def new_id() -> str:
 @dataclass
 class Span:
     """One timed operation.  ``t_wall`` anchors the span on the shared
-    wall-clock timeline; ``duration_s`` is monotonic-clock elapsed."""
+    wall-clock timeline; ``start_ns``/``end_ns`` are this process's
+    monotonic clock (``time.perf_counter_ns``) and ``duration_s`` their
+    difference."""
 
     name: str
     trace_id: str
@@ -64,16 +91,18 @@ class Span:
     process: str = "main"
     t_wall: float = 0.0                  # epoch seconds at start
     attrs: dict = field(default_factory=dict)
-    _t0: float = 0.0                     # perf_counter at start
-    _t1: Optional[float] = None          # perf_counter at end
+    start_ns: int = 0                    # perf_counter_ns at start
+    end_ns: Optional[int] = None         # perf_counter_ns at end
 
     @property
     def ended(self) -> bool:
-        return self._t1 is not None
+        return self.end_ns is not None
 
     @property
     def duration_s(self) -> float:
-        return (self._t1 if self._t1 is not None else time.perf_counter()) - self._t0
+        end = self.end_ns if self.end_ns is not None else (
+            time.perf_counter_ns())
+        return (end - self.start_ns) / 1e9
 
     @property
     def context(self) -> SpanContext:
@@ -88,6 +117,8 @@ class Span:
             "parent_id": self.parent_id,
             "process": self.process,
             "t_wall": self.t_wall,
+            "start_ns": self.start_ns,
+            "end_ns": self.end_ns,
             "duration_s": self.duration_s,
             "attrs": dict(self.attrs),
         }
@@ -99,8 +130,12 @@ class Span:
             parent_id=d.get("parent_id"), process=d.get("process", "main"),
             t_wall=float(d.get("t_wall", 0.0)), attrs=dict(d.get("attrs", {})),
         )
-        sp._t0 = 0.0
-        sp._t1 = float(d.get("duration_s", 0.0))
+        # A form without the clock readings (a loaded trace file) keeps
+        # its duration.
+        sp.start_ns = int(d.get("start_ns") or 0)
+        end_ns = d.get("end_ns")
+        sp.end_ns = int(end_ns) if end_ns is not None else sp.start_ns + (
+            round(float(d.get("duration_s", 0.0)) * 1e9))
         return sp
 
 
@@ -110,7 +145,9 @@ class Tracer:
     ``enabled`` gates recording only — ``span()`` always times.  The
     buffer is bounded by ``max_spans``; once full, new spans are dropped
     and counted in ``dropped`` (a trace that silently swallows its own
-    overflow would misreport coverage).
+    overflow would misreport coverage).  ``owner`` says whose spans the
+    buffer holds, for a tracer that several components record into in
+    turn (``RoundTelemetry`` sets it).
     """
 
     def __init__(self, process: str = "main", enabled: bool = True,
@@ -120,6 +157,7 @@ class Tracer:
         self.max_spans = max_spans
         self.spans: list[Span] = []
         self.dropped = 0
+        self.owner: Optional[object] = None
         self._lock = threading.Lock()
         self._local = threading.local()
 
@@ -151,12 +189,18 @@ class Tracer:
         sp = Span(name=name, trace_id=trace_id, span_id=new_id(),
                   parent_id=parent_id, process=self.process,
                   t_wall=time.time(), attrs=attrs)
-        sp._t0 = time.perf_counter()
         stack.append(sp)
         try:
-            yield sp
+            # The annotation opens just before the span's clock is read
+            # and closes just after, so the two agree to the cost of one
+            # clock reading.
+            with _annotation()(name):
+                sp.start_ns = time.perf_counter_ns()
+                try:
+                    yield sp
+                finally:
+                    sp.end_ns = time.perf_counter_ns()
         finally:
-            sp._t1 = time.perf_counter()
             stack.pop()
             self._record(sp)
 
@@ -217,10 +261,13 @@ class Tracer:
             self.dropped = 0
 
 
-_default_tracer = Tracer(process="main")
+_default_tracer = Tracer(process="main", enabled=False)
 
 
 def get_tracer() -> Tracer:
-    """Process-wide default tracer (components that want isolation — the
-    engine, each worker — hold their own instance instead)."""
+    """Process-wide default tracer: the engine's, so that whoever opened a
+    recording window (``RoundTelemetry``) finds its spans from anywhere in
+    the process.  Records nothing until a window enables it.  Components
+    that want isolation (the coordinators, each worker, fleetsim) hold
+    their own instance instead."""
     return _default_tracer

@@ -18,7 +18,8 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-REQUIRED_PHASES = {"round", "client_update", "sync_metrics", "evaluate"}
+REQUIRED_PHASES = {"fit", "round", "enqueue", "sync_metrics", "bookkeeping",
+                   "evaluate"}
 
 
 def main(trace_dir: str | None = None) -> dict:

@@ -14,8 +14,8 @@ from colearn_federated_learning_tpu.telemetry.registry import (
     Histogram,
     MetricsRegistry,
 )
+from colearn_federated_learning_tpu.telemetry.lifecycle import RoundProfiler
 from colearn_federated_learning_tpu.telemetry.tracer import Tracer
-from colearn_federated_learning_tpu.utils.profiling import RoundProfiler
 
 
 # ------------------------------------------------------------- tracer ----
@@ -118,7 +118,7 @@ def test_histogram_thinning_keeps_exact_count_sum():
 def test_chrome_trace_schema_roundtrip(tmp_path):
     tr = Tracer(process="engine")
     with tr.span("round", round=0):
-        with tr.span("client_update"):
+        with tr.span("enqueue"):
             pass
     path = telemetry.write_trace(
         str(tmp_path / "t_trace.json"), tr.snapshot(), metrics={"m": 1.0}
@@ -127,7 +127,7 @@ def test_chrome_trace_schema_roundtrip(tmp_path):
     events = doc["traceEvents"]
     x = [e for e in events if e["ph"] == "X"]
     meta = [e for e in events if e["ph"] == "M"]
-    assert {e["name"] for e in x} == {"round", "client_update"}
+    assert {e["name"] for e in x} == {"round", "enqueue"}
     for e in x:                          # Chrome-trace complete events
         assert {"name", "ph", "ts", "dur", "pid", "tid", "args"} <= set(e)
         assert e["dur"] >= 0
@@ -136,7 +136,7 @@ def test_chrome_trace_schema_roundtrip(tmp_path):
     # inverse: spans survive the round-trip with ids intact
     back = {s.name: s for s in telemetry.trace_spans(doc)}
     orig = {s.name: s for s in tr.snapshot()}
-    assert back["client_update"].parent_id == orig["round"].span_id
+    assert back["enqueue"].parent_id == orig["round"].span_id
     with pytest.raises(ValueError):
         bad = tmp_path / "bad.json"
         bad.write_text("{}")
@@ -146,12 +146,12 @@ def test_chrome_trace_schema_roundtrip(tmp_path):
 def test_summarize_trace_reports_phases_and_coverage():
     tr = Tracer(process="engine")
     with tr.span("round"):
-        with tr.span("client_update"):
+        with tr.span("enqueue"):
             time.sleep(0.01)
     text = telemetry.summarize_trace(
         {"traceEvents": telemetry.spans_to_chrome(tr.snapshot())}
     )
-    assert "client_update" in text and "phase coverage" in text
+    assert "enqueue" in text and "phase coverage" in text
 
 
 # ------------------------------------- propagation through the sockets ----

@@ -13,6 +13,7 @@ import trace_smoke  # noqa: E402
 def test_trace_smoke(tmp_path):
     out = trace_smoke.main(str(tmp_path))
     assert out["coverage"] >= 0.95
-    assert "client_update" in out["phases"]
+    assert "enqueue" in out["phases"]
+    assert "client_update" not in out["phases"]
     assert os.path.exists(out["trace_file"])
     assert "phase coverage" in out["summary"]
