@@ -36,6 +36,7 @@ COUNTERS = (
     # engine plane (fed/engine.py, fed/local.py)
     "engine.rounds_total",
     "local.trainers_built",
+    "local.compact_tables",          # leaves trained on a working set of rows
     # comm plane (comm/protocol.py, comm/transport.py, comm/worker.py)
     "comm.messages_sent",
     "comm.messages_received",
@@ -161,6 +162,8 @@ GAUGES = (
     "engine.h2d_transfer_s",
     "engine.from_config_s",          # the from_config span (learner build)
     "local.steps_per_round",
+    "local.compact_rows",            # rows of a client's working set (K)
+    "local.compact_rows_of",         # of the table's rows (V)
     "fleetsim.devices",
     "fleetsim.chunk_size",
     "fleetsim.available_fraction",

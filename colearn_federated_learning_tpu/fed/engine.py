@@ -345,6 +345,7 @@ class FederatedLearner:
         self.local_update, self.num_steps = setup_lib.local_trainer_for_config(
             c, self.model.apply, shards.capacity,
             grad_sync_axes=(self.seq_axis,) if self.sp else (),
+            param_axes=(self.tp_axis,) if self.tp_size > 1 else (),
         )
         # SCAFFOLD per-client control variates: one params-shaped pytree per
         # client, stacked on the client axis — resident on HOST (numpy).

@@ -574,6 +574,7 @@ def build_personalized_eval_fn(ln, steps: int, lr: float):
     update, _ = setup_lib.local_trainer_for_config(
         ft_config, apply_fn, ln.shards.capacity,
         grad_sync_axes=(ln.seq_axis,) if ln.sp else (),
+        param_axes=(ln.tp_axis,) if ln.tp_size > 1 else (),
     )
     budget = jnp.asarray(steps, jnp.int32)
     batch = max(c.fed.batch_size, 64)

@@ -76,10 +76,12 @@ def local_trainer_for_config(
     capacity: int,
     grad_sync_axes: tuple[str, ...] = (),
     lora_dense_ok: bool = False,
+    param_axes: tuple[str, ...] = (),
 ) -> tuple[Callable, int]:
     """(local_update fn, num_steps) for one client round under ``config``.
 
     ``grad_sync_axes``: sequence-parallel mesh axes (fed/local.py).
+    ``param_axes``: mesh axes the parameters are sharded over (fed/local.py).
     ``lora_dense_ok``: fleetsim prices LoRA factor frames but keeps its
     vmapped training dynamics dense by design (fleetsim/sim.py) — only
     it may build this dense trainer under ``lora_rank > 0``."""
@@ -128,6 +130,7 @@ def local_trainer_for_config(
         scaffold=c.strategy == "scaffold",
         lr=c.lr,
         aux_loss_weight=config.model.moe_aux_weight if is_moe else 0.0,
+        param_axes=param_axes,
     )
     return update_fn, num_steps
 

@@ -68,6 +68,9 @@ class BertClassifier(nn.Module):
     # Rematerialize each block under autodiff (activation HBM ∝ depth
     # becomes ∝ 1 at the cost of one extra forward per block).
     remat: bool = False
+    # Leaves read only by gathering rows of ``ids``, and the field that
+    # sizes each: fed/local.py trains them on the rows a round can touch.
+    gathered_tables = {"Embed_0/embedding": "vocab_size"}
 
     @nn.compact
     def __call__(self, ids, train: bool = False):

@@ -135,3 +135,52 @@ def test_thousand_client_build_runs_a_round():
     rec = learner.run_round()
     assert rec["completed"] == 64
     assert np.isfinite(rec["train_loss"])
+
+
+def test_bert_working_set_of_rows_matches_the_dense_trainer():
+    """Two ``fit()`` rounds of a tiny BERT whose vocabulary (2,000) exceeds
+    what a client's round can touch (2 steps x 4 x 64 tokens): the trainer
+    takes a working set of the token table's rows (fed/local.py), and the
+    federation is the one the dense trainer gives."""
+    import jax
+
+    from colearn_federated_learning_tpu import telemetry
+    from colearn_federated_learning_tpu.fed import setup as setup_lib
+
+    cfg = ExperimentConfig(
+        data=DataConfig(dataset="agnews_tiny", num_clients=8, partition="iid",
+                        max_examples_per_client=16),
+        model=ModelConfig(name="bert", num_classes=4, width=32, depth=1,
+                          num_heads=4, seq_len=64, vocab_size=2000),
+        fed=FedConfig(strategy="fedavg", rounds=2, cohort_size=4, local_steps=2,
+                      batch_size=4, lr=1e-3, momentum=0.0,
+                      local_optimizer="adam", lr_schedule="warmup_cosine",
+                      warmup_rounds=2),
+        run=RunConfig(name="bert_rows", seed=3),
+    )
+    compacted = telemetry.get_registry().counter("local.compact_tables")
+    before = compacted.value
+    learner = FederatedLearner(cfg)
+    learner.fit(rounds=2)
+    assert compacted.value - before == 1
+    assert telemetry.get_registry().snapshot()["local.compact_rows"] == 2 * 4 * 64
+    assert learner._round_fn.compiles == 1
+
+    dense = FederatedLearner(cfg)
+
+    class Undeclared(type(dense.model)):
+        gathered_tables = {}
+
+    twin = Undeclared(**{f.name: getattr(dense.model, f.name)
+                         for f in dataclasses.fields(dense.model)
+                         if f.name not in ("parent", "name")})
+    dense.local_update, _ = setup_lib.local_trainer_for_config(
+        cfg, twin.apply, dense.shards.capacity)
+    dense.fit(rounds=2)
+    assert compacted.value - before == 1              # the dense one did not
+    np.testing.assert_allclose([r["train_loss"] for r in learner.history],
+                               [r["train_loss"] for r in dense.history],
+                               rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(learner.server_state.params),
+                    jax.tree.leaves(dense.server_state.params)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
