@@ -149,6 +149,8 @@ COUNTERS = (
     # ops/attention.py: flash kernels traced, labeled
     # {mode=mosaic|interpret} — which of the two lowerings a run used
     "ops.flash_trace_total",
+    # flash calls traced with a prefix of keys every causal query sees
+    "attention.flash_prefix_calls",
     "flight.dumps_total",            # flight-recorder dump writes
     "export.scrapes_total",          # /metrics + /snapshot.json hits
     "export.events_written_total",   # JSONL event-stream lines
@@ -164,6 +166,10 @@ GAUGES = (
     "local.steps_per_round",
     "local.compact_rows",            # rows of a client's working set (K)
     "local.compact_rows_of",         # of the table's rows (V)
+    # ops/eva.py, set where the kernel path of EVA attention is built
+    "eva.keys_per_query_max",        # a window and the summaries before it
+    "eva.window",
+    "eva.chunk",
     "fleetsim.devices",
     "fleetsim.chunk_size",
     "fleetsim.available_fraction",

@@ -24,7 +24,7 @@ from colearn_federated_learning_tpu.data import synthetic
 @dataclasses.dataclass(frozen=True)
 class DatasetSpec:
     name: str
-    kind: str                      # "image" | "text" | "timeseries"
+    kind: str                      # "image" | "text" | "timeseries" | "bytes"
     input_shape: tuple[int, ...]   # per-example shape: image HWC,
                                    # text (seq_len,), timeseries (T, F)
     num_classes: int
@@ -50,6 +50,12 @@ SPECS: dict[str, DatasetSpec] = {
                                40_000, 8_000),
     "iot_traffic_tiny": DatasetSpec("iot_traffic_tiny", "timeseries",
                                     (64, 16), 8, 2_000, 400),
+    # Packed byte documents for a next-byte model (models/evabyte.py): an
+    # example is one sequence, its labels the 8 bytes after each position.
+    "bytes": DatasetSpec("bytes", "bytes", (16_384,), 320, 128, 4,
+                         vocab_size=320),
+    "bytes_tiny": DatasetSpec("bytes_tiny", "bytes", (128,), 320, 64, 8,
+                              vocab_size=320),
 }
 
 
@@ -99,7 +105,8 @@ def _load_disk(spec: DatasetSpec) -> Dataset | None:
                     f"({len(x)} vs {len(y)})")
             if spec.kind == "image" and x.dtype == np.uint8:
                 x = x.astype(np.float32) / 255.0   # keras raw-byte layout
-            y = y.reshape(-1)
+            if spec.kind != "bytes":      # bytes: labels per position
+                y = y.reshape(-1)
             # Range-check BEFORE the int32 cast: a corrupt wide integer
             # must not wrap into the valid range and pass.
             if y.size and (int(y.min()) < 0
@@ -124,6 +131,12 @@ def _make_synthetic(spec: DatasetSpec, seed: int) -> Dataset:
             spec.n_test, spec.input_shape, spec.num_classes, seed=seed + 1,
             proto_seed=proto_seed,
         )
+        return Dataset(spec, x_tr, y_tr, x_te, y_te, "synthetic")
+    if spec.kind == "bytes":
+        x_tr, y_tr = synthetic.synthetic_byte_stream(
+            spec.n_train, spec.input_shape[0], seed=seed)
+        x_te, y_te = synthetic.synthetic_byte_stream(
+            spec.n_test, spec.input_shape[0], seed=seed + 1)
         return Dataset(spec, x_tr, y_tr, x_te, y_te, "synthetic")
     if spec.kind == "image":
         x_tr, y_tr = synthetic.synthetic_image_classification(

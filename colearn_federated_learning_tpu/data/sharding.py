@@ -23,7 +23,7 @@ class ClientShards:
     """Stacked, padded per-client data: leaves shaped (num_clients, M, ...)."""
 
     x: np.ndarray        # (C, M, *example_shape)
-    y: np.ndarray        # (C, M) int32
+    y: np.ndarray        # (C, M) int32; (C, M, ...) with a label per token
     counts: np.ndarray   # (C,) int32 — true examples per client
 
     @property

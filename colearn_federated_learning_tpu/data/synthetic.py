@@ -123,3 +123,55 @@ def synthetic_traffic_classification(
     y = rng.integers(0, n_classes, size=n).astype(np.int32)
     x = protos[y] + rng.normal(0.0, noise, size=(n, t_len, n_feat))
     return x.astype(np.float32), y
+
+
+# The byte source behind ``synthetic_byte_stream``: EvaByte's vocabulary
+# is 64 special ids followed by the 256 byte values.
+BYTE_OFFSET = 64
+SEPARATOR_ID = 3
+_SOURCE_SEED = 20250101
+
+
+def synthetic_byte_stream(
+    n: int,
+    seq_len: int,
+    horizon: int = 8,
+    seed: int = 0,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Packed documents of heavy-tailed length from a fixed first-order
+    Markov source over bytes, for a next-byte model with ``horizon``
+    prediction heads (models/evabyte.py).
+
+    Each row is cut from a stream ``horizon`` longer than ``seq_len``:
+    ``x`` (n, seq_len) int32 and ``y`` (n, seq_len, horizon) with
+    ``y[r, i, j] = stream[r, i + 1 + j]``, so every position is labelled.
+    A byte ``b`` is id ``64 + b``; documents (32 bytes and up, Pareto
+    tail) are packed back to back with one separator id between them and
+    no boundary mask.  The source (four likely successors a byte, the same
+    for every seed) is what makes the task learnable: the next byte has
+    1.1 nats of entropy, not ln 256.
+    """
+    source = np.random.default_rng(_SOURCE_SEED)
+    successors = source.integers(0, 256, size=(256, 4))
+    odds = np.array([0.55, 0.25, 0.12, 0.08])
+    rng = np.random.default_rng(seed)
+    total = seq_len + horizon
+    choice = rng.choice(4, size=(n, total), p=odds)
+    fresh = rng.integers(0, 256, size=(n, total))
+    # Document ends: cumulative lengths, each followed by one separator.
+    lengths = (32 * (1.0 + rng.pareto(1.1, size=(n, total // 33 + 1)))
+               ).astype(np.int64)
+    ends = np.cumsum(lengths + 1, axis=1) - 1
+    is_sep = np.zeros((n, total), bool)
+    rows = np.broadcast_to(np.arange(n)[:, None], ends.shape)
+    inside = ends < total
+    is_sep[rows[inside], ends[inside]] = True
+    stream = np.empty((n, total), np.int32)
+    state = fresh[:, 0]
+    for t in range(total):
+        stream[:, t] = np.where(is_sep[:, t], SEPARATOR_ID,
+                                BYTE_OFFSET + state)
+        state = np.where(is_sep[:, t], fresh[:, t],
+                         successors[state, choice[:, t]])
+    ahead = np.arange(seq_len)[:, None] + 1 + np.arange(horizon)[None, :]
+    return stream[:, :seq_len].copy(), stream[:, ahead]

@@ -37,6 +37,7 @@ from colearn_federated_learning_tpu.fed import setup as setup_lib
 from colearn_federated_learning_tpu.fed import strategies
 from colearn_federated_learning_tpu.fed.evaluation import (
     detection_report,
+    eval_rows,
     make_confusion_eval_fn,
     make_eval_fn,
 )
@@ -539,7 +540,8 @@ class FederatedLearner:
             self.eval_model.apply,
             self.dataset.x_test,
             self.dataset.y_test,
-            batch=max(self.config.fed.batch_size, 64),
+            batch=eval_rows(self.config.fed.batch_size,
+                            self.dataset.x_test),
         ))
 
     def _holdout_program(self, fn):
@@ -742,7 +744,8 @@ class FederatedLearner:
                     self.eval_model.apply,
                     self.dataset.x_test,
                     self.dataset.y_test,
-                    batch=max(self.config.fed.batch_size, 64),
+                    batch=eval_rows(self.config.fed.batch_size,
+                                    self.dataset.x_test),
                     num_classes=self.config.model.num_classes,
                 ))
         conf = np.asarray(self._conf_eval_fn(self.server_state.params))

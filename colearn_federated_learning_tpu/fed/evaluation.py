@@ -7,17 +7,42 @@ training setup at all.
 
 from __future__ import annotations
 
+import math
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+# An evaluation batch: this many rows, or the trainer's batch if that is
+# larger; rows of token ids, whose cost grows with their length, as many as
+# hold this many tokens (a 16,384-token sequence is a batch of its own).
+EVAL_ROWS = 64
+EVAL_TOKENS = 16_384
+
+
+def eval_rows(batch_size: int, x) -> int:
+    """Rows of ``x`` (the examples, first axis the rows) in one batch of an
+    evaluation program."""
+    rows = max(batch_size, EVAL_ROWS)
+    if np.issubdtype(x.dtype, np.integer):
+        tokens = math.prod(x.shape[1:])
+        rows = min(rows, max(batch_size, EVAL_TOKENS // tokens))
+    return rows
+
+
+def per_label(mask, like):
+    """A per-row ``mask`` (..., rows) as a weight for each label of
+    ``like`` (..., rows, *labels of a row)."""
+    return jnp.broadcast_to(
+        mask.reshape(mask.shape + (1,) * (like.ndim - mask.ndim)), like.shape)
+
 
 def _pad_batches(x_test, y_test, batch: int):
     """(xb, yb, mb) device arrays: the test set padded to whole
     ``batch``-sized chunks with a validity mask — static shapes, shared
-    by every eval builder in this module."""
+    by every eval builder in this module.  ``y_test`` holds a class per
+    row, (n,), or any block of labels per row, (n, ...)."""
     x_test = np.asarray(x_test)
     y_test = np.asarray(y_test)
     n = len(x_test)
@@ -25,17 +50,20 @@ def _pad_batches(x_test, y_test, batch: int):
     pad = n_batches * batch - n
     x_pad = np.concatenate(
         [x_test, np.zeros((pad,) + x_test.shape[1:], x_test.dtype)])
-    y_pad = np.concatenate([y_test, np.zeros((pad,), y_test.dtype)])
+    y_pad = np.concatenate(
+        [y_test, np.zeros((pad,) + y_test.shape[1:], y_test.dtype)])
     mask = np.concatenate([np.ones(n, np.float32), np.zeros(pad, np.float32)])
     xb = jnp.asarray(x_pad.reshape((n_batches, batch) + x_test.shape[1:]))
-    yb = jnp.asarray(y_pad.reshape((n_batches, batch)))
+    yb = jnp.asarray(y_pad.reshape((n_batches, batch) + y_test.shape[1:]))
     mb = jnp.asarray(mask.reshape((n_batches, batch)))
     return xb, yb, mb
 
 
 def make_eval_fn(apply_fn: Callable, x_test, y_test, batch: int) -> Callable:
     """Build ``eval_fn(params) -> (mean_loss, accuracy)`` over the test set,
-    reduced in a single ``lax.scan`` — one compile."""
+    reduced in a single ``lax.scan`` — one compile.  The mean and the share
+    are over labels: one a row, or every token's (``y_test`` of (n, ...)
+    against logits of (n, ..., K))."""
     xb, yb, mb = _pad_batches(x_test, y_test, batch)
 
     @jax.jit
@@ -44,8 +72,9 @@ def make_eval_fn(apply_fn: Callable, x_test, y_test, batch: int) -> Callable:
             x, y, m = inp
             logits = apply_fn({"params": params}, x, train=False)
             ce = jax.nn.log_softmax(logits.astype(jnp.float32))
-            nll = -jnp.take_along_axis(ce, y[:, None], axis=1)[:, 0]
+            nll = -jnp.take_along_axis(ce, y[..., None], axis=-1)[..., 0]
             correct = (jnp.argmax(logits, axis=-1) == y).astype(jnp.float32)
+            m = per_label(m, nll)
             loss_sum, acc_sum, m_sum = carry
             return (
                 loss_sum + jnp.sum(nll * m),
@@ -76,7 +105,7 @@ def make_confusion_eval_fn(apply_fn: Callable, x_test, y_test, batch: int,
             logits = apply_fn({"params": params}, x, train=False)
             pred = jnp.argmax(logits, axis=-1)
             flat = y.astype(jnp.int32) * C + pred.astype(jnp.int32)
-            return conf.at[flat].add(m), None
+            return conf.at[flat].add(per_label(m, flat)), None
 
         conf, _ = jax.lax.scan(step, jnp.zeros(C * C, jnp.float32),
                                (xb, yb, mb))

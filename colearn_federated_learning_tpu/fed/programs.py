@@ -33,6 +33,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from colearn_federated_learning_tpu.fed import strategies
+from colearn_federated_learning_tpu.fed.evaluation import eval_rows, per_label
 from colearn_federated_learning_tpu.privacy import dp as dp_lib
 from colearn_federated_learning_tpu.privacy import secure_agg as sa_lib
 from colearn_federated_learning_tpu.utils import prng, pytrees
@@ -504,7 +505,7 @@ def build_client_eval_fn(ln):
     """Per-client (loss, acc) of the CURRENT global params on each
     client's own shard — vmapped, sharded over the client axis on a
     mesh.  Chunked scan bounds activation memory."""
-    batch = max(ln.config.fed.batch_size, 64)
+    batch = eval_rows(ln.config.fed.batch_size, ln.shards.x[0])
     cap = ln.shards.capacity
     n_chunks = int(np.ceil(cap / batch))
     padded = n_chunks * batch
@@ -518,18 +519,21 @@ def build_client_eval_fn(ln):
         cxp = jnp.concatenate(
             [cx, jnp.zeros((pad,) + cx.shape[1:], cx.dtype)]
         ) if pad else cx
-        cyp = jnp.concatenate([cy, jnp.zeros((pad,), cy.dtype)]) if pad else cy
+        cyp = jnp.concatenate(
+            [cy, jnp.zeros((pad,) + cy.shape[1:], cy.dtype)]
+        ) if pad else cy
         xb = cxp.reshape((n_chunks, batch) + cx.shape[1:])
-        yb = cyp.reshape((n_chunks, batch))
+        yb = cyp.reshape((n_chunks, batch) + cy.shape[1:])
         base = jnp.arange(n_chunks) * batch
 
         def step(carry, inp):
             x_, y_, b = inp
             logits = apply_fn({"params": params}, x_, train=False)
             ce = jax.nn.log_softmax(logits.astype(jnp.float32))
-            nll = -jnp.take_along_axis(ce, y_[:, None], axis=1)[:, 0]
+            nll = -jnp.take_along_axis(ce, y_[..., None], axis=-1)[..., 0]
             correct = (jnp.argmax(logits, axis=-1) == y_).astype(jnp.float32)
-            m = ((b + jnp.arange(batch)) < count).astype(jnp.float32)
+            m = per_label(
+                ((b + jnp.arange(batch)) < count).astype(jnp.float32), nll)
             l, a, n = carry
             return (l + jnp.sum(nll * m), a + jnp.sum(correct * m),
                     n + jnp.sum(m)), None
@@ -577,7 +581,7 @@ def build_personalized_eval_fn(ln, steps: int, lr: float):
         param_axes=(ln.tp_axis,) if ln.tp_size > 1 else (),
     )
     budget = jnp.asarray(steps, jnp.int32)
-    batch = max(c.fed.batch_size, 64)
+    batch = eval_rows(c.fed.batch_size, ln.shards.x[0])
     cap = ln.shards.capacity
     n_chunks = int(np.ceil(cap / batch))
     padded = n_chunks * batch
@@ -590,9 +594,11 @@ def build_personalized_eval_fn(ln, steps: int, lr: float):
         cxp = jnp.concatenate(
             [cx, jnp.zeros((pad,) + cx.shape[1:], cx.dtype)]
         ) if pad else cx
-        cyp = jnp.concatenate([cy, jnp.zeros((pad,), cy.dtype)]) if pad else cy
+        cyp = jnp.concatenate(
+            [cy, jnp.zeros((pad,) + cy.shape[1:], cy.dtype)]
+        ) if pad else cy
         xb = cxp.reshape((n_chunks, batch) + cx.shape[1:])
-        yb = cyp.reshape((n_chunks, batch))
+        yb = cyp.reshape((n_chunks, batch) + cy.shape[1:])
         base = jnp.arange(n_chunks) * batch
 
         def chunk(carry, inp):
@@ -600,7 +606,8 @@ def build_personalized_eval_fn(ln, steps: int, lr: float):
             logits = apply_fn({"params": params}, x_, train=False)
             correct = (jnp.argmax(logits, axis=-1) == y_).astype(jnp.float32)
             rows = b + jnp.arange(batch)
-            m = ((rows >= lo) & (rows < hi)).astype(jnp.float32)
+            m = per_label(
+                ((rows >= lo) & (rows < hi)).astype(jnp.float32), correct)
             a, n = carry
             return (a + jnp.sum(correct * m), n + jnp.sum(m)), None
 

@@ -37,6 +37,11 @@ def partition_for_config(
     """Per-client index lists for ``config.data``
     (iid | dirichlet | pathological)."""
     c = config.data
+    if c.partition != "iid" and np.ndim(labels) > 1:
+        raise ValueError(
+            f"data.partition {c.partition!r} splits by an example's class; "
+            f"labels of shape {np.shape(labels)[1:]} per example (a label "
+            "per token) have none: use 'iid'")
     if c.partition == "dirichlet":
         return partition_lib.dirichlet_partition(
             labels, c.num_clients, c.dirichlet_alpha, seed=config.run.seed

@@ -25,10 +25,11 @@ def build_model(cfg: ModelConfig, seq_axis_name: str | None = None):
             "sequence parallelism is only supported for 'bert'/'moe_bert', "
             f"not {cfg.name!r}"
         )
-    if cfg.remat and cfg.name not in ("bert", "moe_bert", "vit_b16"):
+    if cfg.remat and cfg.name not in ("bert", "moe_bert", "vit_b16",
+                                      "evabyte"):
         raise ValueError(
             "remat is only implemented for the transformer families "
-            f"(bert/moe_bert/vit_b16), not {cfg.name!r} — silently "
+            f"(bert/moe_bert/vit_b16/evabyte), not {cfg.name!r} — silently "
             "ignoring it would fake the memory savings"
         )
     if cfg.name == "mlp":
@@ -77,6 +78,21 @@ def build_model(cfg: ModelConfig, seq_axis_name: str | None = None):
                    depth=cfg.depth, num_heads=cfg.num_heads,
                    patch_size=cfg.patch_size, dtype=dtype,
                    attn_impl=cfg.attn_impl, remat=cfg.remat)
+    if cfg.name == "evabyte":
+        from colearn_federated_learning_tpu.models.evabyte import EvaByte
+        from colearn_federated_learning_tpu.ops.eva import EVA_IMPLS
+
+        if cfg.attn_impl not in EVA_IMPLS:
+            raise ValueError(
+                f"evabyte's attention runs as {EVA_IMPLS}, not "
+                f"{cfg.attn_impl!r}")
+        return EvaByte(vocab_size=cfg.vocab_size, embed_dim=cfg.width,
+                       depth=cfg.depth, num_heads=cfg.num_heads,
+                       ffn_dim=cfg.ffn_dim, window=cfg.window_size,
+                       chunk=cfg.chunk_size,
+                       num_pred_heads=cfg.num_pred_heads,
+                       rope_theta=cfg.rope_theta, dtype=dtype,
+                       attn_impl=cfg.attn_impl, remat=cfg.remat)
     raise KeyError(f"unknown model {cfg.name!r}")
 
 

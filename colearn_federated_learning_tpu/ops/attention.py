@@ -86,8 +86,27 @@ def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+def _shifted(q_end, prefix: int):
+    """Keys a causal query block ending at ``q_end`` reaches; with no
+    prefix the expression is the one it always was."""
+    return q_end + prefix if prefix else q_end
+
+
+def _causal(s, qb, kb, block_q: int, block_k: int, prefix: int):
+    """Scores of one (q-block, k-block) tile with the keys a causal query
+    may not see set to ``_NEG``.  The first ``prefix`` keys stand before
+    position 0 (every query sees them; only the key bias masks them) and
+    the causal test runs on the keys after them."""
+    q_pos = qb * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if prefix:
+        k_pos = k_pos - prefix
+    return jnp.where(q_pos >= k_pos, s, _NEG)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
-                  block_k: int, scale: float, causal: bool, block_q: int):
+                  block_k: int, scale: float, causal: bool, block_q: int,
+                  prefix: int = 0):
     """One (batch·head, q-block) tile; K/V for the whole row are VMEM-resident.
 
     q_ref: (1, block_q, D) — this tile's queries
@@ -119,9 +138,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                             preferred_element_type=jnp.float32)  # (bq, bk)
         s = s * scale + bias_ref[0, pl.ds(kb * block_k, block_k), 0][None, :]
         if causal:
-            q_pos = qb * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG)
+            s = _causal(s, qb, kb, block_q, block_k, prefix)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         # Fully-masked blocks: m_new sits at the _NEG floor and exp(0)=1
@@ -137,7 +154,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
 
     if causal:
         # Skip k-blocks entirely above the diagonal.
-        num_kb = jnp.minimum(num_kb, pl.cdiv((qb + 1) * block_q, block_k))
+        num_kb = jnp.minimum(
+            num_kb, pl.cdiv(_shifted((qb + 1) * block_q, prefix), block_k))
     m0 = jnp.full((q.shape[0], 1), _NEG, jnp.float32)
     l0 = jnp.zeros((q.shape[0], 1), jnp.float32)
     acc0 = jnp.zeros((q.shape[0], D), jnp.float32)
@@ -186,17 +204,18 @@ def _blocks(q, k, v, kv_mask, block_q, block_k, interpret):
 
 def _flash_impl(q, k, v, kv_mask, causal: bool,
                 block_q: int, block_k: int, interpret: Optional[bool],
-                return_lse: bool = False):
+                return_lse: bool = False, prefix: int = 0):
     (B, Lq, H, D, Lk, bq, bk, Lq_p, Lk_p, to_rows, bias,
      interpret) = _blocks(q, k, v, kv_mask, block_q, block_k, interpret)
     qr, kr, vr = to_rows(q, Lq_p), to_rows(k, Lk_p), to_rows(v, Lk_p)
 
     kernel = functools.partial(
         _flash_kernel, block_k=bk, scale=1.0 / (D ** 0.5),
-        causal=causal, block_q=bq,
+        causal=causal, block_q=bq, prefix=prefix,
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B * H, Lq_p // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
@@ -223,7 +242,7 @@ def _flash_impl(q, k, v, kv_mask, causal: bool,
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                      delta_ref, dq_ref, *, block_k: int, scale: float,
-                     causal: bool, block_q: int):
+                     causal: bool, block_q: int, prefix: int = 0):
     """dQ for one (batch·head, q-block) tile, looping over k-blocks:
     p = exp(qk^T·s + bias − lse);  ds = p ⊙ (dO·V^T − Δ);  dq += ds·K·s."""
     Lk = k_ref.shape[1]
@@ -244,9 +263,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                             preferred_element_type=jnp.float32)
         s = s * scale + bias_ref[0, pl.ds(kb * block_k, block_k), 0][None, :]
         if causal:
-            q_pos = qb * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG)
+            s = _causal(s, qb, kb, block_q, block_k, prefix)
         p = jnp.exp(s - lse)                                 # exact softmax
         p = jnp.where(s > 0.5 * _NEG, p, 0.0)
         dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
@@ -258,7 +275,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         )
 
     if causal:
-        num_kb = jnp.minimum(num_kb, pl.cdiv((qb + 1) * block_q, block_k))
+        num_kb = jnp.minimum(
+            num_kb, pl.cdiv(_shifted((qb + 1) * block_q, prefix), block_k))
     dq = lax.fori_loop(
         0, num_kb, body, jnp.zeros((q.shape[0], q.shape[1]), jnp.float32)
     )
@@ -267,7 +285,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                       delta_ref, dk_ref, dv_ref, *, block_q: int,
-                      scale: float, causal: bool, block_k: int):
+                      scale: float, causal: bool, block_k: int,
+                      prefix: int = 0):
     """dK/dV for one (batch·head, k-block) tile, looping over q-blocks:
     dv += p^T·dO;  dk += ds^T·(q·s)."""
     Lq = q_ref.shape[1]
@@ -291,9 +310,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                             preferred_element_type=jnp.float32)   # (bq, bk)
         s = s * scale + bias
         if causal:
-            q_pos = qb * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG)
+            s = _causal(s, qb, kb, block_q, block_k, prefix)
         p = jnp.exp(s - lse)
         p = jnp.where(s > 0.5 * _NEG, p, 0.0)
         dv_new = dv + lax.dot_general(
@@ -313,6 +330,8 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     if causal:
         # q-blocks strictly above the diagonal contribute nothing.
         qb0 = (kb * block_k) // block_q
+        if prefix:
+            qb0 = jnp.maximum(kb * block_k - prefix, 0) // block_q
     D = k_blk.shape[1]
     dk, dv = lax.fori_loop(
         qb0, num_qb, body,
@@ -324,7 +343,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 
 def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
-                    block_q, block_k, interpret):
+                    block_q, block_k, interpret, prefix: int = 0):
     (B, Lq, H, D, Lk, bq, bk, Lq_p, Lk_p, to_rows, bias,
      interpret) = _blocks(q, k, v, kv_mask, block_q, block_k, interpret)
     qr, kr, vr = to_rows(q, Lq_p), to_rows(k, Lk_p), to_rows(v, Lk_p)
@@ -337,9 +356,10 @@ def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
 
     scale = 1.0 / (D ** 0.5)
     dq_kernel = functools.partial(_flash_dq_kernel, block_k=bk, scale=scale,
-                                  causal=causal, block_q=bq)
+                                  causal=causal, block_q=bq, prefix=prefix)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_dq",
         grid=(B * H, Lq_p // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
@@ -357,9 +377,10 @@ def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
     )(qr, kr, vr, bias, gr, lse, delta)
 
     dkv_kernel = functools.partial(_flash_dkv_kernel, block_q=bq, scale=scale,
-                                   causal=causal, block_k=bk)
+                                   causal=causal, block_k=bk, prefix=prefix)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_dkv",
         grid=(B * H, Lk_p // bk),
         in_specs=[
             pl.BlockSpec((1, Lq_p, D), lambda b, j: (b, 0, 0)),
@@ -389,24 +410,26 @@ def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
             from_rows(dv, Lk, Lk_p))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, kv_mask, causal, block_q, block_k, interpret):
-    return _flash_impl(q, k, v, kv_mask, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, kv_mask, causal, block_q, block_k, interpret, prefix):
+    return _flash_impl(q, k, v, kv_mask, causal, block_q, block_k, interpret,
+                       prefix=prefix)
 
 
-def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
+               prefix):
     out, lse = _flash_impl(q, k, v, kv_mask, causal, block_q, block_k,
-                           interpret, return_lse=True)
+                           interpret, return_lse=True, prefix=prefix)
     return out, (q, k, v, kv_mask, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, block_q, block_k, interpret, prefix, res, g):
     # Blockwise Pallas backward (FlashAttention-2): probabilities are
     # recomputed tile-by-tile from the saved logsumexp — exact gradients,
     # no (L, L) matrix in either direction.
     q, k, v, kv_mask, out, lse = res
     dq, dk, dv = _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
-                                 block_q, block_k, interpret)
+                                 block_q, block_k, interpret, prefix)
     return dq, dk, dv, None
 
 
@@ -423,13 +446,27 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    prefix: int = 0,
 ) -> jax.Array:
     """Blockwise (flash) attention over ``(B, L, H, D)`` tensors.
 
     ``kv_mask``: optional ``(B, L_k)`` bool, False = padding key.  Fully
     masked query rows return 0, matching ``dense_attention``.
+    ``prefix`` (static, with ``causal``): the first ``prefix`` keys stand
+    before the sequence — every query sees them unless ``kv_mask`` hides
+    them — and query ``i`` sees key ``prefix + j`` for ``j <= i``
+    (``L_k = prefix + L_q``).  What ``ops/eva.py`` puts there are the
+    chunk summaries of earlier windows.
     ``interpret=None`` auto-selects Pallas interpret mode off-TPU.
     ``block_q``/``block_k`` default per TPU generation (512 on v4+, 128 on
     v2/v3 whose smaller VMEM rejects the large configuration).
     """
-    return _flash(q, k, v, kv_mask, causal, block_q, block_k, interpret)
+    if prefix:
+        if not causal or prefix < 0:
+            raise ValueError(
+                f"prefix={prefix} needs causal=True and prefix >= 0: without "
+                "a causal part every key is a prefix key already")
+        # Counted at trace time, as ``ops.flash_trace_total`` is.
+        telemetry.get_registry().counter("attention.flash_prefix_calls").inc()
+    return _flash(q, k, v, kv_mask, causal, block_q, block_k, interpret,
+                  prefix)

@@ -196,6 +196,15 @@ class ModelConfig:
     # Defaults preserve the measured baseline model exactly.
     stem: str = "conv"                # conv | space_to_depth (CNN)
     norm: str = "group"               # group | none (CNN)
+    # Causal decoder (models/evabyte.py): gated feed-forward width, EVA's
+    # exact-attention window and summary chunk (ops/eva.py), next-byte
+    # prediction heads (head j at position i predicts i + 1 + j), rotary
+    # base.  ``vocab_size`` is its vocabulary, ``seq_len`` its context.
+    ffn_dim: int = 11008
+    window_size: int = 2048
+    chunk_size: int = 16
+    num_pred_heads: int = 8
+    rope_theta: float = 100000.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -469,6 +478,24 @@ CONFIGS["iot_traffic_tcn_fedavg"] = _cfg(
     fed=FedConfig(strategy="fedavg", rounds=50, cohort_size=10,
                   local_epochs=1, batch_size=32, lr=0.05, momentum=0.9),
     run=RunConfig(name="iot_traffic_tcn_fedavg"),
+)
+
+
+# A causal byte-level language model (ROADMAP M1/M4): EvaByte at its
+# published widths, 4 of its 32 layers and half its context, which is what
+# one 16 GB chip holds at cohort 1 with SGD clients (PERF.md section 4).
+# One example is one sequence of 16,384 bytes with 8 next-byte labels a
+# position (dataset ``bytes``).
+CONFIGS["evabyte_fedavg"] = _cfg(
+    data=DataConfig(dataset="bytes", num_clients=8, partition="iid"),
+    model=ModelConfig(name="evabyte", num_classes=320, vocab_size=320,
+                      width=4096, depth=4, num_heads=32, seq_len=16384,
+                      ffn_dim=11008, window_size=2048, chunk_size=16,
+                      num_pred_heads=8, rope_theta=100000.0,
+                      dtype="bfloat16", attn_impl="flash", remat=True),
+    fed=FedConfig(strategy="fedavg", rounds=20, cohort_size=1,
+                  local_steps=2, batch_size=1, lr=0.1, momentum=0.0),
+    run=RunConfig(name="evabyte_fedavg", eval_every=2),
 )
 
 

@@ -1,0 +1,80 @@
+"""The EVA attention path compiled for a described TPU v5e at the cell's
+real widths, forward and backward, with no chip attached: Mosaic refuses
+here what it would refuse there (a misaligned slice, too much VMEM), which
+interpret mode cannot show.  Skipped where no v5e can be described.
+
+The topology is described inside a fixture, after this file's tests have
+started, and in this file alone: a process that loads the TPU's library
+keeps it until it exits.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps it from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """``ops/attention.py`` asks jax for its backend, which here is the
+    CPU: hand it the v5e's answers (generation 5, Mosaic, not the
+    interpreter), and keep what is compiled out of the persistent cache
+    (an executable for an absent chip cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from colearn_federated_learning_tpu.ops import attention
+
+    blocks = attention._blocks
+    monkeypatch.setattr(attention, "_tpu_generation", lambda: 5)
+    monkeypatch.setattr(
+        attention, "_blocks",
+        lambda q, k, v, mask, bq, bk, interpret: blocks(
+            q, k, v, mask, bq, bk, False))
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def test_eva_attention_compiles_at_the_published_widths(one_chip,
+                                                        as_on_the_chip):
+    """One sequence of 16,384 positions, 32 heads of 128, window 2,048,
+    chunk 16: 8 folded rows of 896 summaries and 2,048 keys."""
+    from colearn_federated_learning_tpu.ops.eva import eva_attention
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss_and_grads(q, k, v, mu, phi):
+        def loss(*args):
+            out = eva_attention(*args, window=2048, chunk=16, impl="flash")
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+            q, k, v, mu, phi)
+
+    qkv = shape(1, 16384, 32, 128)
+    vector = shape(32, 128, dtype=jnp.float32)
+    compiled = jax.jit(loss_and_grads).lower(
+        qkv, qkv, qkv, vector, vector).compile()
+    text = compiled.as_text()
+    # The three kernels, by the names the benchmark's readers look for,
+    # and no score matrix beside them.
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in text and "tpu_custom_call" in text, name
+    assert "2048,2944]" not in text and "2048,3072]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
