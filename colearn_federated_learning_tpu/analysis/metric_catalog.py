@@ -170,6 +170,9 @@ GAUGES = (
     "eva.keys_per_query_max",        # a window and the summaries before it
     "eva.window",
     "eva.chunk",
+    # models/cnn.py, set on every build: stages computed on the view that
+    # folds two columns into the channel axis (0: the plain path)
+    "cnn.lane_folded_stages",
     "fleetsim.devices",
     "fleetsim.chunk_size",
     "fleetsim.available_fraction",
