@@ -537,8 +537,8 @@ class NoPrintInLibrary(Rule):
             "(`print(..., file=sys.stderr)`); CLI entry modules are "
             "exempt by name")
 
-    # Modules whose contract IS stdout (subcommand surface, bench JSON).
-    _EXEMPT_FILES = {"cli.py", "bench.py"}
+    # The module whose contract IS stdout (the subcommand surface).
+    _EXEMPT_FILES = {"cli.py"}
 
     @staticmethod
     def _is_main_guard(test: ast.AST) -> bool:

@@ -1,4 +1,4 @@
-"""`colearn` command line: train / aggregate / eval / init / configs / bench.
+"""`colearn` command line: train / aggregate / eval / init / configs.
 
 Parity surface (BASELINE.json north_star): the reference exposes
 ``colearn train`` and ``colearn aggregate`` entrypoints and argparse flags
@@ -1353,16 +1353,6 @@ def cmd_configs(_args: argparse.Namespace) -> int:
     return 0
 
 
-def cmd_bench(args: argparse.Namespace) -> int:
-    from colearn_federated_learning_tpu import bench
-
-    argv = ["--rounds", str(args.rounds), "--warmup", str(args.warmup),
-            "--baseline-rounds", str(args.baseline_rounds)]
-    if args.skip_baseline:
-        argv.append("--skip-baseline")
-    return bench.main(argv)
-
-
 def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(prog="colearn")
     sub = parser.add_subparsers(dest="cmd", required=True)
@@ -1780,13 +1770,6 @@ def main(argv: list[str] | None = None) -> int:
                         help="JSONL file, or directory searched "
                              "recursively for *.jsonl")
     p_conv.set_defaults(fn=cmd_converge)
-
-    p_bench = sub.add_parser("bench", help="run the headline benchmark")
-    p_bench.add_argument("--rounds", type=int, default=20)
-    p_bench.add_argument("--warmup", type=int, default=2)
-    p_bench.add_argument("--baseline-rounds", type=int, default=1)
-    p_bench.add_argument("--skip-baseline", action="store_true")
-    p_bench.set_defaults(fn=cmd_bench)
 
     args = parser.parse_args(argv)
     # Every subcommand, so the roles one federation spawns (broker,

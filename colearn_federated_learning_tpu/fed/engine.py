@@ -17,6 +17,7 @@ the program compiles once.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Optional
 
@@ -32,7 +33,6 @@ from colearn_federated_learning_tpu.data.sharding import (
     pad_clients_to_multiple,
 )
 from colearn_federated_learning_tpu.fed import programs
-from colearn_federated_learning_tpu.fed.programs import rank_cohort
 from colearn_federated_learning_tpu.fed import setup as setup_lib
 from colearn_federated_learning_tpu.fed import strategies
 from colearn_federated_learning_tpu.fed.evaluation import (
@@ -42,7 +42,7 @@ from colearn_federated_learning_tpu.fed.evaluation import (
     make_eval_fn,
 )
 from colearn_federated_learning_tpu.models import registry as model_registry
-from colearn_federated_learning_tpu.privacy import dp as dp_lib
+from colearn_federated_learning_tpu.privacy.accountant import RdpAccountant
 from colearn_federated_learning_tpu import telemetry
 from colearn_federated_learning_tpu.utils import prng
 from colearn_federated_learning_tpu.utils import config as config_lib
@@ -155,56 +155,104 @@ class FederatedLearner:
             sp.duration_s)
 
     def _build(self, config, dataset, mesh, partitions):
+        """One direction: mesh axes → data → model and server state → the
+        round's plan → local trainer → programs → placement."""
         self.config = config
         self.mesh = mesh
         c = config
         config_lib.validate_experiment(c)
+        self._read_mesh_axes()
+        self.dataset = dataset or data_registry.get_dataset(
+            c.data.dataset, seed=c.run.seed
+        )
+        self._pack_shards(partitions)
+        self._init_model()
+        # Every check on the federation's options is plan_round's; its
+        # cohort warning is attributed to the caller of FederatedLearner().
+        self.plan = programs.plan_round(
+            c, num_clients=self.num_clients,
+            real_num_clients=self.real_num_clients,
+            num_steps=setup_lib.num_steps_for_config(c, self.shards.capacity),
+            mesh=mesh, stacklevel=4)
+        self.cohort_size = self.plan.cohort_size
+        self.cohort_per_device = self.plan.cohort_per_device
+        self.num_steps = self.plan.num_steps
+        self.local_update = self._local_trainer(c)
+        # SCAFFOLD per-client control variates: one params-shaped pytree per
+        # client, stacked on the client axis — resident on HOST (numpy).
+        # Each round gathers only the COHORT's variates into the jit round
+        # program and scatters the updated block back, so device memory is
+        # O(cohort × model), not O(num_clients × model) — the flagship
+        # configs (thousands of clients × ViT) never fit the full stack.
+        self.client_c = jax.tree.map(
+            lambda w: np.zeros((self.num_clients,) + w.shape, w.dtype),
+            self.params,
+        ) if self.plan.scaffold else None
+        # The clip norm is a device scalar: an operand of every round and,
+        # under adaptive clipping, a metric fed into the next.
+        self._dp_clip = self._on_mesh(jnp.float32(c.fed.dp_clip))
+        # RDP accountant: cumulative (ε, δ) per round when DP is on
+        # (privacy/accountant.py; each round is one subsampled Gaussian
+        # mechanism with q = cohort / N at central noise σ).
+        self.accountant = RdpAccountant.from_config(
+            c.fed, sampling_rate=self.plan.dp_cohort / self.real_num_clients
+        )
+        self.base_key = prng.experiment_key(c.run.seed)
+        # CompileTracker fingerprints every call's abstract signature: the
+        # expected first compile lands in telemetry.compile_total with the
+        # seconds it blocked in telemetry.compile_seconds, any LATER new
+        # signature is a recompile with an attributed reason
+        # (telemetry.recompile_total{fn,reason}) — a coordinator silently
+        # recompiling every round becomes a visible counter + round-record
+        # field.  Attribute access (.lower) passes through to the jitted fn.
+        self._round_fn = telemetry.CompileTracker(
+            programs.build_round_fn(
+                self.plan, self.local_update,
+                jax.tree.map(lambda l: l.sharding, self.server_state)),
+            name="engine.round")
+        self._eval_fn = telemetry.CompileTracker(
+            self._build_eval_fn(), name="engine.eval")
+        self._device_data = self._place_data()
+        self.history: list[dict] = []
+        self._ckpt = None
 
-        # --- mesh axes ------------------------------------------------
-        # 1-D mesh: clients only.  2-D (attn_impl="ring"): + an inner ``seq``
-        # axis (sequence parallelism; parallel/ring.py).  A ``model`` axis
-        # (parallel/tp.py) adds tensor/expert parallelism: it is left to the
-        # AUTOMATIC partitioner (shard_map axis_names excludes it), params
-        # are sharded over it by the TP rules, and XLA inserts the TP
-        # collectives inside each client's local step.
-        self.client_axis = c.run.mesh_axis
-        self.seq_axis = c.run.seq_axis
-        self.tp_axis = c.run.tp_axis
-        if mesh is not None:
-            if self.client_axis not in mesh.shape:
-                raise ValueError(
-                    f"mesh axes {tuple(mesh.shape)} lack the client axis "
-                    f"{self.client_axis!r}"
-                )
-            self.clients_size = mesh.shape[self.client_axis]
-            self.seq_size = mesh.shape.get(self.seq_axis, 1)
-            self.tp_size = mesh.shape.get(self.tp_axis, 1)
-            extra = set(mesh.shape) - {
-                self.client_axis, self.seq_axis, self.tp_axis
-            }
-            if extra:
-                raise ValueError(f"unsupported mesh axes {sorted(extra)}")
-        else:
-            self.clients_size = 1
-            self.seq_size = 1
-            self.tp_size = 1
+    def _read_mesh_axes(self):
+        """1-D mesh: clients only.  2-D (attn_impl="ring"): + an inner
+        ``seq`` axis (sequence parallelism; parallel/ring.py).  A ``model``
+        axis (parallel/tp.py) adds tensor/expert parallelism: it is left to
+        the AUTOMATIC partitioner (shard_map axis_names excludes it), params
+        are sharded over it by the TP rules, and XLA inserts the TP
+        collectives inside each client's local step."""
+        c, mesh = self.config, self.mesh
+        axes = (c.run.mesh_axis, c.run.seq_axis, c.run.tp_axis)
+        shape = mesh.shape if mesh is not None else {}
+        if mesh is not None and axes[0] not in shape:
+            raise ValueError(
+                f"mesh axes {tuple(shape)} lack the client axis "
+                f"{axes[0]!r}"
+            )
+        extra = set(shape) - set(axes)
+        if extra:
+            raise ValueError(f"unsupported mesh axes {sorted(extra)}")
+        self.seq_size = shape.get(c.run.seq_axis, 1)
+        self.tp_size = shape.get(c.run.tp_axis, 1)
         self.sp = self.seq_size > 1
         if self.sp and c.model.attn_impl not in ("ring", "ulysses"):
             raise ValueError(
-                f"a {self.seq_size}-way {self.seq_axis!r} mesh axis requires "
+                f"a {self.seq_size}-way {c.run.seq_axis!r} mesh axis requires "
                 "model.attn_impl='ring' or 'ulysses'"
             )
         if (c.model.attn_impl in ("ring", "ulysses") and mesh is not None
                 and not self.sp):
             raise ValueError(
                 f"attn_impl={c.model.attn_impl!r} on a mesh requires a "
-                f"{self.seq_axis!r} axis of size > 1"
+                f"{c.run.seq_axis!r} axis of size > 1"
             )
 
-        # --- data -----------------------------------------------------
-        self.dataset = dataset or data_registry.get_dataset(
-            c.data.dataset, seed=c.run.seed
-        )
+    def _pack_shards(self, partitions):
+        """The train split packed into per-client shards: ``shards``,
+        ``client_ids``, ``num_clients`` (slots) and ``real_num_clients``."""
+        c = self.config
         labels = np.asarray(self.dataset.y_train)
         parts = (partitions if partitions is not None
                  else setup_lib.partition_for_config(c, labels))
@@ -223,7 +271,7 @@ class FederatedLearner:
             if seq_len % self.seq_size:
                 raise ValueError(
                     f"seq_len {seq_len} is not divisible by the "
-                    f"{self.seq_size}-way {self.seq_axis!r} axis"
+                    f"{self.seq_size}-way {c.run.seq_axis!r} axis"
                 )
             if (c.model.attn_impl == "ulysses"
                     and c.model.num_heads % self.seq_size):
@@ -232,17 +280,17 @@ class FederatedLearner:
                 raise ValueError(
                     f"attn_impl='ulysses' needs num_heads "
                     f"({c.model.num_heads}) divisible by the "
-                    f"{self.seq_size}-way {self.seq_axis!r} axis; use "
+                    f"{self.seq_size}-way {c.run.seq_axis!r} axis; use "
                     "attn_impl='ring'"
                 )
-        if mesh is not None:
-            shards = pad_clients_to_multiple(shards, self.clients_size)
+        if self.mesh is not None:
+            D = self.mesh.shape[c.run.mesh_axis]
+            shards = pad_clients_to_multiple(shards, D)
             # Interleave so real clients spread evenly across devices (ghost
             # padding would otherwise pile onto the last devices and starve
             # their per-device cohorts).  ``client_ids[slot]`` is the
             # ORIGINAL client identity of each array slot; all PRNG is keyed
             # on it, keeping results placement-independent.
-            D = self.clients_size
             L = shards.num_clients // D
             order = np.array(
                 [j * D + d for d in range(D) for j in range(L)], dtype=np.int32
@@ -256,15 +304,16 @@ class FederatedLearner:
         self.shards = shards
         self.num_clients = shards.num_clients
 
-        # --- model ----------------------------------------------------
-        # Under SP the trained module runs on sequence SHARDS inside
-        # shard_map; its dense-attention twin (identical param pytree) is
-        # used for init and full-sequence evaluation outside the mesh.
+    def _init_model(self):
+        """Under SP the trained module runs on sequence SHARDS inside
+        shard_map; its dense-attention twin (identical param pytree) is
+        used for init and full-sequence evaluation outside the mesh."""
+        c = self.config
         train_model_cfg = (
             c.model if self.sp else setup_lib.local_model_config(c.model)
         )
         self.model = model_registry.build_model(
-            train_model_cfg, seq_axis_name=self.seq_axis if self.sp else None
+            train_model_cfg, seq_axis_name=c.run.seq_axis if self.sp else None
         )
         if self.sp:
             self.eval_model = model_registry.build_model(
@@ -272,7 +321,7 @@ class FederatedLearner:
             )
         else:
             self.eval_model = self.model
-        example_x = jnp.asarray(shards.x[0, : c.fed.batch_size])
+        example_x = jnp.asarray(self.shards.x[0, : c.fed.batch_size])
         ikey = prng.init_key(prng.experiment_key(c.run.seed))
         self.params = model_registry.init_params(self.eval_model, example_x, ikey)
         if self.tp_size > 1:
@@ -282,191 +331,21 @@ class FederatedLearner:
             # state lives TP-sharded from the start.
             from colearn_federated_learning_tpu.parallel import tp as tp_lib
 
-            self.params = tp_lib.shard_params(self.params, mesh, self.tp_axis)
+            self.params = tp_lib.shard_params(self.params, self.mesh,
+                                              c.run.tp_axis)
         self.server_state = self._on_mesh(
             strategies.init_server_state(self.params, c.fed))
 
-        # --- local trainer -------------------------------------------
-        self.scaffold = c.fed.strategy == "scaffold"
-        self.fednova = c.fed.strategy == "fednova"
-        if c.fed.secure_agg and c.fed.secure_agg_neighbors and (
-            c.fed.secure_agg_neighbors % 2 or c.fed.secure_agg_neighbors < 2
-        ):
-            raise ValueError(
-                "secure_agg_neighbors must be an even integer >= 2, got "
-                f"{c.fed.secure_agg_neighbors}"
-            )
-        if c.fed.secure_agg and not 0.0 < c.fed.secure_agg_threshold <= 1.0:
-            raise ValueError(
-                "secure_agg_threshold must be in (0, 1], got "
-                f"{c.fed.secure_agg_threshold}"
-            )
-        if self.scaffold and (c.fed.secure_agg or c.fed.dp_clip > 0.0):
-            raise ValueError(
-                "scaffold is incompatible with secure_agg/dp hooks: the "
-                "control-variate deltas are a second payload the masks and "
-                "noise calibration do not cover"
-            )
-        if self.scaffold and self.tp_size > 1:
-            raise ValueError(
-                "scaffold with a model (TP) axis is unsupported: the "
-                "host-resident variate store is unsharded and the per-round "
-                "gather/scatter would funnel TP shards through one host"
-            )
-        # Byzantine-robust aggregation (fed/robust.py).
-        from colearn_federated_learning_tpu.fed.robust import AGGREGATORS
-
-        if c.fed.aggregator not in AGGREGATORS:
-            raise ValueError(
-                f"unknown aggregator {c.fed.aggregator!r}; use {AGGREGATORS}"
-            )
-        self.robust = c.fed.aggregator != "mean"
-        if self.robust:
-            if not 0.0 <= c.fed.trim_fraction < 0.5:
-                raise ValueError(
-                    "trim_fraction must be in [0, 0.5), got "
-                    f"{c.fed.trim_fraction}"
-                )
-            if c.fed.secure_agg:
-                raise ValueError(
-                    "robust aggregators need the individual updates; "
-                    "secure-agg masks only cancel in a plain sum"
-                )
-            if self.scaffold:
-                raise ValueError(
-                    "scaffold assumes mean aggregation of its control "
-                    "variates; use aggregator='mean'"
-                )
-            if c.fed.dp_noise_multiplier > 0.0:
-                raise ValueError(
-                    "robust aggregation of noised updates is not the "
-                    "Gaussian mechanism the RDP accountant models; use "
-                    "dp_clip alone (norm bounding) with robust aggregators"
-                )
-        self.local_update, self.num_steps = setup_lib.local_trainer_for_config(
-            c, self.model.apply, shards.capacity,
-            grad_sync_axes=(self.seq_axis,) if self.sp else (),
-            param_axes=(self.tp_axis,) if self.tp_size > 1 else (),
+    def _local_trainer(self, config):
+        """``config``'s ``local_update`` around the trained module, with
+        this learner's mesh wiring: gradients synchronised over ``seq``
+        under SP, parameters laid over ``model`` under TP."""
+        update, _ = setup_lib.local_trainer_for_config(
+            config, self.model.apply, self.shards.capacity,
+            grad_sync_axes=(config.run.seq_axis,) if self.sp else (),
+            param_axes=(config.run.tp_axis,) if self.tp_size > 1 else (),
         )
-        # SCAFFOLD per-client control variates: one params-shaped pytree per
-        # client, stacked on the client axis — resident on HOST (numpy).
-        # Each round gathers only the COHORT's variates into the jit round
-        # program and scatters the updated block back, so device memory is
-        # O(cohort × model), not O(num_clients × model) — the flagship
-        # configs (thousands of clients × ViT) never fit the full stack.
-        if self.scaffold:
-            self.client_c = jax.tree.map(
-                lambda w: np.zeros((self.num_clients,) + w.shape, w.dtype),
-                self.params,
-            )
-        else:
-            self.client_c = None
-
-        # --- cohort ---------------------------------------------------
-        cohort = c.fed.cohort_size or self.num_clients
-        self.cohort_size = min(cohort, self.num_clients)
-        if mesh is not None:
-            d = self.clients_size
-            # per-device cohort must be equal and static
-            self.cohort_per_device = max(1, self.cohort_size // d)
-            adjusted = self.cohort_per_device * d
-            if adjusted != self.cohort_size:
-                import warnings
-
-                warnings.warn(
-                    f"cohort_size={self.cohort_size} is not a multiple of the "
-                    f"{d}-way client axis; using {adjusted} "
-                    f"({self.cohort_per_device}/device)",
-                    stacklevel=3,      # the caller of FederatedLearner(...)
-                )
-            self.cohort_size = adjusted
-        if (self.robust and c.fed.aggregator in ("trimmed_mean", "krum")
-                and int(c.fed.trim_fraction * self.cohort_size + 1e-4) < 1):
-            # floor(trim · cohort) == 0 trims/excludes nothing — the
-            # "robust" aggregate would silently be the plain mean while
-            # still paying uniform weights and the secure-agg/DP bans.
-            what = ("trims zero clients" if c.fed.aggregator == "trimmed_mean"
-                    else "assumes zero Byzantine clients (f = 0)")
-            if self.cohort_size < 3:
-                # Any fraction satisfying floor(trim·cohort) >= 1 here
-                # would breach the < 0.5 cap: no valid value exists.
-                raise ValueError(
-                    f"aggregator={c.fed.aggregator!r} needs a cohort of at "
-                    f"least 3 (got {self.cohort_size}); use "
-                    "aggregator='median'"
-                )
-            import math
-
-            # Round the suggestion UP so following it actually passes.
-            ok_frac = math.ceil(1e6 / self.cohort_size) / 1e6
-            raise ValueError(
-                f"trim_fraction={c.fed.trim_fraction} {what} at "
-                f"cohort_size={self.cohort_size}; raise it to at least "
-                f"{ok_frac:.6f} (or use aggregator='median')"
-            )
-        # DP noise accounting divides by the number of REAL clients expected
-        # to contribute (ghost padding never contributes).  If stragglers
-        # drop mid-round the realized central noise is below nominal — a
-        # known property of DP-FedAvg with dropouts; see privacy/dp.py.
-        self.dp_cohort = min(self.cohort_size, self.real_num_clients)
-        # Adaptive clipping (privacy/dp.py, quantile tracking): the clip
-        # norm is a DEVICE scalar threaded operand -> metric through the
-        # round program, so back-to-back rounds adapt it with no host sync.
-        self.adaptive_clip = c.fed.dp_adaptive_clip
-        if self.adaptive_clip:
-            if c.fed.dp_clip <= 0.0:
-                raise ValueError(
-                    "dp_adaptive_clip needs dp_clip > 0 as the initial norm"
-                )
-            z = c.fed.dp_noise_multiplier
-            if z > 0.0:
-                self.dp_bit_noise = c.fed.dp_bit_noise or max(
-                    self.dp_cohort / 20.0, 1.0
-                )
-                # The bit query spends part of the budget; the update noise
-                # is inflated so the JOINT per-round mechanism still costs
-                # the configured z — the accountant below stays valid as-is.
-                self.dp_z = dp_lib.adaptive_noise_multiplier(
-                    z, self.dp_bit_noise
-                )
-            else:
-                self.dp_bit_noise = 0.0
-                self.dp_z = 0.0
-        self._dp_clip = self._on_mesh(jnp.float32(c.fed.dp_clip))
-        # RDP accountant: cumulative (ε, δ) per round when DP is on
-        # (privacy/accountant.py; each round is one subsampled Gaussian
-        # mechanism with q = cohort / N at central noise σ).
-        from colearn_federated_learning_tpu.privacy.accountant import (
-            RdpAccountant,
-        )
-
-        self.accountant = RdpAccountant.from_config(
-            c.fed, sampling_rate=self.dp_cohort / self.real_num_clients
-        )
-
-        # --- compiled programs (construction: fed/programs.py) -------
-        # The per-program cohort width: full cohort on the vmap path, the
-        # per-device slice on the mesh path (cohort_step sizes its
-        # straggler-budget vector off this).
-        self.cohort_size_local = (
-            self.cohort_size if mesh is None else self.cohort_per_device
-        )
-        self.base_key = prng.experiment_key(c.run.seed)
-        # CompileTracker fingerprints every call's abstract signature: the
-        # expected first compile lands in telemetry.compile_total with the
-        # seconds it blocked in telemetry.compile_seconds, any LATER new
-        # signature is a recompile with an attributed reason
-        # (telemetry.recompile_total{fn,reason}) — a coordinator silently
-        # recompiling every round becomes a visible counter + round-record
-        # field.  Attribute access (.lower, for the perf script's AOT
-        # path) passes through to the jitted fn.
-        self._round_fn = telemetry.CompileTracker(
-            programs.build_round_fn(self), name="engine.round")
-        self._eval_fn = telemetry.CompileTracker(
-            self._build_eval_fn(), name="engine.eval")
-        self._device_data = self._place_data()
-        self.history: list[dict] = []
-        self._ckpt = None
+        return update
 
     # ------------------------------------------------------------------
     # data placement
@@ -497,17 +376,12 @@ class FederatedLearner:
             x, y = self.shards.x, self.shards.y
             counts, ids = self.shards.counts, self.client_ids
             if self.mesh is not None:
-                ax = self.client_axis
-                # Under SP each client's token dim is also sharded (last
-                # axis of the (clients, capacity, seq_len) block).
-                x_spec = (
-                    P(ax, None, self.seq_axis) if self.sp else P(ax)
-                )
                 # Straight from host memory to each device's own block:
                 # staging the whole array on one device first would make
                 # that device hold every client's data.
-                x = jax.device_put(x, NamedSharding(self.mesh, x_spec))
-                sh = NamedSharding(self.mesh, P(ax))
+                x = jax.device_put(
+                    x, NamedSharding(self.mesh, self.plan.x_spec))
+                sh = NamedSharding(self.mesh, P(self.plan.client_axis))
                 y, counts, ids = (
                     jax.device_put(a, sh) for a in (y, counts, ids)
                 )
@@ -527,10 +401,13 @@ class FederatedLearner:
     # similarity) -- the engine only orchestrates.
     # ------------------------------------------------------------------
     def _build_client_eval_fn(self):
-        # Thin delegate kept as a method: clustered FL swaps models in
-        # and rebuilds per-cluster programs through it (fed/clustered.py).
-        return programs.build_client_eval_fn(self)
-
+        # Kept as a method: clustered FL scores every cluster's model with
+        # the base learner's program through it (fed/clustered.py).  The
+        # shard data arrives as placed for training, sequence-sharded under
+        # SP, so the trained module scores it.
+        return programs.build_client_eval_fn(
+            self.plan, self.model.apply, self.shards.capacity,
+            eval_rows(self.config.fed.batch_size, self.shards.x[0]))
 
     # ------------------------------------------------------------------
     # evaluation (held-out global test set, SURVEY.md §3d)
@@ -562,9 +439,9 @@ class FederatedLearner:
     # public API
     # ------------------------------------------------------------------
     def _host_sample_cohort(self, round_idx: int):
-        """Cohort selection on HOST — same key derivation and ranking as the
-        in-program sampler, run eagerly so the scaffold path can gather the
-        cohort's variate rows before dispatching the round.
+        """Cohort selection on HOST — the program's own draw
+        (``programs.draw_cohort``) run eagerly, so the scaffold path can
+        gather the cohort's variate rows before dispatching the round.
 
         Returns ``(sel, rows)``: ``sel`` are the per-device-local slot
         indices the round program consumes; ``rows`` the absolute rows of
@@ -572,50 +449,31 @@ class FederatedLearner:
         """
         r = jnp.asarray(round_idx, jnp.int32)
         counts = jnp.asarray(self.shards.counts)
+
+        def draw(block, device=None):
+            return np.asarray(programs.draw_cohort(
+                self.plan, self.base_key, r, block, device)).astype(np.int32)
+
         if self.mesh is None:
-            if self.cohort_size < self.num_clients:
-                skey = prng.sampling_key(self.base_key, r)
-                sel = np.asarray(
-                    rank_cohort(skey, counts, self.cohort_size)
-                ).astype(np.int32)
-            else:
-                sel = np.arange(self.num_clients, dtype=np.int32)
+            sel = draw(counts)
             return sel, sel
-        D, cpd = self.clients_size, self.cohort_per_device
-        L = self.num_clients // D
-        skey = prng.sampling_key(self.base_key, r)
-        sels, rows = [], []
-        for d in range(D):
-            if cpd < L:
-                dkey = jax.random.fold_in(skey, d)
-                s = np.asarray(
-                    rank_cohort(dkey, counts[d * L:(d + 1) * L], cpd)
-                ).astype(np.int32)
-            else:
-                s = np.arange(L, dtype=np.int32)
-            sels.append(s)
-            rows.append(d * L + s)
+        L = self.plan.local_clients
+        sels = [draw(counts[d * L:(d + 1) * L], d)
+                for d in range(self.plan.clients_size)]
+        rows = [d * L + s for d, s in enumerate(sels)]
         return np.concatenate(sels), np.concatenate(rows)
 
-    def run_round(self, sync: bool = True) -> dict:
-        """One federated round.  ``sync=False`` skips the host conversion of
-        the round metrics (they stay as device scalars), so back-to-back
-        rounds pipeline on the device: dispatch is asynchronous, and a
-        device→host read per round would make the host wait for each
-        round before enqueueing the next.  (SCAFFOLD rounds still
-        synchronize regardless: the cohort-resident variate gather/scatter
-        is a per-round host⇄device exchange by design.)  Call
-        :meth:`finalize_history` after a ``sync=False`` loop to materialize
-        the floats."""
-        out, phases = self._dispatch_round(sync)
+    def run_round(self) -> dict:
+        """One federated round, its metrics read back to the host."""
+        out, phases = self._dispatch_round()
         with self.tracer.span("bookkeeping", round=len(self.history)):
             return self._record_round(out, phases)
 
-    def _dispatch_round(self, sync: bool) -> tuple[dict, tuple]:
+    def _dispatch_round(self) -> tuple[dict, tuple]:
         """Enqueue the round program and read its metrics: the round's
         metrics and the spans its record takes its phase fields from."""
         r = len(self.history)
-        if self.scaffold:
+        if self.plan.scaffold:
             # Gather the cohort's variates from the host store; scatter the
             # refreshed block back afterwards (device memory stays
             # O(cohort × model)).
@@ -624,7 +482,7 @@ class FederatedLearner:
                 c_cohort = jax.tree.map(lambda l: l[rows], self.client_c)
                 sel_dev = jnp.asarray(sel)
                 if self.mesh is not None:
-                    sh = NamedSharding(self.mesh, P(self.client_axis))
+                    sh = NamedSharding(self.mesh, P(self.plan.client_axis))
                     sel_dev = jax.device_put(sel_dev, sh)
                     c_cohort = jax.tree.map(
                         lambda l: jax.device_put(jnp.asarray(l), sh), c_cohort
@@ -649,11 +507,11 @@ class FederatedLearner:
                 c_cohort,
                 self._dp_clip,
             )
-        if self.adaptive_clip:
+        if self.plan.adaptive_clip:
             # Feed the adapted clip into the next round as a device scalar
-            # (no host round-trip; sync=False rounds keep pipelining).
+            # (no host round-trip).
             self._dp_clip = metrics["dp_clip"]
-        if self.scaffold:
+        if self.plan.scaffold:
             with self.tracer.span("scatter_variates", round=r):
                 updated = jax.tree.map(np.asarray, new_c)
 
@@ -663,13 +521,10 @@ class FederatedLearner:
 
                 self.client_c = jax.tree.map(scatter, self.client_c, updated)
         with self.tracer.span("sync_metrics", round=r) as sync_sp:
-            if sync:
-                # ONE batched device→host transfer for the whole metrics
-                # dict instead of a blocking read per scalar.
-                out = {k: float(v)
-                       for k, v in jax.device_get(metrics).items()}
-            else:
-                out = dict(metrics)      # device scalars; sync deferred
+            # ONE batched device→host transfer for the whole metrics
+            # dict instead of a blocking read per scalar.
+            out = {k: float(v)
+                   for k, v in jax.device_get(metrics).items()}
         return out, (update_sp, sync_sp, sample_sp)
 
     def _record_round(self, out: dict, phases: tuple) -> dict:
@@ -692,40 +547,6 @@ class FederatedLearner:
             out["dp_delta"] = self.accountant.delta
         self.history.append(out)
         return out
-
-    def round_cost_analysis(self) -> dict:
-        """XLA's own cost analysis of the compiled round program for the
-        CURRENT operand shapes (AOT lower+compile, cached per signature
-        by the tracker).  ``flops_per_round`` applies the local-SGD trip
-        count: XLA counts a while/scan BODY ONCE (trip counts are not
-        modeled) and the local-SGD scan holds essentially all the FLOPs —
-        the reported count is identical for local_steps=1 and
-        local_steps=8 — so the per-round figure scales by num_steps."""
-        if self.scaffold:
-            sel, rows = self._host_sample_cohort(0)
-            c_cohort = jax.tree.map(lambda l: l[rows], self.client_c)
-            sel_dev = jnp.asarray(sel)
-        else:
-            sel_dev, c_cohort = None, None
-        cost = self._round_fn.cost_analysis(
-            self.server_state, self.base_key, jnp.asarray(0, jnp.int32),
-            *self._device_data, sel_dev, c_cohort, self._dp_clip,
-        )
-        if cost.get("flops"):
-            cost["flops_per_round"] = cost["flops"] * self.num_steps
-        return cost
-
-    def finalize_history(self) -> list[dict]:
-        """Materialize any deferred (``sync=False``) round metrics to floats
-        — blocks until the device work that produced them is done.  The
-        whole history is fetched in ONE batched transfer."""
-        fetched = jax.device_get(self.history)
-        self.history = [
-            {k: (float(v) if hasattr(v, "dtype") else v)
-             for k, v in rec.items()}
-            for rec in fetched
-        ]
-        return self.history
 
     def evaluate(self) -> tuple[float, float]:
         loss, acc = self._eval_fn(self.server_state.params)
@@ -802,14 +623,15 @@ class FederatedLearner:
         output; rows/cols are then returned to ORIGINAL client-id order
         with ghost padding dropped.
         """
-        if self.scaffold:
+        if self.plan.scaffold:
             raise NotImplementedError(
                 "clustering uses the plain local trainer; run it with a "
                 "stateless strategy"
             )
         if getattr(self, "_sim_key", None) != steps:
             self._sim_key = steps
-            self._sim_fn = programs.build_similarity_fn(self, steps)
+            self._sim_fn = programs.build_similarity_fn(
+                self.plan, self.local_update, steps)
         sim = np.asarray(self._sim_fn(
             self.server_state.params, *self._device_data, self.base_key
         ))
@@ -852,9 +674,21 @@ class FederatedLearner:
         """
         key = (steps, lr)
         if getattr(self, "_pers_eval_key", None) != key:
+            # The fine-tune is the CONFIG's local trainer (same optimizer,
+            # momentum, MoE aux loss, prox term) with the step budget and
+            # lr overridden.
+            fed = self.config.fed
+            fine_tune = self._local_trainer(self.config.replace(
+                fed=dataclasses.replace(
+                    fed,
+                    strategy="fedprox" if fed.strategy == "fedprox"
+                    else "fedavg",
+                    local_steps=steps, lr=lr if lr is not None else fed.lr,
+                    straggler_prob=0.0)))
             self._pers_eval_fn = programs.build_personalized_eval_fn(
-                self, steps, lr if lr is not None else self.config.fed.lr
-            )
+                self.plan, self.model.apply, fine_tune, self.shards.capacity,
+                eval_rows(fed.batch_size, self.shards.x[0]), self.base_key,
+                steps)
             self._pers_eval_key = key
         g_acc, p_acc, n_eval = self._pers_eval_fn(
             self.server_state.params, *self._device_data
@@ -911,7 +745,7 @@ class FederatedLearner:
         if self.accountant is not None:
             # ε must account for every round already spent before the kill.
             self.accountant.steps = step
-        if self.adaptive_clip and history:
+        if self.plan.adaptive_clip and history:
             # The clip state rides the per-round metrics (one scalar per
             # record), so resume continues from the adapted norm.
             self._dp_clip = jnp.float32(history[-1]["dp_clip"])
@@ -954,7 +788,7 @@ class FederatedLearner:
         eval_every = max(1, run.eval_every)
         log_every = max(1, run.log_every)
         ckpt_every = max(0, run.checkpoint_every)
-        out, phases = self._dispatch_round(sync=True)
+        out, phases = self._dispatch_round()
         r = len(self.history)
         with self.tracer.span("bookkeeping", round=r):
             rec = self._record_round(out, phases)

@@ -44,7 +44,7 @@ from colearn_federated_learning_tpu import telemetry
 from colearn_federated_learning_tpu.fed import compression
 from colearn_federated_learning_tpu.fed import setup as setup_lib
 from colearn_federated_learning_tpu.fed import strategies
-from colearn_federated_learning_tpu.fed.programs import rank_cohort
+from colearn_federated_learning_tpu.fed.programs import draw_cohort
 from colearn_federated_learning_tpu.utils import prng, pytrees
 from colearn_federated_learning_tpu.utils.config import ExperimentConfig
 from colearn_federated_learning_tpu.utils.serialization import (
@@ -272,9 +272,10 @@ class FleetSim:
                      fault_plan=None,
                      round_deadline_ms: float = 1000.0) -> "FleetSim":
         """Wrap a vmap-path :class:`FederatedLearner`: same shards, same
-        trainer closure, same base key, same host cohort ranking — the
-        ONLY difference from ``learner.run_round()`` is the chunked
-        dispatch, which is exactly what the parity tests pin down."""
+        trainer closure, same base key, the round program's own cohort
+        draw — the ONLY difference from ``learner.run_round()`` is the
+        chunked dispatch, which is exactly what the parity tests pin
+        down."""
         if learner.mesh is not None:
             raise NotImplementedError(
                 "from_learner wraps the single-device vmap path; shard "
@@ -286,14 +287,9 @@ class FleetSim:
         base_key = learner.base_key
 
         def select(round_idx: int) -> np.ndarray:
-            # Mirrors fed/engine._host_sample_cohort (vmap branch): same
-            # key, same ranking function, eager.
-            if cohort < num_clients:
-                skey = prng.sampling_key(
-                    base_key, jnp.asarray(round_idx, jnp.int32))
-                return np.asarray(
-                    rank_cohort(skey, counts_dev, cohort)).astype(np.int64)
-            return np.arange(num_clients, dtype=np.int64)
+            return np.asarray(draw_cohort(
+                learner.plan, base_key, jnp.asarray(round_idx, jnp.int32),
+                counts_dev)).astype(np.int64)
 
         def shard_slices(ids: np.ndarray) -> tuple:
             return shards.x[ids], shards.y[ids], shards.counts[ids]

@@ -19,7 +19,7 @@ arrival process:
 ``sample_cohort`` ranks the currently-available devices by a per-round
 hashed score and takes the first ``cohort_size`` — uniform sampling
 without replacement among available devices, the host-side analog of
-the engine's ``_rank_cohort``.
+the engine's ``fed.programs.rank_cohort``.
 """
 
 from __future__ import annotations

@@ -248,7 +248,7 @@ def compiled_cost(fn, *args, **kwargs) -> dict:
     ``cost_analysis`` dict plus ``compile_s``.  ``{}``-valued keys when
     the backend reports nothing (CPU often does).  NOTE: XLA counts a
     while/scan body ONCE — callers whose FLOPs live in a scan must scale
-    by the trip count themselves (fed/engine.round_cost_analysis does)."""
+    by the trip count themselves."""
     if not hasattr(fn, "lower"):
         return {}
     t0 = time.perf_counter()

@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives.
 
-Every entry point (``cli.main``, ``bench.py``, ``chip_smoke.py``,
+Every entry point (``cli.main``, ``chip_smoke.py``,
 ``__graft_entry__.py``, the scripts, ``tests/conftest.py``) calls
 :func:`enable_compile_cache` once, before its first compile:
 

@@ -88,7 +88,7 @@ def run_curve(partition: str, rounds: int, lr: float, eval_every: int,
     curve, reached = [], None
     t0 = time.perf_counter()
     for r in range(1, rounds + 1):
-        learner.run_round(sync=False)
+        learner.run_round()
         if r % eval_every == 0 or r == rounds:
             _, acc = learner.evaluate()
             acc = float(acc)

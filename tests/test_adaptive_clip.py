@@ -84,7 +84,7 @@ def test_adaptive_with_noise_accounts_single_mechanism():
     # bit query's cost is folded in by the inflated update noise).
     cfg = _cfg(dp_noise_multiplier=0.8, dp_bit_noise=2.0)
     learner = FederatedLearner(cfg)
-    assert learner.dp_z > 0.8          # inflated update noise
+    assert learner.plan.dp_z > 0.8          # inflated update noise
     rec = learner.run_round()
     assert rec["dp_epsilon"] > 0.0 and np.isfinite(rec["dp_epsilon"])
 

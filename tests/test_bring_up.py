@@ -2,7 +2,6 @@
 compile cache must be placeable from outside."""
 
 import os
-import sys
 
 import jax
 import pytest
@@ -73,14 +72,3 @@ def test_unparseable_tpu_device_kind_is_an_error(monkeypatch):
         assert attention._tpu_generation() == 5
     finally:
         attention._tpu_generation.cache_clear()
-
-
-def test_mfu_of_unknown_device_is_an_error():
-    sys.path.insert(0, os.path.join(_ROOT, "scripts"))
-    try:
-        import perf_north_star
-    finally:
-        sys.path.pop(0)
-    assert perf_north_star.peak_bf16_flops("TPU v5 lite") == 197e12
-    with pytest.raises(KeyError, match="TPU v9"):
-        perf_north_star.peak_bf16_flops("TPU v9")

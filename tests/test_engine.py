@@ -3,9 +3,12 @@ data, 2-layer MLP, 10 simulated clients on one device via vmap, FedAvg
 in-XLA, accuracy rising across rounds (BASELINE config #1 scaled down)."""
 
 import dataclasses
+import re
 
 import numpy as np
+import pytest
 
+from colearn_federated_learning_tpu.fed import engine, evaluation
 from colearn_federated_learning_tpu.fed.engine import FederatedLearner
 from colearn_federated_learning_tpu.utils.config import (
     DataConfig,
@@ -145,6 +148,7 @@ def test_bert_working_set_of_rows_matches_the_dense_trainer():
     import jax
 
     from colearn_federated_learning_tpu import telemetry
+    from colearn_federated_learning_tpu.fed import programs
     from colearn_federated_learning_tpu.fed import setup as setup_lib
 
     cfg = ExperimentConfig(
@@ -174,8 +178,10 @@ def test_bert_working_set_of_rows_matches_the_dense_trainer():
     twin = Undeclared(**{f.name: getattr(dense.model, f.name)
                          for f in dataclasses.fields(dense.model)
                          if f.name not in ("parent", "name")})
-    dense.local_update, _ = setup_lib.local_trainer_for_config(
+    update, _ = setup_lib.local_trainer_for_config(
         cfg, twin.apply, dense.shards.capacity)
+    dense._round_fn = telemetry.CompileTracker(
+        programs.build_round_fn(dense.plan, update), name="engine.round")
     dense.fit(rounds=2)
     assert compacted.value - before == 1              # the dense one did not
     np.testing.assert_allclose([r["train_loss"] for r in learner.history],
@@ -184,3 +190,33 @@ def test_bert_working_set_of_rows_matches_the_dense_trainer():
     for a, b in zip(jax.tree.leaves(learner.server_state.params),
                     jax.tree.leaves(dense.server_state.params)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("on_mesh", [False, True], ids=["vmap", "mesh4"])
+def test_names_the_benchmark_reads(on_mesh, cpu_devices):
+    """``benchmarks/`` tells the programs of a device trace by the jitted
+    functions' names and reads these attributes off the learner: a rename
+    here is a change of the yardstick."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(cpu_devices[:4]), ("clients",)) if on_mesh else None
+    learner = FederatedLearner(tiny_config(cohort_size=4), mesh=mesh)
+
+    def module_name(lowered):
+        return re.match(r"module @(\S+)", lowered.as_text()).group(1)
+
+    round_program = module_name(learner._round_fn.lower(
+        learner.server_state, learner.base_key, jnp.asarray(0, jnp.int32),
+        *learner._device_data, None, None, learner._dp_clip))
+    assert round_program == ("jit_body" if on_mesh else "jit_round_fn")
+    assert "eval" in module_name(
+        learner._eval_fn.lower(learner.server_state.params))
+    assert (learner._round_fn.name, learner._eval_fn.name) == (
+        "engine.round", "engine.eval")
+    assert engine.make_eval_fn is evaluation.make_eval_fn    # faults.py patches it
+    for name in ("params", "history", "dataset", "model", "server_state",
+                 "devices", "num_steps", "cohort_size", "fit", "from_config"):
+        assert hasattr(learner, name), name
+    assert len(learner.devices) == (4 if on_mesh else 1)
+    assert learner.cohort_size == 4 == learner.plan.cohort_size

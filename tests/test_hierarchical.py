@@ -100,7 +100,7 @@ def test_hierarchical_composes_with_robust_aggregation():
     cfg = _cfg()
     cfg = cfg.replace(fed=dataclasses.replace(cfg.fed, aggregator="median"))
     h = HierarchicalLearner(cfg, num_groups=2, sync_period=2)
-    assert all(g.robust for g in h.groups)
+    assert all(g.plan.robust for g in h.groups)
     hist = h.fit(rounds=6)
     assert np.isfinite(hist[-1]["train_loss"])
     _, acc = h.evaluate()

@@ -513,10 +513,9 @@ def test_cl010_exempts_cli_scripts_and_main_guards(tmp_path):
         def report(x):
             print(x)
     """
-    # cli.py and bench.py ARE the stdout contract (machine-readable
-    # summary lines); scripts/ is operator tooling.
+    # cli.py IS the stdout contract (machine-readable summary lines);
+    # scripts/ is operator tooling.
     assert run_lint(tmp_path, src, relpath="pkg/cli.py").findings == []
-    assert run_lint(tmp_path, src, relpath="pkg/bench.py").findings == []
     assert run_lint(tmp_path, src,
                     relpath="pkg/scripts/tool.py").findings == []
     # __main__ guard: the module is being run AS a script.

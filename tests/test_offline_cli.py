@@ -113,66 +113,3 @@ def test_serialization_rejects_list_nodes(tmp_path):
         serialization.save_pytree_npz(
             str(tmp_path / "x.npz"), {"layers": [np.zeros(3), np.zeros(3)]}
         )
-
-
-def test_cli_bench_parses_forwarded_args(monkeypatch, capsys):
-    # `colearn bench` must forward its own argv to bench.main (it used to
-    # re-parse sys.argv and die on the 'bench' token) and return its exit
-    # code; stub the accelerator and the workload and check the wiring.
-    import jax
-
-    from colearn_federated_learning_tpu import bench
-
-    class FakeTpu:
-        platform, device_kind = "tpu", "TPU v5 lite"
-
-    monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu()])
-    monkeypatch.setattr(
-        bench, "run_tpu_native",
-        lambda rounds, warmup: {
-            "rounds_per_sec": float(rounds),
-            "client_samples_per_sec_per_chip": 1.0,
-            "n_devices": 1, "rounds_timed": rounds, "seconds_timed": 1.0,
-            "platform": "tpu", "device_kind": "TPU v5 lite",
-        })
-    rc = cli.main(["bench", "--rounds", "3", "--skip-baseline"])
-    assert rc == 0
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["value"] == 3.0 and rec["unit"] == "rounds/sec"
-    assert rec["platform"] == "tpu" and rec["device_kind"] == "TPU v5 lite"
-    # The options of the probe-and-fall-back bench are gone.
-    for flag in ("--force-cpu", "--probe-budget", "--min-time"):
-        with pytest.raises(SystemExit):
-            cli.main(["bench", flag, "1"])
-
-
-def test_bench_without_accelerator_fails_and_prints_no_result(
-        monkeypatch, capsys):
-    # No accelerator -> non-zero exit and nothing on stdout that looks
-    # like a result; the workload is never started on the CPU.
-    from colearn_federated_learning_tpu import bench
-
-    def never(*a, **k):
-        raise AssertionError("bench ran its workload without an accelerator")
-
-    monkeypatch.setattr(bench, "run_tpu_native", never)
-    assert cli.main(["bench", "--skip-baseline"]) == 1
-    assert capsys.readouterr().out.strip() == ""
-
-
-def test_bench_run_failure_is_not_swallowed(monkeypatch, capsys):
-    import jax
-
-    from colearn_federated_learning_tpu import bench
-
-    class FakeTpu:
-        platform, device_kind = "tpu", "TPU v5 lite"
-
-    def boom(rounds, warmup):
-        raise RuntimeError("RESOURCE_EXHAUSTED")
-
-    monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu()])
-    monkeypatch.setattr(bench, "run_tpu_native", boom)
-    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
-        bench.main(["--skip-baseline"])
-    assert capsys.readouterr().out.strip() == ""

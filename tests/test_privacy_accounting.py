@@ -104,7 +104,7 @@ def test_engine_reports_cumulative_epsilon():
     assert all(np.isfinite(e) and e > 0 for e in eps)
     assert eps[0] < eps[1] < eps[2]        # budget strictly accumulates
     # matches a freshly composed accountant for the same mechanism
-    ref = RdpAccountant(1.0, learner.dp_cohort / learner.real_num_clients,
+    ref = RdpAccountant(1.0, learner.plan.dp_cohort / learner.real_num_clients,
                         delta=1e-5)
     ref.step(3)
     assert eps[-1] == pytest.approx(ref.epsilon(), rel=1e-12)

@@ -115,11 +115,13 @@ def test_round_spans_tile_the_round(tmp_path):
                           key=lambda s: s.start_ns)
         assert [s.name for s in children] == [
             "enqueue", "sync_metrics", "bookkeeping", "evaluate", "log"]
-        # One after the other, no overlap, and next to nothing between.
+        # One after the other inside the round, no overlap.  (How much
+        # of the round they cover is a time: the chip's trace says, as
+        # `host_gap_ms_per_round`'s remainder, not a shared CPU.)
+        assert r.start_ns <= children[0].start_ns
+        assert children[-1].end_ns <= r.end_ns
         for a, b in zip(children, children[1:]):
             assert a.end_ns <= b.start_ns
-        covered = sum(s.end_ns - s.start_ns for s in children)
-        assert covered >= 0.95 * (r.end_ns - r.start_ns)
 
 
 def test_recording_fit_builds_the_round_program_once(tmp_path):

@@ -128,13 +128,6 @@ def test_scaffold_rejected_by_stateless_paths(tmp_path):
         DeviceWorker(cfg, 0)
 
 
-def test_scaffold_rejects_privacy_hooks():
-    cfg = _cfg()
-    cfg = cfg.replace(fed=dataclasses.replace(cfg.fed, secure_agg=True))
-    with pytest.raises(ValueError, match="incompatible"):
-        FederatedLearner(cfg)
-
-
 def test_scaffold_checkpoint_roundtrip(tmp_path):
     cfg = _cfg()
     cfg = cfg.replace(run=dataclasses.replace(

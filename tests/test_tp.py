@@ -8,7 +8,6 @@ to the single-device vmap path.
 
 import jax
 import numpy as np
-import pytest
 from jax.sharding import PartitionSpec as P
 
 from colearn_federated_learning_tpu.fed.engine import FederatedLearner
@@ -208,10 +207,3 @@ def test_tp_checkpoint_roundtrip(cpu_devices, tmp_path):
     p_after = np.concatenate([np.ravel(np.asarray(x))
                               for x in jax.tree.leaves(b.server_state.params)])
     np.testing.assert_array_equal(p_before, p_after)
-
-
-def test_scaffold_rejects_tp(cpu_devices):
-    cfg = _bert_cfg(strategy="scaffold", momentum=0.0)
-    mesh = make_mesh(("clients", "model"), (4, 2), devices=cpu_devices[:8])
-    with pytest.raises(ValueError, match="scaffold"):
-        FederatedLearner(cfg, mesh=mesh)
