@@ -173,6 +173,9 @@ GAUGES = (
     # models/cnn.py, set on every build: stages computed on the view that
     # folds two columns into the channel axis (0: the plain path)
     "cnn.lane_folded_stages",
+    # models/evabyte.py, set on every build: named arrays a rematerialised
+    # block keeps for its backward (0: ``remat`` is off, everything is kept)
+    "evabyte.remat_saved_arrays",
     "fleetsim.devices",
     "fleetsim.chunk_size",
     "fleetsim.available_fraction",

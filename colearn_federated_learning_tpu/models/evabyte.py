@@ -23,8 +23,11 @@ and ``phi`` N(0, 1).
 from __future__ import annotations
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
+from colearn_federated_learning_tpu import telemetry
+from colearn_federated_learning_tpu.ops.attention import FLASH_RESIDUAL_NAMES
 from colearn_federated_learning_tpu.ops.eva import eva_attention
 
 RMS_NORM_EPS = 1e-5
@@ -113,7 +116,10 @@ class EvaByte(nn.Module):
     rope_theta: float = 100000.0
     dtype: jnp.dtype = jnp.float32
     attn_impl: str = "flash"
-    # Rematerialize each block under autodiff (models/bert.py ditto).
+    # Rematerialize each block under autodiff, but for the attention
+    # kernel's output and log-sum: kept (136 MB a layer at the published
+    # widths), they spare the backward a second ``flash_fwd``, the slowest
+    # unit of the block to repeat.
     remat: bool = False
 
     @nn.compact
@@ -127,7 +133,14 @@ class EvaByte(nn.Module):
         h = nn.Embed(self.vocab_size, self.embed_dim, dtype=self.dtype,
                      embedding_init=nn.initializers.normal(INIT_STD),
                      name="embed")(ids).astype(jnp.float32)
-        block_cls = nn.remat(EvaBlock) if self.remat else EvaBlock
+        block_cls = EvaBlock
+        if self.remat:
+            block_cls = nn.remat(
+                EvaBlock, policy=jax.checkpoint_policies.save_only_these_names(
+                    *FLASH_RESIDUAL_NAMES))
+        # Set at trace time, on every build: 0 says nothing is rematerialised.
+        telemetry.get_registry().gauge("evabyte.remat_saved_arrays").set(
+            len(FLASH_RESIDUAL_NAMES) if self.remat else 0)
         for i in range(self.depth):
             # Explicit names pin param paths across remat (models/bert.py).
             h = block_cls(self.num_heads, self.ffn_dim, self.window,

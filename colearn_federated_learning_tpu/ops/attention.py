@@ -31,11 +31,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from colearn_federated_learning_tpu import telemetry
 
 _NEG = -1e30
+# What the forward leaves for the backward besides its own arguments, by the
+# names a ``jax.checkpoint`` policy may ask for (``save_only_these_names``):
+# a rematerialised block that keeps both does not run ``flash_fwd`` again.
+# Under any other policy, and outside a checkpoint, a name is the identity.
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 @functools.lru_cache(maxsize=None)
@@ -420,6 +426,12 @@ def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
                prefix):
     out, lse = _flash_impl(q, k, v, kv_mask, causal, block_q, block_k,
                            interpret, return_lse=True, prefix=prefix)
+    # The log-sum is held dense, (B·H, Lq_p): as the kernel writes it, its
+    # singleton lane dimension is padded to a 128-lane tile on the chip, and
+    # a kept copy would take 128 × the room.
+    out_name, lse_name = FLASH_RESIDUAL_NAMES
+    out = checkpoint_name(out, out_name)
+    lse = checkpoint_name(lse[..., 0], lse_name)
     return out, (q, k, v, kv_mask, out, lse)
 
 
@@ -428,8 +440,8 @@ def _flash_bwd(causal, block_q, block_k, interpret, prefix, res, g):
     # recomputed tile-by-tile from the saved logsumexp — exact gradients,
     # no (L, L) matrix in either direction.
     q, k, v, kv_mask, out, lse = res
-    dq, dk, dv = _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
-                                 block_q, block_k, interpret, prefix)
+    dq, dk, dv = _flash_bwd_impl(q, k, v, kv_mask, out, lse[..., None], g,
+                                 causal, block_q, block_k, interpret, prefix)
     return dq, dk, dv, None
 
 

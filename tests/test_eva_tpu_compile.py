@@ -1,7 +1,8 @@
-"""The EVA attention path compiled for a described TPU v5e at the cell's
-real widths, forward and backward, with no chip attached: Mosaic refuses
-here what it would refuse there (a misaligned slice, too much VMEM), which
-interpret mode cannot show.  Skipped where no v5e can be described.
+"""The EVA attention path, and a local step of the model around it,
+compiled for a described TPU v5e at the cell's real widths, forward and
+backward, with no chip attached: Mosaic refuses here what it would refuse
+there (a misaligned slice, too much VMEM), which interpret mode cannot
+show.  Skipped where no v5e can be described.
 
 The topology is described inside a fixture, after this file's tests have
 started, and in this file alone: a process that loads the TPU's library
@@ -78,3 +79,42 @@ def test_eva_attention_compiles_at_the_published_widths(one_chip,
         assert name in text and "tpu_custom_call" in text, name
     assert "2048,2944]" not in text and "2048,3072]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_a_rematerialised_step_runs_flash_fwd_once_a_layer(one_chip,
+                                                           as_on_the_chip):
+    """One local step (gradient and SGD, the weights donated) of the
+    shipped model, 4 rematerialised layers at the published widths, one
+    sequence of 16,384: each block keeps the kernel's output and its
+    log-sum, so ``flash_fwd`` is there 4 times (plain ``nn.remat``: 8),
+    and the log-sum is kept dense (5.66 GB of temporaries; as the kernel
+    writes it, a tile per row, 6.39; nothing kept, 5.25)."""
+    from colearn_federated_learning_tpu.fed import losses
+    from colearn_federated_learning_tpu.models import registry
+    from colearn_federated_learning_tpu.utils.config import get_config
+
+    config = get_config("evabyte_fedavg").model
+    assert config.remat and config.depth == 4 and config.seq_len == 16384
+    model = registry.build_model(config)
+
+    def on_the_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    ids = jnp.zeros((1, config.seq_len), jnp.int32)
+    y = jnp.zeros((1, config.seq_len, config.num_pred_heads), jnp.int32)
+    params = jax.eval_shape(
+        lambda: registry.init_params(model, ids, jax.random.PRNGKey(0)))
+
+    def step(params, ids, y):
+        grads = jax.grad(lambda p: losses.softmax_cross_entropy(
+            model.apply({"params": p}, ids, train=True), y))(params)
+        return jax.tree.map(lambda p, g: p - 0.1 * g, params, grads)
+
+    compiled = jax.jit(step, donate_argnums=0).lower(
+        *on_the_chip((params, ids, y))).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    for name, calls in (("flash_fwd", 4), ("flash_dq", 4), ("flash_dkv", 4)):
+        assert sum(f"/{name}/" in line for line in kernels) == calls, name
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.0e9

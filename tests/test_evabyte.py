@@ -4,7 +4,9 @@ per-query loop, the model against the benchmark's plain reference,
 causality, a loss and an evaluation per token, and ``fit()`` on the vmap
 and mesh paths."""
 
+import collections
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,7 +63,10 @@ def prefix_oracle(q, k, v, kv_mask, prefix):
 def test_flash_prefix_matches_the_oracle(prefix, vmapped):
     """Forward and all three gradients; blocks of 8, so a prefix of none,
     of one block, and of part of one; a key hidden inside the prefix and
-    one after it."""
+    one after it.  Every case's backward reads the log-sum that the
+    forward left dense (``_flash_fwd``), with a mask and with none
+    hidden (row 0 of prefix 0), plain and mapped: what holds it there
+    needs no case of its own."""
     B, Lq = 2, 24
     q, k, v = _qkv(jax.random.PRNGKey(prefix), B, Lq, prefix + Lq)
     mask = jnp.ones((B, prefix + Lq), bool).at[1, prefix + 3].set(False)
@@ -179,12 +184,16 @@ def _model_and_batch(attn_impl="flash", scale=8.0, **sizes):
     return model, params, ids, y, sizes
 
 
+def _loss_fn(model, ids, y):
+    return lambda p: losses.softmax_cross_entropy(
+        model.apply({"params": p}, ids, train=True), y)
+
+
 @pytest.mark.parametrize("attn_impl", ["flash", "dense"])
 def test_model_matches_the_plain_reference(attn_impl):
     """Loss and every gradient leaf, float32, tight."""
     model, params, ids, y, sizes = _model_and_batch(attn_impl)
-    loss, grads = jax.value_and_grad(lambda p: losses.softmax_cross_entropy(
-        model.apply({"params": p}, ids, train=True), y))(params)
+    loss, grads = jax.value_and_grad(_loss_fn(model, ids, y))(params)
     ref_loss, ref_grads = jax.value_and_grad(
         lambda p: reference.loss(p, ids, y, sizes))(params)
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
@@ -208,6 +217,79 @@ def test_model_logits_and_remat():
     assert bf16.apply({"params": params}, ids).dtype == jnp.float32
     with pytest.raises(ValueError, match="evabyte's attention runs as"):
         registry.build_model(ModelConfig(**{**sizes, "attn_impl": "ring"}))
+
+
+def kernel_calls(fn, *args):
+    """The flash kernels' calls in the jaxpr of ``fn`` at ``args``."""
+    return collections.Counter(re.findall(
+        r"\bname=(flash_(?:fwd|dq|dkv))\b", str(jax.make_jaxpr(fn)(*args))))
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "remat"])
+def test_a_rematerialised_block_runs_the_forward_kernel_once(remat):
+    """Depth 2: one ``flash_fwd`` a layer with ``remat`` too (plain
+    ``nn.remat`` traced 4), because the block keeps the kernel's output
+    and log-sum; the backward's two kernels once a layer either way."""
+    model, params, ids, y, _ = _model_and_batch(remat=remat)
+    assert kernel_calls(jax.grad(_loss_fn(model, ids, y)), params) == {
+        "flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2}
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["jit", "vmap"])
+def test_remat_keeps_loss_and_gradients(vmapped):
+    """What is kept and what is computed again are the same numbers:
+    float32 rounding apart (XLA orders a recomputed sum as it likes); with
+    a client axis in front, as ``fed/programs.py`` maps it."""
+    results = []
+    for remat in (False, True):
+        model, params, ids, y, _ = _model_and_batch(remat=remat)
+
+        def run(p, ids, y, model=model):
+            return jax.value_and_grad(_loss_fn(model, ids, y))(p)
+
+        args = (params, ids, y)
+        if vmapped:
+            run = jax.vmap(run)
+            args = (jax.tree.map(lambda a: jnp.stack([a, 0.5 * a]), params),
+                    jnp.stack([ids, ids[::-1]]), jnp.stack([y, y[::-1]]))
+        results.append(jax.jit(run)(*args))
+    (loss, grads), (remat_loss, remat_grads) = results
+    np.testing.assert_allclose(remat_loss, loss, rtol=1e-6)
+    assert jax.tree.structure(grads) == jax.tree.structure(remat_grads)
+    for (path, want), got in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                 jax.tree.leaves(remat_grads)):
+        gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+        assert gap < 1e-5, (jax.tree_util.keystr(path), gap)
+
+
+@pytest.mark.parametrize("remat,saved", [(False, 0), (True, 2)],
+                         ids=["kept", "remat"])
+def test_gauge_counts_what_a_rematerialised_block_keeps(remat, saved):
+    _model_and_batch(remat=not remat)       # the gauge is set on every build
+    _model_and_batch(remat=remat)
+    assert _snapshot()["evabyte.remat_saved_arrays"] == saved
+
+
+def test_plain_remat_of_a_flash_block_still_repeats_the_kernel():
+    """The two names change nothing for a caller whose ``nn.remat`` has
+    no policy (``models/bert.py``): its backward runs ``flash_fwd`` again,
+    2 × depth calls in all, and the numbers are ``remat=False``'s."""
+    sizes = dict(name="bert", num_classes=4, width=32, depth=2, num_heads=4,
+                 seq_len=64, vocab_size=2000, attn_impl="flash")
+    x = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 1, 2000)
+    y = jax.random.randint(jax.random.PRNGKey(2), (4,), 0, 4)
+    results = {}
+    for remat in (False, True):
+        model = registry.build_model(ModelConfig(**sizes, remat=remat))
+        params = registry.init_params(model, x, jax.random.PRNGKey(0))
+        loss = _loss_fn(model, x, y)
+        assert kernel_calls(jax.grad(loss), params) == {
+            "flash_fwd": 4 if remat else 2, "flash_dq": 2, "flash_dkv": 2}
+        results[remat] = jax.jit(jax.value_and_grad(loss))(params)
+    np.testing.assert_allclose(results[True][0], results[False][0], rtol=1e-6)
+    for got, want in zip(jax.tree.leaves(results[True][1]),
+                         jax.tree.leaves(results[False][1])):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("position", [31, 32, 35, 36, 100])
