@@ -176,6 +176,20 @@ GAUGES = (
     # models/evabyte.py, set on every build: named arrays a rematerialised
     # block keeps for its backward (0: ``remat`` is off, everything is kept)
     "evabyte.remat_saved_arrays",
+    # models/nemotron_h.py, set at trace time on every build: layers of
+    # the stack, labeled {kind=mamba|moe|attention}
+    "hybrid.layers",
+    # ops/ssd.py's caller: heads, chunk and state of the Mamba-2 scan
+    "ssd.heads",
+    "ssd.chunk",
+    "ssd.state",
+    # models/moe.py LatentMoEShare: the experts this chip holds, of how
+    # many, a token's choices, and the static rows of a layer's grouped
+    # products a step (tokens x the most held experts a token can choose)
+    "moe.experts_held",
+    "moe.experts_total",
+    "moe.top_k",
+    "moe.dispatch_rows",
     "fleetsim.devices",
     "fleetsim.chunk_size",
     "fleetsim.available_fraction",

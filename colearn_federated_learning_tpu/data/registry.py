@@ -25,6 +25,7 @@ from colearn_federated_learning_tpu.data import synthetic
 class DatasetSpec:
     name: str
     kind: str                      # "image" | "text" | "timeseries" | "bytes"
+                                   # | "tokens"
     input_shape: tuple[int, ...]   # per-example shape: image HWC,
                                    # text (seq_len,), timeseries (T, F)
     num_classes: int
@@ -56,7 +57,16 @@ SPECS: dict[str, DatasetSpec] = {
                          vocab_size=320),
     "bytes_tiny": DatasetSpec("bytes_tiny", "bytes", (128,), 320, 64, 8,
                               vocab_size=320),
+    # Packed token documents for a next-token model (models/nemotron_h.py)
+    # over a slice of its vocabulary: an example is one sequence, its
+    # labels the token after each position.
+    "tokens": DatasetSpec("tokens", "tokens", (16_384,), 16_384, 128, 4,
+                          vocab_size=16_384),
+    "tokens_tiny": DatasetSpec("tokens_tiny", "tokens", (64,), 96, 64, 8,
+                               vocab_size=96),
 }
+# Kinds whose labels are a block per example (one per position), not a class.
+LABEL_PER_TOKEN_KINDS = ("bytes", "tokens")
 
 
 @dataclasses.dataclass
@@ -105,7 +115,7 @@ def _load_disk(spec: DatasetSpec) -> Dataset | None:
                     f"({len(x)} vs {len(y)})")
             if spec.kind == "image" and x.dtype == np.uint8:
                 x = x.astype(np.float32) / 255.0   # keras raw-byte layout
-            if spec.kind != "bytes":      # bytes: labels per position
+            if spec.kind not in LABEL_PER_TOKEN_KINDS:
                 y = y.reshape(-1)
             # Range-check BEFORE the int32 cast: a corrupt wide integer
             # must not wrap into the valid range and pass.
@@ -137,6 +147,12 @@ def _make_synthetic(spec: DatasetSpec, seed: int) -> Dataset:
             spec.n_train, spec.input_shape[0], seed=seed)
         x_te, y_te = synthetic.synthetic_byte_stream(
             spec.n_test, spec.input_shape[0], seed=seed + 1)
+        return Dataset(spec, x_tr, y_tr, x_te, y_te, "synthetic")
+    if spec.kind == "tokens":
+        x_tr, y_tr = synthetic.synthetic_token_stream(
+            spec.n_train, spec.input_shape[0], spec.vocab_size, seed=seed)
+        x_te, y_te = synthetic.synthetic_token_stream(
+            spec.n_test, spec.input_shape[0], spec.vocab_size, seed=seed + 1)
         return Dataset(spec, x_tr, y_tr, x_te, y_te, "synthetic")
     if spec.kind == "image":
         x_tr, y_tr = synthetic.synthetic_image_classification(

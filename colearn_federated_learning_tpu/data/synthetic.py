@@ -130,6 +130,36 @@ def synthetic_traffic_classification(
 BYTE_OFFSET = 64
 SEPARATOR_ID = 3
 _SOURCE_SEED = 20250101
+_SUCCESSOR_ODDS = (0.55, 0.25, 0.12, 0.08)
+
+
+def _document_ends(rng, n: int, total: int) -> np.ndarray:
+    """(n, total) bool: where a document of 32 symbols and up (Pareto
+    tail) ends and one separator stands."""
+    lengths = (32 * (1.0 + rng.pareto(1.1, size=(n, total // 33 + 1)))
+               ).astype(np.int64)
+    ends = np.cumsum(lengths + 1, axis=1) - 1
+    is_sep = np.zeros((n, total), bool)
+    rows = np.broadcast_to(np.arange(n)[:, None], ends.shape)
+    inside = ends < total
+    is_sep[rows[inside], ends[inside]] = True
+    return is_sep
+
+
+def _markov_walk(is_sep, fresh, successors, choice, separator_id: int,
+                 offset: int) -> np.ndarray:
+    """(n, total) int32 ids: a first-order walk over ``successors`` (which
+    of a symbol's likely successors: ``choice``), a separator where
+    ``is_sep`` says and a fresh symbol (``fresh``) after it; symbol ``s``
+    is id ``offset + s``."""
+    n, total = is_sep.shape
+    stream = np.empty((n, total), np.int32)
+    state = fresh[:, 0]
+    for t in range(total):
+        stream[:, t] = np.where(is_sep[:, t], separator_id, offset + state)
+        state = np.where(is_sep[:, t], fresh[:, t],
+                         successors[state, choice[:, t]])
+    return stream
 
 
 def synthetic_byte_stream(
@@ -151,27 +181,57 @@ def synthetic_byte_stream(
     for every seed) is what makes the task learnable: the next byte has
     1.1 nats of entropy, not ln 256.
     """
-    source = np.random.default_rng(_SOURCE_SEED)
-    successors = source.integers(0, 256, size=(256, 4))
-    odds = np.array([0.55, 0.25, 0.12, 0.08])
+    successors = np.random.default_rng(_SOURCE_SEED).integers(
+        0, 256, size=(256, 4))
     rng = np.random.default_rng(seed)
     total = seq_len + horizon
-    choice = rng.choice(4, size=(n, total), p=odds)
+    choice = rng.choice(4, size=(n, total), p=np.array(_SUCCESSOR_ODDS))
     fresh = rng.integers(0, 256, size=(n, total))
-    # Document ends: cumulative lengths, each followed by one separator.
-    lengths = (32 * (1.0 + rng.pareto(1.1, size=(n, total // 33 + 1)))
-               ).astype(np.int64)
-    ends = np.cumsum(lengths + 1, axis=1) - 1
-    is_sep = np.zeros((n, total), bool)
-    rows = np.broadcast_to(np.arange(n)[:, None], ends.shape)
-    inside = ends < total
-    is_sep[rows[inside], ends[inside]] = True
-    stream = np.empty((n, total), np.int32)
-    state = fresh[:, 0]
-    for t in range(total):
-        stream[:, t] = np.where(is_sep[:, t], SEPARATOR_ID,
-                                BYTE_OFFSET + state)
-        state = np.where(is_sep[:, t], fresh[:, t],
-                         successors[state, choice[:, t]])
+    stream = _markov_walk(_document_ends(rng, n, total), fresh, successors,
+                          choice, SEPARATOR_ID, BYTE_OFFSET)
     ahead = np.arange(seq_len)[:, None] + 1 + np.arange(horizon)[None, :]
     return stream[:, :seq_len].copy(), stream[:, ahead]
+
+
+# The token source behind ``synthetic_token_stream``: word ids follow a
+# Zipf-Mandelbrot law, as a tokenizer's do; id 0 separates documents.
+_ZIPF_SHIFT = 2.7
+
+
+def zipf_odds(vocab_size: int) -> np.ndarray:
+    """Odds of the word ids 1..``vocab_size`` - 1 (id 0 is the
+    separator)."""
+    odds = 1.0 / (np.arange(1, vocab_size) + _ZIPF_SHIFT)
+    return odds / odds.sum()
+
+
+def synthetic_token_stream(
+    n: int,
+    seq_len: int,
+    vocab_size: int,
+    seed: int = 0,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Packed documents of heavy-tailed length from a fixed first-order
+    Markov source over ``vocab_size`` token ids, for a next-token model
+    (models/nemotron_h.py).
+
+    Each row is cut from a stream one longer than ``seq_len``: ``x`` (n,
+    seq_len) int32 and ``y`` (n, seq_len) with ``y[r, i] = stream[r, i +
+    1]``.  Documents (32 tokens and up, Pareto tail) are packed back to
+    back with id 0 between them and no boundary mask.  A word has four
+    likely successors, themselves drawn by Zipf's law and the same for
+    every seed, and so has a document's first word: the stream's unigram
+    statistics are heavy-tailed (a loss falls from ln V within a few
+    steps), its next token has 1.1 nats of entropy.
+    """
+    odds = zipf_odds(vocab_size)
+    # A word is its own id: row 0 of the table is no word's.
+    successors = 1 + np.random.default_rng(_SOURCE_SEED).choice(
+        vocab_size - 1, size=(vocab_size, 4), p=odds)
+    rng = np.random.default_rng(seed)
+    total = seq_len + 1
+    choice = rng.choice(4, size=(n, total), p=np.array(_SUCCESSOR_ODDS))
+    fresh = 1 + rng.choice(vocab_size - 1, size=(n, total), p=odds)
+    stream = _markov_walk(_document_ends(rng, n, total), fresh, successors,
+                          choice, 0, 0)
+    return stream[:, :seq_len].copy(), stream[:, 1:].copy()

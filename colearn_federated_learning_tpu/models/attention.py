@@ -19,12 +19,23 @@ Selected per-model via ``ModelConfig.attn_impl``.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
 
 ATTN_IMPLS = ("dense", "flash", "ring", "ulysses")
+
+
+def grouped_query(num_heads: int, num_kv_heads: Optional[int]) -> int:
+    """Query heads to a key/value head; 1 when they are as many."""
+    if num_kv_heads is None:
+        return 1
+    if num_kv_heads < 1 or num_heads % num_kv_heads:
+        raise ValueError(
+            f"{num_heads} query heads do not share {num_kv_heads} key/value "
+            "heads evenly")
+    return num_heads // num_kv_heads
 
 
 class MultiHeadAttention(nn.Module):
@@ -33,19 +44,38 @@ class MultiHeadAttention(nn.Module):
     impl: str = "dense"
     axis_name: Optional[str] = None   # mesh axis (impl="ring"/"ulysses")
     causal: bool = False
+    # Grouped-query attention: fewer key/value heads than query heads,
+    # query head h reading key/value head h // (num_heads // num_kv_heads);
+    # a head size that is not embed dim / heads; projections without bias
+    # or with another initialisation.  The defaults are the equal-head
+    # layer this module always was.
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    use_bias: bool = True
+    kernel_init: Callable = nn.linear.default_kernel_init
+    out_kernel_init: Callable = nn.linear.default_kernel_init
 
     @nn.compact
     def __call__(self, x, kv_mask=None):
         """x: (B, L, D); kv_mask: optional (B, L) bool, False = padding."""
         D = x.shape[-1]
-        if D % self.num_heads:
-            raise ValueError(f"embed dim {D} not divisible by {self.num_heads} heads")
-        head_dim = D // self.num_heads
+        head_dim = self.head_dim
+        if head_dim is None:
+            if D % self.num_heads:
+                raise ValueError(f"embed dim {D} not divisible by {self.num_heads} heads")
+            head_dim = D // self.num_heads
+        group = grouped_query(self.num_heads, self.num_kv_heads)
 
-        proj = lambda name: nn.DenseGeneral(  # noqa: E731
-            features=(self.num_heads, head_dim), dtype=self.dtype, name=name
+        proj = lambda name, heads=self.num_heads: nn.DenseGeneral(  # noqa: E731
+            features=(heads, head_dim), dtype=self.dtype, name=name,
+            use_bias=self.use_bias, kernel_init=self.kernel_init
         )
-        q, k, v = proj("query")(x), proj("key")(x), proj("value")(x)
+        q = proj("query")(x)
+        k = proj("key", self.num_heads // group)(x)
+        v = proj("value", self.num_heads // group)(x)
+        if group > 1 and self.impl != "flash":
+            # The flash path shares the heads itself (ops/attention.py).
+            k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
 
         if self.impl == "dense":
             from colearn_federated_learning_tpu.parallel.ring import dense_attention
@@ -76,4 +106,5 @@ class MultiHeadAttention(nn.Module):
             raise ValueError(f"unknown attn impl {self.impl!r}; use {ATTN_IMPLS}")
 
         return nn.DenseGeneral(features=D, axis=(-2, -1), dtype=self.dtype,
-                               name="out")(out)
+                               use_bias=self.use_bias, name="out",
+                               kernel_init=self.out_kernel_init)(out)

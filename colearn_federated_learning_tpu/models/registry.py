@@ -8,6 +8,12 @@ import jax.numpy as jnp
 from colearn_federated_learning_tpu.utils.config import ModelConfig
 
 
+# Families whose blocks can be rematerialised, and those of them that run
+# inside ``shard_map`` on a shard of the sequence.
+REMAT_FAMILIES = ("bert", "moe_bert", "vit_b16", "evabyte", "nemotron_h")
+SEQ_PARALLEL_FAMILIES = ("bert", "moe_bert")
+
+
 def _dtype(cfg: ModelConfig):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[cfg.dtype]
 
@@ -20,16 +26,16 @@ def build_model(cfg: ModelConfig, seq_axis_name: str | None = None):
     inside ``shard_map`` with the sequence dim sharded over that axis.
     """
     dtype = _dtype(cfg)
-    if seq_axis_name is not None and cfg.name not in ("bert", "moe_bert"):
+    if seq_axis_name is not None and cfg.name not in SEQ_PARALLEL_FAMILIES:
         raise ValueError(
-            "sequence parallelism is only supported for 'bert'/'moe_bert', "
-            f"not {cfg.name!r}"
+            "sequence parallelism is only supported for "
+            f"{'/'.join(SEQ_PARALLEL_FAMILIES)} (their attention runs over "
+            f"the axis), not {cfg.name!r}"
         )
-    if cfg.remat and cfg.name not in ("bert", "moe_bert", "vit_b16",
-                                      "evabyte"):
+    if cfg.remat and cfg.name not in REMAT_FAMILIES:
         raise ValueError(
             "remat is only implemented for the transformer families "
-            f"(bert/moe_bert/vit_b16/evabyte), not {cfg.name!r} — silently "
+            f"({'/'.join(REMAT_FAMILIES)}), not {cfg.name!r} — silently "
             "ignoring it would fake the memory savings"
         )
     if cfg.name == "mlp":
@@ -93,6 +99,27 @@ def build_model(cfg: ModelConfig, seq_axis_name: str | None = None):
                        num_pred_heads=cfg.num_pred_heads,
                        rope_theta=cfg.rope_theta, dtype=dtype,
                        attn_impl=cfg.attn_impl, remat=cfg.remat)
+    if cfg.name == "nemotron_h":
+        from colearn_federated_learning_tpu.models.nemotron_h import NemotronH
+
+        if cfg.attn_impl not in ("flash", "dense"):
+            raise ValueError(
+                "nemotron_h's attention runs as ('flash', 'dense') on one "
+                f"device, not {cfg.attn_impl!r}")
+        return NemotronH(
+            pattern=cfg.layer_pattern, vocab_size=cfg.vocab_size,
+            embed_dim=cfg.width, mamba_heads=cfg.mamba_heads,
+            mamba_head_dim=cfg.mamba_head_dim,
+            mamba_groups=cfg.mamba_groups, state_size=cfg.ssm_state_size,
+            conv_kernel=cfg.conv_kernel, chunk=cfg.chunk_size,
+            experts_total=cfg.num_experts,
+            experts_held=(cfg.experts_first, cfg.experts_held),
+            top_k=cfg.experts_per_token, latent_dim=cfg.latent_dim,
+            expert_dim=cfg.expert_dim, shared_dim=cfg.shared_expert_dim,
+            routed_scale=cfg.routed_scale, num_heads=cfg.num_heads,
+            num_kv_heads=cfg.num_kv_heads or cfg.num_heads,
+            head_dim=cfg.head_dim or cfg.width // cfg.num_heads,
+            dtype=dtype, attn_impl=cfg.attn_impl, remat=cfg.remat)
     raise KeyError(f"unknown model {cfg.name!r}")
 
 

@@ -469,6 +469,9 @@ def flash_attention(
     them — and query ``i`` sees key ``prefix + j`` for ``j <= i``
     (``L_k = prefix + L_q``).  What ``ops/eva.py`` puts there are the
     chunk summaries of earlier windows.
+    ``k`` and ``v`` may have fewer heads than ``q`` (grouped-query
+    attention: ``H_q`` a multiple of ``H_kv``); query head ``h`` reads
+    key/value head ``h // (H_q // H_kv)``.
     ``interpret=None`` auto-selects Pallas interpret mode off-TPU.
     ``block_q``/``block_k`` default per TPU generation (512 on v4+, 128 on
     v2/v3 whose smaller VMEM rejects the large configuration).
@@ -480,5 +483,14 @@ def flash_attention(
                 "a causal part every key is a prefix key already")
         # Counted at trace time, as ``ops.flash_trace_total`` is.
         telemetry.get_registry().counter("attention.flash_prefix_calls").inc()
+    if k.shape[2] != q.shape[2]:
+        heads, kv_heads = q.shape[2], k.shape[2]
+        if heads % kv_heads or v.shape[2] != kv_heads:
+            raise ValueError(
+                f"{heads} query heads do not share {kv_heads} key heads and "
+                f"{v.shape[2]} value heads evenly")
+        # Every query head is handed its own copy of the head it shares;
+        # the copies' gradients sum in the repeat's transpose.
+        k, v = (jnp.repeat(a, heads // kv_heads, axis=2) for a in (k, v))
     return _flash(q, k, v, kv_mask, causal, block_q, block_k, interpret,
                   prefix)

@@ -205,6 +205,31 @@ class ModelConfig:
     chunk_size: int = 16
     num_pred_heads: int = 8
     rope_theta: float = 100000.0
+    # Hybrid decoder (models/nemotron_h.py): one letter a layer (M Mamba-2,
+    # E the chip's share of a LatentMoE layer, * grouped-query attention).
+    # Mamba-2: heads of ``mamba_head_dim`` in ``mamba_groups`` groups that
+    # share B and C, state and convolution widths (``chunk_size`` is the
+    # scan's chunk, ops/ssd.py).  LatentMoE: ``num_experts`` is the whole
+    # mixture the router scores, of which this chip holds ``experts_held``
+    # from ``experts_first`` on; experts a token; the latent, expert and
+    # shared-expert widths; the factor on the routed weights.  Attention:
+    # ``num_heads`` query heads of ``head_dim`` on ``num_kv_heads``
+    # key/value heads (0: as many as query heads; width / heads).
+    layer_pattern: str = "MEMEMEM*EME"
+    mamba_heads: int = 32
+    mamba_head_dim: int = 64
+    mamba_groups: int = 2
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    experts_first: int = 0
+    experts_held: int = 8
+    experts_per_token: int = 22
+    latent_dim: int = 1024
+    expert_dim: int = 2688
+    shared_expert_dim: int = 5376
+    routed_scale: float = 5.0
+    num_kv_heads: int = 0
+    head_dim: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -496,6 +521,25 @@ CONFIGS["evabyte_fedavg"] = _cfg(
     fed=FedConfig(strategy="fedavg", rounds=20, cohort_size=1,
                   local_steps=2, batch_size=1, lr=0.1, momentum=0.0),
     run=RunConfig(name="evabyte_fedavg", eval_every=2),
+)
+
+
+# A hybrid state-space / sparse language model: Nemotron-3-Super at its
+# published widths as one chip's share of a stated deployment (the first 11
+# of 88 layers, a pipeline stage; 8 of 512 experts, a quarter of the mixers'
+# heads, an eighth of the vocabulary: PERF.md section 4).  One example is
+# one sequence of 16,384 tokens with the next token as each position's
+# label (dataset ``tokens``).
+CONFIGS["nemotron_h_fedavg"] = _cfg(
+    data=DataConfig(dataset="tokens", num_clients=8, partition="iid"),
+    model=ModelConfig(name="nemotron_h", num_classes=16384, vocab_size=16384,
+                      width=4096, seq_len=16384, layer_pattern="MEMEMEM*EME",
+                      chunk_size=128, num_experts=512, num_heads=8,
+                      num_kv_heads=1, head_dim=128,
+                      dtype="bfloat16", attn_impl="flash", remat=True),
+    fed=FedConfig(strategy="fedavg", rounds=20, cohort_size=1,
+                  local_steps=2, batch_size=1, lr=0.003, momentum=0.0),
+    run=RunConfig(name="nemotron_h_fedavg", eval_every=2),
 )
 
 

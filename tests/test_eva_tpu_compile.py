@@ -118,3 +118,43 @@ def test_a_rematerialised_step_runs_flash_fwd_once_a_layer(one_chip,
     for name, calls in (("flash_fwd", 4), ("flash_dq", 4), ("flash_dkv", 4)):
         assert sum(f"/{name}/" in line for line in kernels) == calls, name
     assert compiled.memory_analysis().temp_size_in_bytes < 6.0e9
+
+
+def test_the_share_layer_compiles_under_a_client_axis(one_chip,
+                                                      as_on_the_chip):
+    """One chip's share of a LatentMoE layer at the published widths (8 of
+    512 experts, top 22, latent 1,024, experts 2,688), one block of 4,096
+    tokens, gradient and all, mapped over a client axis as
+    ``fed/programs.py`` maps it: the chip's compiler refuses a grouped
+    product with a batch dimension, so each of the eight (two forward, the same
+    two made again by the block's checkpoint, four backward) must come out
+    as its own ``ragged-dot`` kernel without one, at the rows' static bound
+    of 4,096 x 8."""
+    from colearn_federated_learning_tpu.models.moe import LatentMoEShare
+
+    layer = LatentMoEShare(
+        embed_dim=4096, latent_dim=1024, expert_dim=2688, shared_dim=5376,
+        experts_total=512, experts_held=(0, 8), top_k=22, routed_scale=5.0,
+        dtype=jnp.bfloat16)
+    u = jnp.zeros((4096, 4096), jnp.float32)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), u)["params"])
+
+    def grads(p, u):
+        return jax.grad(lambda p, u: jnp.sum(
+            layer.apply({"params": p}, u).astype(jnp.float32) ** 2),
+            argnums=(0, 1))(p, u)
+
+    def with_client_axis(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            (1,) + a.shape, a.dtype, sharding=one_chip), tree)
+
+    compiled = jax.jit(jax.vmap(grads)).lower(
+        *with_client_axis((params, u))).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "ragged-dot-none" in line.split("=")[0]]
+    assert len(kernels) == 8, len(kernels)
+    assert all("[32768," in line or "[8," in line.split("custom-call")[0]
+               for line in kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
