@@ -184,12 +184,14 @@ GAUGES = (
     "ssd.chunk",
     "ssd.state",
     # models/moe.py LatentMoEShare: the experts this chip holds, of how
-    # many, a token's choices, and the static rows of a layer's grouped
-    # products a step (tokens x the most held experts a token can choose)
+    # many, a token's choices, the static rows of a layer's grouped
+    # products a step (tokens x the most held experts a token can choose),
+    # and the rows of a tile of the loop that visits those the routing filled
     "moe.experts_held",
     "moe.experts_total",
     "moe.top_k",
     "moe.dispatch_rows",
+    "moe.row_tile",
     "fleetsim.devices",
     "fleetsim.chunk_size",
     "fleetsim.available_fraction",

@@ -29,6 +29,8 @@ capacity — the standard choice, avoiding an all-to-all over the seq axis.
 
 from __future__ import annotations
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -133,47 +135,149 @@ class MoEFfn(nn.Module):
 # --- The share of a large mixture that one chip holds ------------------------
 
 
-@sequential_vmap
-def _grouped_product(rows, banks, group_sizes):
-    """``rows`` (R, K) sorted by group, ``banks`` (G, K, N), ``group_sizes``
-    (G,): row ``r`` of group ``g`` times ``banks[g]``.  XLA's grouped
-    product visits the row tiles the group sizes cover and no others, so
-    its work follows the rows routed and not ``R``; rows past their sum
-    are left unwritten.  Under ``vmap`` (a client axis) it runs a client at
-    a time: the chip's compiler takes no batch dimension here."""
-    return lax.ragged_dot(rows, banks, group_sizes,
-                          preferred_element_type=rows.dtype)
+def relu2(x):
+    return jnp.square(nn.relu(x))
 
 
-@sequential_vmap
-def _grouped_product_transposed(rows, banks, group_sizes, g):
-    _, pull = jax.vjp(
-        lambda rows, banks: lax.ragged_dot(
-            rows, banks, group_sizes, preferred_element_type=rows.dtype),
-        rows, banks)
-    return pull(g)
+def tile_sizes(sizes, start, rows: int):
+    """What the rows ``start .. start + rows - 1`` hold of each group,
+    (G,), where ``sizes`` (G,) are the groups' sizes and the groups lie one
+    after another from row 0: they sum to the part of the range that lies
+    before the groups' end."""
+    ends = jnp.cumsum(sizes)
+    return (jnp.clip(ends, start, start + rows)
+            - jnp.clip(ends - sizes, start, start + rows))
 
 
-@jax.custom_vjp
-def grouped_product(rows, banks, group_sizes):
-    """``_grouped_product`` with its backward pass batched the same way
-    (``vmap`` of a gradient meets the two rules below, not the primitive's
-    own transpose, which would carry the batch dimension)."""
-    return _grouped_product(rows, banks, group_sizes)
+def _tile(acc, latent, weight, w1, w2, token, held, sizes):
+    """One tile of rows sorted by expert, added to their tokens: ``acc``,
+    ``latent`` (T, Z); ``token``, ``weight``, ``held`` (R,) as
+    ``held_pairs`` gives them; the banks ``w1`` (G, Z, F), ``w2`` (G, F, Z);
+    ``sizes`` (G,), what the tile holds of each expert.  A tile may span
+    several experts, and an expert several tiles.  XLA's grouped product
+    visits the row tiles the group sizes cover and leaves the rows past
+    their sum unwritten: nothing of those may pass, forward or backward
+    (``held`` is False there, and ``weight`` 0).  Returns ``acc`` plus, at
+    every token of the tile, ``weight * W2_e relu(W1_e latent)^2``, (T, Z)
+    float32."""
+    rows = jnp.where(held[:, None], latent[token], 0)
+    hidden = relu2(lax.ragged_dot(rows, w1, sizes,
+                                  preferred_element_type=rows.dtype))
+    out = lax.ragged_dot(hidden, w2, sizes,
+                         preferred_element_type=rows.dtype)
+    out = jnp.where(held[:, None], out, 0).astype(
+        jnp.float32) * weight[:, None]
+    return acc.at[token].add(out)
 
 
-def _grouped_product_fwd(rows, banks, group_sizes):
-    return (_grouped_product(rows, banks, group_sizes),
-            (rows, banks, group_sizes))
+def _tile_at(i, tile: int, token, weight, held, sizes):
+    """Tile ``i``'s slice of the rows' vectors and its group sizes."""
+    return (*(lax.dynamic_slice(a, (i * tile,), (tile,))
+              for a in (token, weight, held)),
+            tile_sizes(sizes, i * tile, tile))
 
 
-def _grouped_product_bwd(kept, g):
-    rows, banks, group_sizes = kept
-    d_rows, d_banks = _grouped_product_transposed(rows, banks, group_sizes, g)
-    return d_rows, d_banks, None
+def _tiles_to_visit(sizes, tile: int):
+    return (jnp.sum(sizes) + tile - 1) // tile
 
 
-grouped_product.defvjp(_grouped_product_fwd, _grouped_product_bwd)
+def visit_row_tiles(tile: int, latent, weight, w1, w2, token, held, sizes):
+    """The held experts' part for blocks of tokens, (B, T, Z) float32, and
+    the tiles visited in each, (B,).  ``latent`` (B, T, Z); a block's rows
+    are ``held_pairs``' vectors ``token``, ``weight``, ``held`` (B, R; whole
+    tiles of ``tile``) and ``sizes`` (B, G); the banks (G, Z, F) and (G, F,
+    Z) are rounded to ``latent``'s precision once.  A block's rows go
+    through ``_tile`` in a loop whose trip count is its held pairs
+    (``sizes.sum()``) over ``tile``, rounded up, a value on the device.  The
+    rows past the last visited tile are never read and never written back;
+    at a routing that fills the rows' static bound, every tile is
+    visited."""
+    w1, w2 = w1.astype(latent.dtype), w2.astype(latent.dtype)
+
+    def block(rows):
+        latent, weight, token, held, sizes = rows
+        tiles = _tiles_to_visit(sizes, tile)
+
+        def visit(i, acc):
+            token_i, weight_i, held_i, sizes_i = _tile_at(
+                i, tile, token, weight, held, sizes)
+            return _tile(acc, latent, weight_i, w1, w2, token_i, held_i,
+                         sizes_i)
+
+        return lax.fori_loop(
+            0, tiles, visit, jnp.zeros(latent.shape, jnp.float32)), tiles
+
+    return lax.map(block, (latent, weight, token, held, sizes))
+
+
+def _visit_row_tiles_transposed(tile: int, latent, weight, w1, w2, token,
+                                held, sizes, g):
+    """``g`` (B, T, Z) float32 pulled back through ``visit_row_tiles`` to
+    ``latent``, ``weight`` and the banks: the same loops, each visit
+    pulling its block's ``g`` back through its tile (``jax.vjp`` of
+    ``_tile``).  A tile's part of ``d weight`` is its own slice; the banks'
+    are summed in float32 over every tile of every block, in their own
+    precision and not the products'."""
+    banks = w1.astype(latent.dtype), w2.astype(latent.dtype)
+
+    def block(d_banks, rows):
+        latent, weight, token, held, sizes, g = rows
+
+        def visit(i, sums):
+            token_i, weight_i, held_i, sizes_i = _tile_at(
+                i, tile, token, weight, held, sizes)
+            _, pull = jax.vjp(
+                lambda latent, weight_i, w1, w2: _tile(
+                    jnp.zeros_like(g), latent, weight_i, w1, w2, token_i,
+                    held_i, sizes_i),
+                latent, weight_i, *banks)
+            d_latent, d_weight_i, d_w1, d_w2 = pull(g)
+            return (sums[0] + d_latent,
+                    lax.dynamic_update_slice(sums[1], d_weight_i, (i * tile,)),
+                    sums[2] + d_w1, sums[3] + d_w2)
+
+        d_latent, d_weight, *d_banks = lax.fori_loop(
+            0, _tiles_to_visit(sizes, tile), visit,
+            (jnp.zeros(latent.shape, jnp.float32), jnp.zeros_like(weight),
+             *d_banks))
+        return tuple(d_banks), (d_latent.astype(latent.dtype), d_weight)
+
+    (d_w1, d_w2), (d_latent, d_weight) = lax.scan(
+        block, (jnp.zeros(w1.shape, jnp.float32),
+                jnp.zeros(w2.shape, jnp.float32)),
+        (latent, weight, token, held, sizes, g))
+    return d_latent, d_weight, d_w1.astype(w1.dtype), d_w2.astype(w2.dtype)
+
+
+def _a_client_at_a_time(visit, tile: int):
+    """Under ``vmap`` (a client axis) a loop whose trip count differs from
+    client to client would run every client to the longest, and the chip's
+    compiler takes no batch dimension on a grouped product: ``visit`` runs
+    a client at a time."""
+    return sequential_vmap(functools.partial(visit, tile))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def routed_rows(tile: int, latent, weight, w1, w2, token, held, sizes):
+    """``visit_row_tiles``' answer, differentiable in ``latent``, ``weight``
+    and the banks: a loop with a trip count on the device has no
+    reverse-mode rule of its own.  The backward is handed the arguments
+    and nothing else; ``vmap`` of a gradient meets the two rules below,
+    each a client at a time, and no primitive's own."""
+    return _a_client_at_a_time(visit_row_tiles, tile)(
+        latent, weight, w1, w2, token, held, sizes)[0]
+
+
+def _routed_rows_fwd(tile, *args):
+    return _a_client_at_a_time(visit_row_tiles, tile)(*args)[0], args
+
+
+def _routed_rows_bwd(tile, args, g):
+    return (*_a_client_at_a_time(_visit_row_tiles_transposed, tile)(*args, g),
+            None, None, None)
+
+
+routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
 
 
 def held_pairs(chosen, weights, first: int, count: int):
@@ -202,10 +306,6 @@ def held_pairs(chosen, weights, first: int, count: int):
             held, sizes)
 
 
-def relu2(x):
-    return jnp.square(nn.relu(x))
-
-
 class LatentMoEShare(nn.Module):
     """One chip's share of a sigmoid-routed mixture whose experts work in a
     latent space (Nemotron-3's LatentMoE), beside a shared expert in the
@@ -227,14 +327,19 @@ class LatentMoEShare(nn.Module):
 
     No token is dropped and no shape depends on the routing.  Tokens go
     through in blocks of ``token_block``; in a block, the (token, choice)
-    pairs are sorted by held expert (pairs that fell elsewhere last), the
-    first ``token_block * min(top_k, count)`` of them (every held pair is
-    among them: a token falls on a held expert at most that often) are
-    gathered into rows, two grouped products over the experts' banks
-    follow the group sizes (``grouped_product``), and the rows are added
-    back to their tokens under their weights.  A block is a
-    ``jax.checkpoint``: what its backward needs at the rows' static bound
-    is made again a block at a time and not kept for all.
+    pairs are sorted by held expert (pairs that fell elsewhere last) and
+    the first ``token_block * min(top_k, count)`` of them are the block's
+    rows (``held_pairs``; every held pair is among them: a token falls on a
+    held expert at most that often).  That bound is the length of four
+    vectors and of nothing else: the rows are gathered, taken through two
+    grouped products over the experts' banks and added back to their
+    tokens under their weights a tile of ``row_tile`` at a time, by a loop
+    that visits the tiles the held pairs fill and no others
+    (``visit_row_tiles``), so the work follows the rows routed up to the
+    bound, where it visits them all.  The loop's trip count is a value on
+    the device, so the blocks' loops are one ``jax.custom_vjp``
+    (``routed_rows``): its backward is handed the forward's arguments and
+    runs the same loops, a tile's forward made again inside them.
     """
 
     embed_dim: int
@@ -246,6 +351,9 @@ class LatentMoEShare(nn.Module):
     top_k: int
     routed_scale: float = 1.0
     token_block: int = 4096
+    # Whole tiles of the grouped product (512 rows).  As many rows as a block
+    # has tokens: one visit while a token falls on one held expert or fewer.
+    row_tile: int = 4096
     dtype: jnp.dtype = jnp.float32
     init_std: float = 0.02
     out_scale: float = 1.0          # on the maps back into the stream
@@ -284,23 +392,6 @@ class LatentMoEShare(nn.Module):
         weights = self.routed_scale * picked / picked.sum(-1, keepdims=True)
         return chosen, weights
 
-    def _block(self, latent, chosen, weights):
-        """The held experts' part for one block of tokens: ``latent`` (T,
-        Z), ``chosen``/``weights`` (T, top_k).  Returns (T, Z)."""
-        token, weight, held, sizes = held_pairs(
-            chosen, weights, *self.experts_held)
-        # Rows past the group sizes' sum are unwritten by the products:
-        # nothing of them may pass, forward or backward.
-        rows = jnp.where(held[:, None], latent[token], 0)
-        hidden = relu2(grouped_product(
-            rows, self.experts_w1.astype(self.dtype), sizes))
-        out = grouped_product(
-            hidden, self.experts_w2.astype(self.dtype), sizes)
-        out = jnp.where(held[:, None], out, 0).astype(
-            jnp.float32) * weight[:, None]
-        return jnp.zeros(latent.shape, jnp.float32).at[token].add(
-            out).astype(self.dtype)
-
     def routed_latent(self, u, u32):
         """``sum over e chosen and held of w_e E_e(l)``, (N, Z): the part
         of the layer that differs from share to share."""
@@ -315,18 +406,26 @@ class LatentMoEShare(nn.Module):
         registry.gauge("moe.experts_held").set(count)
         registry.gauge("moe.experts_total").set(self.experts_total)
         registry.gauge("moe.top_k").set(self.top_k)
-        registry.gauge("moe.dispatch_rows").set(
-            tokens * min(self.top_k, count))
+        bound = min(self.top_k, count)
+        tile = min(self.row_tile, block * bound)
+        registry.gauge("moe.dispatch_rows").set(tokens * bound)
+        registry.gauge("moe.row_tile").set(tile)
         chosen, weights = self.route(u32)
         latent = jnp.dot(u, self.latent_down.astype(self.dtype))
 
         def blocks(a):
             return a.reshape(tokens // block, block, *a.shape[1:])
 
-        out = lax.map(
-            lambda args: jax.checkpoint(self._block)(*args),
-            (blocks(latent), blocks(chosen), blocks(weights)))
-        return out.reshape(tokens, -1)
+        token, weight, held, sizes = lax.map(
+            lambda pairs: held_pairs(*pairs, *self.experts_held),
+            (blocks(chosen), blocks(weights)))
+        # Whole tiles; what is added holds no pair.
+        token, weight, held = (
+            jnp.pad(a, ((0, 0), (0, -a.shape[1] % tile)))
+            for a in (token, weight, held))
+        out = routed_rows(tile, blocks(latent), weight, self.experts_w1,
+                          self.experts_w2, token, held, sizes)
+        return out.astype(self.dtype).reshape(tokens, -1)
 
     def shared(self, u):
         return jnp.dot(relu2(jnp.dot(u, self.shared_w1.astype(self.dtype))),

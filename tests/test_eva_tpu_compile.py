@@ -126,10 +126,12 @@ def test_the_share_layer_compiles_under_a_client_axis(one_chip,
     512 experts, top 22, latent 1,024, experts 2,688), one block of 4,096
     tokens, gradient and all, mapped over a client axis as
     ``fed/programs.py`` maps it: the chip's compiler refuses a grouped
-    product with a batch dimension, so each of the eight (two forward, the same
-    two made again by the block's checkpoint, four backward) must come out
-    as its own ``ragged-dot`` kernel without one, at the rows' static bound
-    of 4,096 x 8."""
+    product with a batch dimension, so each of the eight (two in the
+    forward loop's body; in the backward loop's the same two again, two for
+    the rows and two for the banks) must come out as its own ``ragged-dot``
+    kernel without one, at a tile's rows and not at the static bound of
+    4,096 x 8, which nothing in the program has as an array of the latent or
+    the expert width."""
     from colearn_federated_learning_tpu.models.moe import LatentMoEShare
 
     layer = LatentMoEShare(
@@ -155,6 +157,9 @@ def test_the_share_layer_compiles_under_a_client_axis(one_chip,
                if 'custom_call_target="tpu_custom_call"' in line
                and "ragged-dot-none" in line.split("=")[0]]
     assert len(kernels) == 8, len(kernels)
-    assert all("[32768," in line or "[8," in line.split("custom-call")[0]
+    tile = layer.row_tile
+    assert all(f"[{tile}," in line or "[8," in line.split("custom-call")[0]
                for line in kernels)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    assert not any(f"[32768,{width}]" in compiled.as_text()
+                   for width in (1024, 2688))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9

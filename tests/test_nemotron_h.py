@@ -169,11 +169,28 @@ def test_attention_module_with_fewer_key_value_heads():
 # --- the share layer ----------------------------------------------------------
 
 
-def _share_layer(first=4, count=4, total=16, top_k=6, **kw):
+def _share_layer(first=4, count=4, total=16, top_k=6, row_tile=16, **kw):
+    """A block of 32 tokens has 128 rows at the bound and holds 48 pairs or
+    so: several tiles of 16 are visited, and one spans two experts."""
     return moe.LatentMoEShare(
         embed_dim=32, latent_dim=16, expert_dim=24, shared_dim=40,
         experts_total=total, experts_held=(first, count), top_k=top_k,
-        routed_scale=5.0, init_std=0.3, **kw)
+        routed_scale=5.0, init_std=0.3, row_tile=row_tile, **kw)
+
+
+def _program_and_reference(layer, first=4):
+    """Value and gradients (weights, input) of a scalar of the layer's
+    answer, and of the reference's loop over the held experts."""
+    model = dict(experts_per_token=layer.top_k, routed_scale=5.0,
+                 experts_first=first)
+
+    def program(p, u):
+        return jnp.sum(jnp.sin(layer.apply({"params": p}, u)))
+
+    def plain(p, u):
+        return jnp.sum(jnp.sin(reference.moe(u, p, model)))
+
+    return [jax.value_and_grad(f, argnums=(0, 1)) for f in (program, plain)]
 
 
 @pytest.mark.parametrize("router", ["uniform", "skewed"])
@@ -217,15 +234,7 @@ def test_share_layer_is_the_plain_loop_over_held_experts(vmapped):
     layer = _share_layer(token_block=32)
     u = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
     params = layer.init(jax.random.PRNGKey(1), u)["params"]
-    model = dict(experts_per_token=6, routed_scale=5.0, experts_first=4)
-
-    def program(p, u):
-        return jnp.sum(jnp.sin(layer.apply({"params": p}, u)))
-
-    def plain(p, u):
-        return jnp.sum(jnp.sin(reference.moe(u, p, model)))
-
-    run = [jax.value_and_grad(f, argnums=(0, 1)) for f in (program, plain)]
+    run = _program_and_reference(layer)
     args = (params, u)
     if vmapped:
         run = [jax.vmap(f) for f in run]
@@ -241,6 +250,83 @@ def test_share_layer_is_the_plain_loop_over_held_experts(vmapped):
             assert not np.asarray(g).any() and not np.asarray(w).any()
         else:
             assert _rel(g, w) < 2e-5, name
+
+
+def test_tile_sizes_cut_the_groups_at_the_tiles_edges():
+    """A tile may span several groups and a group several tiles; past the
+    groups' end a tile holds nothing."""
+    sizes = jnp.array([3, 0, 6, 2])
+    got = [moe.tile_sizes(sizes, start, 4).tolist() for start in (0, 4, 8, 12)]
+    assert got == [[3, 0, 1, 0], [0, 0, 4, 0], [0, 0, 1, 2], [0, 0, 0, 0]]
+
+
+def _assert_close_or_both_zero(got, want, name):
+    if np.asarray(want).any():
+        assert _rel(got, want) < 2e-5, name
+    else:
+        assert not np.asarray(got).any(), name
+
+
+# The correction bias on the held experts: 0 leaves the router as drawn, +10
+# sends every token to all of them (the rows' static bound is met), -10 none.
+@pytest.mark.parametrize("biases", [(0.0,), (10.0,), (-10.0,), (10.0, 0.0),
+                                    (0.0, -10.0)],
+                         ids=["uniform", "every_held", "none_held",
+                              "vmap_bound_and_uniform",
+                              "vmap_uniform_and_none"])
+def test_row_tiles_visited_follow_the_routing(biases):
+    """The loop visits ``ceil(held pairs / tile)`` tiles of a block's 8: a
+    few under the router as drawn (one of them across two experts), all at
+    the bound, none where no pair is held (the answer is 0 there, and every
+    gradient 0 and finite).  Whatever it visits, answer and every gradient
+    leaf are the reference's loop over the held experts, for two clients
+    with different counts under one ``vmap`` too."""
+    tile, first, count = 16, 4, 4
+    layer = _share_layer(token_block=32, row_tile=tile)
+    us = jax.random.normal(jax.random.PRNGKey(0), (len(biases), 64, 32))
+    drawn = layer.init(jax.random.PRNGKey(1), us[0])["params"]
+    clients = [dict(drawn, router_bias=drawn["router_bias"].at[
+        first:first + count].set(bias)) for bias in biases]
+    for bias, params, u in zip(biases, clients, us):
+        chosen, weights = layer.apply({"params": params}, u, method="route")
+        latent = (u @ params["latent_down"]).reshape(2, 32, -1)
+        token, weight, held, sizes = jax.vmap(
+            lambda c, w: moe.held_pairs(c, w, first, count))(
+            chosen.reshape(2, 32, -1), weights.reshape(2, 32, -1))
+        out, visited = jax.jit(moe.visit_row_tiles, static_argnums=0)(
+            tile, latent, weight, params["experts_w1"], params["experts_w2"],
+            token, held, sizes)
+        pairs = np.asarray(sizes.sum(axis=1))
+        np.testing.assert_array_equal(visited, -(-pairs // tile))
+        if bias > 0:
+            assert (pairs == token.shape[1]).all() and (visited == 8).all()
+        elif bias < 0:
+            assert not pairs.any() and not np.asarray(out).any()
+        else:
+            assert (1 < visited).all() and (visited < 8).all()
+            assert np.count_nonzero(moe.tile_sizes(sizes[0], 0, tile)) > 1
+    run = _program_and_reference(layer, first)
+    if len(biases) == 1:
+        args = (clients[0], us[0])
+    else:
+        run = [jax.vmap(f) for f in run]
+        args = (jax.tree.map(lambda *a: jnp.stack(a), *clients), us)
+    (got, got_g), (want, want_g) = (jax.jit(f)(*args) for f in run)
+    # A sum of 2,048 sines that may cancel: float32 leaves it 1e-4 or so.
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=5e-4)
+    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want_g),
+                            jax.tree.leaves(got_g)):
+        name = jax.tree_util.keystr(path)
+        assert np.isfinite(g).all(), name
+        if "router_bias" in name:
+            assert not np.asarray(g).any() and not np.asarray(w).any()
+        elif len(biases) == 1:
+            _assert_close_or_both_zero(g, w, name)
+            if biases[0] < 0 and ("experts" in name or name == "['router']"):
+                assert not np.asarray(g).any(), name
+        else:
+            for client in range(len(biases)):
+                _assert_close_or_both_zero(g[client], w[client], name)
 
 
 def test_share_layer_refuses_experts_it_cannot_hold():
@@ -436,8 +522,9 @@ def test_gauges_say_what_was_built():
     assert (got["ssd.heads"], got["ssd.chunk"], got["ssd.state"]) == (4, 16, 8)
     assert (got["moe.experts_held"], got["moe.experts_total"],
             got["moe.top_k"]) == (4, 16, 6)
-    # One sequence of 64 tokens, at most 4 held experts a token.
-    assert got["moe.dispatch_rows"] == 64 * 4
+    # One sequence of 64 tokens, at most 4 held experts a token; the
+    # tile is the module's own, cut to the rows a block has.
+    assert got["moe.dispatch_rows"] == got["moe.row_tile"] == 64 * 4
     _model_and_batch(layer_pattern="M*")
     assert _snapshot()["hybrid.layers{kind=moe}"] == 0
 
