@@ -183,7 +183,8 @@ GAUGES = (
     "ssd.heads",
     "ssd.chunk",
     "ssd.state",
-    # models/moe.py LatentMoEShare: the experts this chip holds, of how
+    # models/moe.py, the share layers (LatentMoEShare, GatedMoEShare): the
+    # experts this chip holds, of how
     # many, a token's choices, the static rows of a layer's grouped
     # products a step (tokens x the most held experts a token can choose),
     # and the rows of a tile of the loop that visits those the routing filled
@@ -192,6 +193,20 @@ GAUGES = (
     "moe.top_k",
     "moe.dispatch_rows",
     "moe.row_tile",
+    # models/xing4.py, set at trace time on every build: layers of the
+    # stack, labeled {kind=dense|moe}, and sequential prediction modules
+    "xing4.layers",
+    "mtp.modules",
+    # models/mhc.py: rows of a token's residual stream, and the Sinkhorn
+    # iterations that make their mixing map doubly stochastic
+    "mhc.streams",
+    "mhc.sinkhorn_iters",
+    # models/mla.py: heads of latent attention, the widths its kernel
+    # scores over and weighs, and the key/value path's low rank
+    "mla.heads",
+    "mla.qk_dim",
+    "mla.v_dim",
+    "mla.kv_rank",
     "fleetsim.devices",
     "fleetsim.chunk_size",
     "fleetsim.available_fraction",

@@ -32,6 +32,7 @@ class DatasetSpec:
     n_train: int                   # synthetic fallback sizes
     n_test: int
     vocab_size: int = 0            # text only
+    horizon: int = 1               # "tokens_ahead": labels a position
 
 
 SPECS: dict[str, DatasetSpec] = {
@@ -64,9 +65,18 @@ SPECS: dict[str, DatasetSpec] = {
                           vocab_size=16_384),
     "tokens_tiny": DatasetSpec("tokens_tiny", "tokens", (64,), 96, 64, 8,
                                vocab_size=96),
+    # The same stream for a model with sequential prediction modules
+    # (models/xing4.py): the labels of a position are the ``horizon``
+    # tokens after it, one and each module's (the shipped model has none).
+    "tokens_ahead": DatasetSpec("tokens_ahead", "tokens_ahead", (8_192,),
+                                16_384, 128, 4, vocab_size=16_384,
+                                horizon=1),
+    "tokens_ahead_tiny": DatasetSpec("tokens_ahead_tiny", "tokens_ahead",
+                                     (64,), 96, 64, 8, vocab_size=96,
+                                     horizon=2),
 }
 # Kinds whose labels are a block per example (one per position), not a class.
-LABEL_PER_TOKEN_KINDS = ("bytes", "tokens")
+LABEL_PER_TOKEN_KINDS = ("bytes", "tokens", "tokens_ahead")
 
 
 @dataclasses.dataclass
@@ -148,11 +158,14 @@ def _make_synthetic(spec: DatasetSpec, seed: int) -> Dataset:
         x_te, y_te = synthetic.synthetic_byte_stream(
             spec.n_test, spec.input_shape[0], seed=seed + 1)
         return Dataset(spec, x_tr, y_tr, x_te, y_te, "synthetic")
-    if spec.kind == "tokens":
+    if spec.kind in ("tokens", "tokens_ahead"):
+        ahead = {"horizon": spec.horizon} if spec.kind == "tokens_ahead" else {}
         x_tr, y_tr = synthetic.synthetic_token_stream(
-            spec.n_train, spec.input_shape[0], spec.vocab_size, seed=seed)
+            spec.n_train, spec.input_shape[0], spec.vocab_size, seed=seed,
+            **ahead)
         x_te, y_te = synthetic.synthetic_token_stream(
-            spec.n_test, spec.input_shape[0], spec.vocab_size, seed=seed + 1)
+            spec.n_test, spec.input_shape[0], spec.vocab_size, seed=seed + 1,
+            **ahead)
         return Dataset(spec, x_tr, y_tr, x_te, y_te, "synthetic")
     if spec.kind == "image":
         x_tr, y_tr = synthetic.synthetic_image_classification(

@@ -210,6 +210,7 @@ def synthetic_token_stream(
     seq_len: int,
     vocab_size: int,
     seed: int = 0,
+    horizon: int = 0,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Packed documents of heavy-tailed length from a fixed first-order
     Markov source over ``vocab_size`` token ids, for a next-token model
@@ -217,7 +218,10 @@ def synthetic_token_stream(
 
     Each row is cut from a stream one longer than ``seq_len``: ``x`` (n,
     seq_len) int32 and ``y`` (n, seq_len) with ``y[r, i] = stream[r, i +
-    1]``.  Documents (32 tokens and up, Pareto tail) are packed back to
+    1]``.  With ``horizon`` (a model that also predicts further ahead,
+    models/xing4.py) the stream is ``horizon`` longer and ``y`` is (n,
+    seq_len, horizon), ``y[r, i, j] = stream[r, i + 1 + j]``.  Documents
+    (32 tokens and up, Pareto tail) are packed back to
     back with id 0 between them and no boundary mask.  A word has four
     likely successors, themselves drawn by Zipf's law and the same for
     every seed, and so has a document's first word: the stream's unigram
@@ -229,9 +233,12 @@ def synthetic_token_stream(
     successors = 1 + np.random.default_rng(_SOURCE_SEED).choice(
         vocab_size - 1, size=(vocab_size, 4), p=odds)
     rng = np.random.default_rng(seed)
-    total = seq_len + 1
+    total = seq_len + max(horizon, 1)
     choice = rng.choice(4, size=(n, total), p=np.array(_SUCCESSOR_ODDS))
     fresh = 1 + rng.choice(vocab_size - 1, size=(n, total), p=odds)
     stream = _markov_walk(_document_ends(rng, n, total), fresh, successors,
                           choice, 0, 0)
-    return stream[:, :seq_len].copy(), stream[:, 1:].copy()
+    if not horizon:
+        return stream[:, :seq_len].copy(), stream[:, 1:].copy()
+    ahead = np.arange(seq_len)[:, None] + 1 + np.arange(horizon)[None, :]
+    return stream[:, :seq_len].copy(), stream[:, ahead]

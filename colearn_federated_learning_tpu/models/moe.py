@@ -139,6 +139,12 @@ def relu2(x):
     return jnp.square(nn.relu(x))
 
 
+def gated(u, w_gate, w_up, w_down, product=jnp.dot):
+    """``W_d (silu(W_g u) * W_u u)``; ``product`` multiplies rows by a
+    matrix."""
+    return product(nn.silu(product(u, w_gate)) * product(u, w_up), w_down)
+
+
 def tile_sizes(sizes, start, rows: int):
     """What the rows ``start .. start + rows - 1`` hold of each group,
     (G,), where ``sizes`` (G,) are the groups' sizes and the groups lie one
@@ -149,22 +155,38 @@ def tile_sizes(sizes, start, rows: int):
             - jnp.clip(ends - sizes, start, start + rows))
 
 
-def _tile(acc, latent, weight, w1, w2, token, held, sizes):
-    """One tile of rows sorted by expert, added to their tokens: ``acc``,
-    ``latent`` (T, Z); ``token``, ``weight``, ``held`` (R,) as
-    ``held_pairs`` gives them; the banks ``w1`` (G, Z, F), ``w2`` (G, F, Z);
-    ``sizes`` (G,), what the tile holds of each expert.  A tile may span
-    several experts, and an expert several tiles.  XLA's grouped product
-    visits the row tiles the group sizes cover and leaves the rows past
-    their sum unwritten: nothing of those may pass, forward or backward
-    (``held`` is False there, and ``weight`` 0).  Returns ``acc`` plus, at
-    every token of the tile, ``weight * W2_e relu(W1_e latent)^2``, (T, Z)
-    float32."""
-    rows = jnp.where(held[:, None], latent[token], 0)
+def relu2_experts(rows, sizes, w1, w2):
+    """``W2_e relu(W1_e l)^2`` for rows sorted by expert: the banks ``w1``
+    (G, Z, F), ``w2`` (G, F, Z) in a latent space (Nemotron-3's
+    LatentMoE)."""
     hidden = relu2(lax.ragged_dot(rows, w1, sizes,
                                   preferred_element_type=rows.dtype))
-    out = lax.ragged_dot(hidden, w2, sizes,
-                         preferred_element_type=rows.dtype)
+    return lax.ragged_dot(hidden, w2, sizes,
+                          preferred_element_type=rows.dtype)
+
+
+def gated_experts(rows, sizes, w_gate, w_up, w_down):
+    """``W_d (silu(W_g u) * W_u u)`` for rows sorted by expert: three banks
+    (G, C, F), (G, C, F), (G, F, C) in the stream's own width."""
+    return gated(rows, w_gate, w_up, w_down, product=lambda a, bank:
+                 lax.ragged_dot(a, bank, sizes,
+                                preferred_element_type=rows.dtype))
+
+
+def _tile(experts, acc, latent, weight, banks, token, held, sizes):
+    """One tile of rows sorted by expert, added to their tokens: ``acc``,
+    ``latent`` (T, Z); ``token``, ``weight``, ``held`` (R,) as
+    ``held_pairs`` gives them; ``banks``, the experts' weights with the
+    held experts in front, and ``experts`` the function that takes rows
+    through them (``relu2_experts``, ``gated_experts``); ``sizes`` (G,),
+    what the tile holds of each expert.  A tile may span several experts,
+    and an expert several tiles.  XLA's grouped product visits the row
+    tiles the group sizes cover and leaves the rows past their sum
+    unwritten: nothing of those may pass, forward or backward (``held`` is
+    False there, and ``weight`` 0).  Returns ``acc`` plus, at every token
+    of the tile, ``weight * E_e(latent)``, (T, Z) float32."""
+    rows = jnp.where(held[:, None], latent[token], 0)
+    out = experts(rows, sizes, *banks)
     out = jnp.where(held[:, None], out, 0).astype(
         jnp.float32) * weight[:, None]
     return acc.at[token].add(out)
@@ -181,18 +203,21 @@ def _tiles_to_visit(sizes, tile: int):
     return (jnp.sum(sizes) + tile - 1) // tile
 
 
-def visit_row_tiles(tile: int, latent, weight, w1, w2, token, held, sizes):
+def visit_row_tiles(tile: int, latent, weight, *rest,
+                    experts=relu2_experts):
     """The held experts' part for blocks of tokens, (B, T, Z) float32, and
-    the tiles visited in each, (B,).  ``latent`` (B, T, Z); a block's rows
-    are ``held_pairs``' vectors ``token``, ``weight``, ``held`` (B, R; whole
-    tiles of ``tile``) and ``sizes`` (B, G); the banks (G, Z, F) and (G, F,
-    Z) are rounded to ``latent``'s precision once.  A block's rows go
-    through ``_tile`` in a loop whose trip count is its held pairs
+    the tiles visited in each, (B,).  ``latent`` (B, T, Z); ``rest`` is the
+    experts' banks (for ``relu2_experts`` (G, Z, F) and (G, F, Z)), rounded
+    to ``latent``'s precision once, and after them a block's rows:
+    ``held_pairs``' vectors ``token``, ``weight``, ``held`` (B, R; whole
+    tiles of ``tile``) and ``sizes`` (B, G).  A block's rows go through
+    ``_tile`` in a loop whose trip count is its held pairs
     (``sizes.sum()``) over ``tile``, rounded up, a value on the device.  The
     rows past the last visited tile are never read and never written back;
     at a routing that fills the rows' static bound, every tile is
     visited."""
-    w1, w2 = w1.astype(latent.dtype), w2.astype(latent.dtype)
+    *banks, token, held, sizes = rest
+    banks = tuple(w.astype(latent.dtype) for w in banks)
 
     def block(rows):
         latent, weight, token, held, sizes = rows
@@ -201,8 +226,8 @@ def visit_row_tiles(tile: int, latent, weight, w1, w2, token, held, sizes):
         def visit(i, acc):
             token_i, weight_i, held_i, sizes_i = _tile_at(
                 i, tile, token, weight, held, sizes)
-            return _tile(acc, latent, weight_i, w1, w2, token_i, held_i,
-                         sizes_i)
+            return _tile(experts, acc, latent, weight_i, banks, token_i,
+                         held_i, sizes_i)
 
         return lax.fori_loop(
             0, tiles, visit, jnp.zeros(latent.shape, jnp.float32)), tiles
@@ -210,15 +235,15 @@ def visit_row_tiles(tile: int, latent, weight, w1, w2, token, held, sizes):
     return lax.map(block, (latent, weight, token, held, sizes))
 
 
-def _visit_row_tiles_transposed(tile: int, latent, weight, w1, w2, token,
-                                held, sizes, g):
+def _visit_row_tiles_transposed(tile: int, experts, latent, weight, banks,
+                                token, held, sizes, g):
     """``g`` (B, T, Z) float32 pulled back through ``visit_row_tiles`` to
     ``latent``, ``weight`` and the banks: the same loops, each visit
     pulling its block's ``g`` back through its tile (``jax.vjp`` of
     ``_tile``).  A tile's part of ``d weight`` is its own slice; the banks'
     are summed in float32 over every tile of every block, in their own
     precision and not the products'."""
-    banks = w1.astype(latent.dtype), w2.astype(latent.dtype)
+    rounded = tuple(w.astype(latent.dtype) for w in banks)
 
     def block(d_banks, rows):
         latent, weight, token, held, sizes, g = rows
@@ -227,14 +252,14 @@ def _visit_row_tiles_transposed(tile: int, latent, weight, w1, w2, token,
             token_i, weight_i, held_i, sizes_i = _tile_at(
                 i, tile, token, weight, held, sizes)
             _, pull = jax.vjp(
-                lambda latent, weight_i, w1, w2: _tile(
-                    jnp.zeros_like(g), latent, weight_i, w1, w2, token_i,
-                    held_i, sizes_i),
-                latent, weight_i, *banks)
-            d_latent, d_weight_i, d_w1, d_w2 = pull(g)
+                lambda latent, weight_i, *banks: _tile(
+                    experts, jnp.zeros_like(g), latent, weight_i, banks,
+                    token_i, held_i, sizes_i),
+                latent, weight_i, *rounded)
+            d_latent, d_weight_i, *d_tile = pull(g)
             return (sums[0] + d_latent,
                     lax.dynamic_update_slice(sums[1], d_weight_i, (i * tile,)),
-                    sums[2] + d_w1, sums[3] + d_w2)
+                    *(total + d for total, d in zip(sums[2:], d_tile)))
 
         d_latent, d_weight, *d_banks = lax.fori_loop(
             0, _tiles_to_visit(sizes, tile), visit,
@@ -242,38 +267,46 @@ def _visit_row_tiles_transposed(tile: int, latent, weight, w1, w2, token,
              *d_banks))
         return tuple(d_banks), (d_latent.astype(latent.dtype), d_weight)
 
-    (d_w1, d_w2), (d_latent, d_weight) = lax.scan(
-        block, (jnp.zeros(w1.shape, jnp.float32),
-                jnp.zeros(w2.shape, jnp.float32)),
+    d_banks, (d_latent, d_weight) = lax.scan(
+        block, tuple(jnp.zeros(w.shape, jnp.float32) for w in banks),
         (latent, weight, token, held, sizes, g))
-    return d_latent, d_weight, d_w1.astype(w1.dtype), d_w2.astype(w2.dtype)
+    return d_latent, d_weight, tuple(
+        d.astype(w.dtype) for d, w in zip(d_banks, banks))
 
 
-def _a_client_at_a_time(visit, tile: int):
+def _a_client_at_a_time(visit):
     """Under ``vmap`` (a client axis) a loop whose trip count differs from
     client to client would run every client to the longest, and the chip's
     compiler takes no batch dimension on a grouped product: ``visit`` runs
     a client at a time."""
-    return sequential_vmap(functools.partial(visit, tile))
+    return sequential_vmap(visit)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def routed_rows(tile: int, latent, weight, w1, w2, token, held, sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def routed_rows(tile: int, experts, latent, weight, banks, token, held,
+                sizes):
     """``visit_row_tiles``' answer, differentiable in ``latent``, ``weight``
-    and the banks: a loop with a trip count on the device has no
+    and the banks (a tuple): a loop with a trip count on the device has no
     reverse-mode rule of its own.  The backward is handed the arguments
     and nothing else; ``vmap`` of a gradient meets the two rules below,
     each a client at a time, and no primitive's own."""
-    return _a_client_at_a_time(visit_row_tiles, tile)(
-        latent, weight, w1, w2, token, held, sizes)[0]
+    return _routed_rows_fwd(tile, experts, latent, weight, banks, token,
+                            held, sizes)[0]
 
 
-def _routed_rows_fwd(tile, *args):
-    return _a_client_at_a_time(visit_row_tiles, tile)(*args)[0], args
+def _routed_rows_fwd(tile, experts, latent, weight, banks, token, held,
+                     sizes):
+    visit = functools.partial(visit_row_tiles, tile, experts=experts)
+    out, _ = _a_client_at_a_time(
+        lambda latent, weight, banks, *rows: visit(
+            latent, weight, *banks, *rows))(
+        latent, weight, banks, token, held, sizes)
+    return out, (latent, weight, banks, token, held, sizes)
 
 
-def _routed_rows_bwd(tile, args, g):
-    return (*_a_client_at_a_time(_visit_row_tiles_transposed, tile)(*args, g),
+def _routed_rows_bwd(tile, experts, args, g):
+    return (*_a_client_at_a_time(functools.partial(
+        _visit_row_tiles_transposed, tile, experts))(*args, g),
             None, None, None)
 
 
@@ -306,24 +339,20 @@ def held_pairs(chosen, weights, first: int, count: int):
             held, sizes)
 
 
-class LatentMoEShare(nn.Module):
-    """One chip's share of a sigmoid-routed mixture whose experts work in a
-    latent space (Nemotron-3's LatentMoE), beside a shared expert in the
-    full width.
+class ExpertShare(nn.Module):
+    """What the shares of a sigmoid-routed mixture have in common, whatever
+    their experts compute: the router over all the experts, the rows of
+    the held ones, the loop over their tiles.  A subclass declares the
+    sizes (``embed_dim``, ``experts_total``, ``experts_held``, ``top_k``,
+    ``routed_scale``, ``token_block``, ``row_tile``, ``dtype``,
+    ``init_std``) and, in ``setup``, calls ``setup_router``.
 
     The router scores all ``experts_total`` experts in float32, ``s =
     sigmoid(u W_r)``; a token's ``top_k`` experts are the largest of ``s +
     b`` (``b``, the correction bias, enters the choice alone, so its
     gradient is 0) and weigh ``w_e = scale * s_e / sum over all chosen of
     s`` (``norm_topk``), held here or not.  This chip holds the experts
-    ``experts_held = (first, count)``.  With ``l = u W_down`` the result is
-
-        (sum over e chosen and held of w_e W2_e relu(W1_e l)^2) W_up
-            + W2_s relu(W1_s u)^2
-
-    so the parts that the shares of all chips give, with the shared expert
-    counted once and ``W_up`` applied to their sum, add up to the whole
-    layer; what the absent experts would add is left out.
+    ``experts_held = (first, count)``.
 
     No token is dropped and no shape depends on the routing.  Tokens go
     through in blocks of ``token_block``; in a block, the (token, choice)
@@ -331,7 +360,7 @@ class LatentMoEShare(nn.Module):
     the first ``token_block * min(top_k, count)`` of them are the block's
     rows (``held_pairs``; every held pair is among them: a token falls on a
     held expert at most that often).  That bound is the length of four
-    vectors and of nothing else: the rows are gathered, taken through two
+    vectors and of nothing else: the rows are gathered, taken through the
     grouped products over the experts' banks and added back to their
     tokens under their weights a tile of ``row_tile`` at a time, by a loop
     that visits the tiles the held pairs fill and no others
@@ -340,6 +369,81 @@ class LatentMoEShare(nn.Module):
     the device, so the blocks' loops are one ``jax.custom_vjp``
     (``routed_rows``): its backward is handed the forward's arguments and
     runs the same loops, a tile's forward made again inside them.
+    """
+
+    def setup_router(self):
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= self.experts_total):
+            raise ValueError(
+                f"experts_held {self.experts_held} is no range of the "
+                f"{self.experts_total} experts")
+        if self.top_k > self.experts_total:
+            raise ValueError(f"top_k {self.top_k} of {self.experts_total}")
+        self.router = self.param(
+            "router", nn.initializers.normal(self.init_std),
+            (self.embed_dim, self.experts_total))
+        self.router_bias = self.param(
+            "router_bias", nn.initializers.zeros, (self.experts_total,))
+
+    def route(self, u32):
+        """``u32``: (N, D) float32.  The chosen experts (N, top_k) and
+        their weights (N, top_k) float32."""
+        scores = nn.sigmoid(jnp.dot(
+            u32, self.router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, chosen = lax.top_k(scores + self.router_bias, self.top_k)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = self.routed_scale * picked / picked.sum(-1, keepdims=True)
+        return chosen, weights
+
+    def routed(self, experts, banks, chosen, weights, rows_in):
+        """``sum over e chosen and held of w_e E_e(rows_in)``, (N, Z) in
+        ``dtype``: ``chosen`` and ``weights`` are ``route``'s, ``rows_in``
+        (N, Z) is what the experts read, ``experts`` and ``banks`` as
+        ``_tile`` takes them."""
+        tokens = rows_in.shape[0]
+        block = min(self.token_block, tokens)
+        if tokens % block:
+            raise ValueError(
+                f"{tokens} tokens are not whole blocks of {block}")
+        count = self.experts_held[1]
+        # Set at trace time, on every build.
+        registry = telemetry.get_registry()
+        registry.gauge("moe.experts_held").set(count)
+        registry.gauge("moe.experts_total").set(self.experts_total)
+        registry.gauge("moe.top_k").set(self.top_k)
+        bound = min(self.top_k, count)
+        tile = min(self.row_tile, block * bound)
+        registry.gauge("moe.dispatch_rows").set(tokens * bound)
+        registry.gauge("moe.row_tile").set(tile)
+
+        def blocks(a):
+            return a.reshape(tokens // block, block, *a.shape[1:])
+
+        token, weight, held, sizes = lax.map(
+            lambda pairs: held_pairs(*pairs, *self.experts_held),
+            (blocks(chosen), blocks(weights)))
+        # Whole tiles; what is added holds no pair.
+        token, weight, held = (
+            jnp.pad(a, ((0, 0), (0, -a.shape[1] % tile)))
+            for a in (token, weight, held))
+        out = routed_rows(tile, experts, blocks(rows_in), weight, banks,
+                          token, held, sizes)
+        return out.astype(self.dtype).reshape(tokens, -1)
+
+
+class LatentMoEShare(ExpertShare):
+    """One chip's share of a mixture whose experts work in a latent space
+    (Nemotron-3's LatentMoE), beside a shared expert in the full width.
+    With ``l = u W_down`` the result is
+
+        (sum over e chosen and held of w_e W2_e relu(W1_e l)^2) W_up
+            + W2_s relu(W1_s u)^2
+
+    so the parts that the shares of all chips give, with the shared expert
+    counted once and ``W_up`` applied to their sum, add up to the whole
+    layer; what the absent experts would add is left out.
     """
 
     embed_dim: int
@@ -359,20 +463,11 @@ class LatentMoEShare(nn.Module):
     out_scale: float = 1.0          # on the maps back into the stream
 
     def setup(self):
-        first, count = self.experts_held
-        if not (0 <= first and count >= 1
-                and first + count <= self.experts_total):
-            raise ValueError(
-                f"experts_held {self.experts_held} is no range of the "
-                f"{self.experts_total} experts")
-        if self.top_k > self.experts_total:
-            raise ValueError(f"top_k {self.top_k} of {self.experts_total}")
+        self.setup_router()
+        count = self.experts_held[1]
         init = nn.initializers.normal(self.init_std)
         out_init = nn.initializers.normal(self.init_std * self.out_scale)
         D, Z, F = self.embed_dim, self.latent_dim, self.expert_dim
-        self.router = self.param("router", init, (D, self.experts_total))
-        self.router_bias = self.param(
-            "router_bias", nn.initializers.zeros, (self.experts_total,))
         self.latent_down = self.param("latent_down", init, (D, Z))
         self.latent_up = self.param("latent_up", out_init, (Z, D))
         self.experts_w1 = self.param("experts_w1", init, (count, Z, F))
@@ -381,51 +476,13 @@ class LatentMoEShare(nn.Module):
         self.shared_w2 = self.param("shared_w2", out_init,
                                     (self.shared_dim, D))
 
-    def route(self, u32):
-        """``u32``: (N, D) float32.  The chosen experts (N, top_k) and
-        their weights (N, top_k) float32."""
-        scores = nn.sigmoid(jnp.dot(
-            u32, self.router.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        _, chosen = lax.top_k(scores + self.router_bias, self.top_k)
-        picked = jnp.take_along_axis(scores, chosen, axis=-1)
-        weights = self.routed_scale * picked / picked.sum(-1, keepdims=True)
-        return chosen, weights
-
     def routed_latent(self, u, u32):
         """``sum over e chosen and held of w_e E_e(l)``, (N, Z): the part
         of the layer that differs from share to share."""
-        tokens = u.shape[0]
-        block = min(self.token_block, tokens)
-        if tokens % block:
-            raise ValueError(
-                f"{tokens} tokens are not whole blocks of {block}")
-        count = self.experts_held[1]
-        # Set at trace time, on every build.
-        registry = telemetry.get_registry()
-        registry.gauge("moe.experts_held").set(count)
-        registry.gauge("moe.experts_total").set(self.experts_total)
-        registry.gauge("moe.top_k").set(self.top_k)
-        bound = min(self.top_k, count)
-        tile = min(self.row_tile, block * bound)
-        registry.gauge("moe.dispatch_rows").set(tokens * bound)
-        registry.gauge("moe.row_tile").set(tile)
-        chosen, weights = self.route(u32)
-        latent = jnp.dot(u, self.latent_down.astype(self.dtype))
-
-        def blocks(a):
-            return a.reshape(tokens // block, block, *a.shape[1:])
-
-        token, weight, held, sizes = lax.map(
-            lambda pairs: held_pairs(*pairs, *self.experts_held),
-            (blocks(chosen), blocks(weights)))
-        # Whole tiles; what is added holds no pair.
-        token, weight, held = (
-            jnp.pad(a, ((0, 0), (0, -a.shape[1] % tile)))
-            for a in (token, weight, held))
-        out = routed_rows(tile, blocks(latent), weight, self.experts_w1,
-                          self.experts_w2, token, held, sizes)
-        return out.astype(self.dtype).reshape(tokens, -1)
+        return self.routed(
+            relu2_experts, (self.experts_w1, self.experts_w2),
+            *self.route(u32),
+            jnp.dot(u, self.latent_down.astype(self.dtype)))
 
     def shared(self, u):
         return jnp.dot(relu2(jnp.dot(u, self.shared_w1.astype(self.dtype))),
@@ -440,3 +497,63 @@ class LatentMoEShare(nn.Module):
         out = jnp.dot(self.routed_latent(u, u32),
                       self.latent_up.astype(self.dtype)) + self.shared(u)
         return out.reshape(*lead, -1)
+
+
+class GatedMoEShare(ExpertShare):
+    """One chip's share of a mixture of gated experts in the stream's own
+    width (DeepSeek-V3's form, arXiv:2412.19437), beside a shared expert of
+    the same form:
+
+        sum over e chosen and held of w_e E_e(u) + E_s(u),
+        E(u) = W_d (silu(W_g u) * W_u u)
+
+    There are no latent maps: the parts that the shares of all chips give,
+    with the shared expert counted once, add up to the whole layer.
+    """
+
+    embed_dim: int
+    expert_dim: int
+    shared_dim: int
+    experts_total: int
+    experts_held: tuple[int, int]
+    top_k: int
+    routed_scale: float = 1.0
+    token_block: int = 4096
+    row_tile: int = 4096
+    dtype: jnp.dtype = jnp.float32
+    init_std: float = 0.02
+    out_scale: float = 1.0          # on the maps back into the stream
+
+    def setup(self):
+        self.setup_router()
+        count = self.experts_held[1]
+        init = nn.initializers.normal(self.init_std)
+        out_init = nn.initializers.normal(self.init_std * self.out_scale)
+        D, F, S = self.embed_dim, self.expert_dim, self.shared_dim
+        self.experts_gate = self.param("experts_gate", init, (count, D, F))
+        self.experts_up = self.param("experts_up", init, (count, D, F))
+        self.experts_down = self.param("experts_down", out_init,
+                                       (count, F, D))
+        self.shared_gate = self.param("shared_gate", init, (D, S))
+        self.shared_up = self.param("shared_up", init, (D, S))
+        self.shared_down = self.param("shared_down", out_init, (S, D))
+
+    def routed_part(self, u, u32):
+        """``sum over e chosen and held of w_e E_e(u)``, (N, D): the part
+        of the layer that differs from share to share."""
+        return self.routed(
+            gated_experts,
+            (self.experts_gate, self.experts_up, self.experts_down),
+            *self.route(u32), u)
+
+    def shared(self, u):
+        return gated(u, *(w.astype(self.dtype) for w in (
+            self.shared_gate, self.shared_up, self.shared_down)))
+
+    def __call__(self, u32):
+        """``u32``: (..., D), the normed stream in float32.  Returns (...,
+        D) in ``dtype``."""
+        lead = u32.shape[:-1]
+        u32 = u32.reshape(-1, u32.shape[-1]).astype(jnp.float32)
+        u = u32.astype(self.dtype)
+        return (self.routed_part(u, u32) + self.shared(u)).reshape(*lead, -1)

@@ -10,7 +10,8 @@ from colearn_federated_learning_tpu.utils.config import ModelConfig
 
 # Families whose blocks can be rematerialised, and those of them that run
 # inside ``shard_map`` on a shard of the sequence.
-REMAT_FAMILIES = ("bert", "moe_bert", "vit_b16", "evabyte", "nemotron_h")
+REMAT_FAMILIES = ("bert", "moe_bert", "vit_b16", "evabyte", "nemotron_h",
+                  "xing4")
 SEQ_PARALLEL_FAMILIES = ("bert", "moe_bert")
 
 
@@ -120,6 +121,31 @@ def build_model(cfg: ModelConfig, seq_axis_name: str | None = None):
             num_kv_heads=cfg.num_kv_heads or cfg.num_heads,
             head_dim=cfg.head_dim or cfg.width // cfg.num_heads,
             dtype=dtype, attn_impl=cfg.attn_impl, remat=cfg.remat)
+    if cfg.name == "xing4":
+        from colearn_federated_learning_tpu.models.mla import MLA_IMPLS
+        from colearn_federated_learning_tpu.models.xing4 import Xing4
+
+        if cfg.attn_impl not in MLA_IMPLS:
+            raise ValueError(
+                f"xing4's attention runs as {MLA_IMPLS} on one device, not "
+                f"{cfg.attn_impl!r}")
+        return Xing4(
+            vocab_size=cfg.vocab_size, embed_dim=cfg.width, depth=cfg.depth,
+            dense_layers=cfg.dense_layers, streams=cfg.hc_streams,
+            sinkhorn_iters=cfg.sinkhorn_iters,
+            sinkhorn_eps=cfg.sinkhorn_eps,
+            res_clamp=(cfg.res_clamp_min, cfg.res_clamp_max),
+            num_heads=cfg.num_heads, q_rank=cfg.q_rank, kv_rank=cfg.kv_rank,
+            nope_dim=cfg.nope_dim, rope_dim=cfg.rope_dim, v_dim=cfg.v_dim,
+            rope_theta=cfg.rope_theta,
+            yarn=(cfg.yarn_factor, cfg.yarn_original_max, cfg.yarn_beta_fast,
+                  cfg.yarn_beta_slow, cfg.yarn_mscale_all_dim),
+            ffn_dim=cfg.ffn_dim, experts_total=cfg.num_experts,
+            experts_held=(cfg.experts_first, cfg.experts_held),
+            top_k=cfg.experts_per_token, expert_dim=cfg.expert_dim,
+            shared_dim=cfg.shared_expert_dim, routed_scale=cfg.routed_scale,
+            mtp_modules=cfg.mtp_modules, norm_eps=cfg.norm_eps, dtype=dtype,
+            attn_impl=cfg.attn_impl, remat=cfg.remat)
     raise KeyError(f"unknown model {cfg.name!r}")
 
 

@@ -116,17 +116,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     """One (batch·head, q-block) tile; K/V for the whole row are VMEM-resident.
 
     q_ref: (1, block_q, D) — this tile's queries
-    k_ref, v_ref: (1, Lk, D) — all keys/values for this batch·head
+    k_ref: (1, Lk, D), v_ref: (1, Lk, Dv) — all keys/values for this
+      batch·head; the values may have a width of their own
     bias_ref: (1, Lk, 1) — additive key bias (0 valid / _NEG masked).  The
       sequence dim sits on the SUBLANE axis with a singleton lane dim:
       Mosaic requires a block's lane dim be 128-divisible or span the whole
       array, and in-kernel dynamic slices must be lane-aligned — k-block
       offsets are only 8-aligned, which the sublane axis accepts.
-    o_ref: (1, block_q, D)
+    o_ref: (1, block_q, Dv)
     lse_ref: (1, block_q, 1) — per-row logsumexp, the backward residual
     """
     Lk = k_ref.shape[1]
-    D = q_ref.shape[2]
+    Dv = v_ref.shape[2]
     num_kb = Lk // block_k
     qb = pl.program_id(1)
 
@@ -164,7 +165,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
             num_kb, pl.cdiv(_shifted((qb + 1) * block_q, prefix), block_k))
     m0 = jnp.full((q.shape[0], 1), _NEG, jnp.float32)
     l0 = jnp.zeros((q.shape[0], 1), jnp.float32)
-    acc0 = jnp.zeros((q.shape[0], D), jnp.float32)
+    acc0 = jnp.zeros((q.shape[0], Dv), jnp.float32)
     m, l, acc = lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
     # Fully-masked rows (l == 0) get lse = +BIG so the backward's
@@ -208,15 +209,23 @@ def _blocks(q, k, v, kv_mask, block_q, block_k, interpret):
     return (B, Lq, H, D, Lk, bq, bk, Lq_p, Lk_p, to_rows, bias, interpret)
 
 
+def _scale(scale: Optional[float], D: int) -> float:
+    """The factor on the scores: ``D ** -0.5`` unless the caller has its
+    own (latent attention under yarn multiplies it)."""
+    return 1.0 / (D ** 0.5) if scale is None else scale
+
+
 def _flash_impl(q, k, v, kv_mask, causal: bool,
                 block_q: int, block_k: int, interpret: Optional[bool],
-                return_lse: bool = False, prefix: int = 0):
+                return_lse: bool = False, prefix: int = 0,
+                scale: Optional[float] = None):
     (B, Lq, H, D, Lk, bq, bk, Lq_p, Lk_p, to_rows, bias,
      interpret) = _blocks(q, k, v, kv_mask, block_q, block_k, interpret)
     qr, kr, vr = to_rows(q, Lq_p), to_rows(k, Lk_p), to_rows(v, Lk_p)
+    Dv = v.shape[-1]
 
     kernel = functools.partial(
-        _flash_kernel, block_k=bk, scale=1.0 / (D ** 0.5),
+        _flash_kernel, block_k=bk, scale=_scale(scale, D),
         causal=causal, block_q=bq, prefix=prefix,
     )
     out, lse = pl.pallas_call(
@@ -226,21 +235,21 @@ def _flash_impl(q, k, v, kv_mask, causal: bool,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Lk_p, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Lk_p, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Lk_p, Dv), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, Lk_p, 1), lambda b, i: (b // H, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Lq_p, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Lq_p, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, Lq_p, 1), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=None if interpret else _tpu_params(),
     )(qr, kr, vr, bias)
-    out = out.reshape(B, H, Lq_p, D).transpose(0, 2, 1, 3)[:, :Lq]
+    out = out.reshape(B, H, Lq_p, Dv).transpose(0, 2, 1, 3)[:, :Lq]
     if return_lse:
         return out, lse                                    # lse stays padded
     return out
@@ -338,18 +347,18 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         qb0 = (kb * block_k) // block_q
         if prefix:
             qb0 = jnp.maximum(kb * block_k - prefix, 0) // block_q
-    D = k_blk.shape[1]
     dk, dv = lax.fori_loop(
         qb0, num_qb, body,
-        (jnp.zeros((k_blk.shape[0], D), jnp.float32),
-         jnp.zeros((k_blk.shape[0], D), jnp.float32)),
+        (jnp.zeros(k_blk.shape, jnp.float32),
+         jnp.zeros(v_blk.shape, jnp.float32)),
     )
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
-                    block_q, block_k, interpret, prefix: int = 0):
+                    block_q, block_k, interpret, prefix: int = 0,
+                    scale: Optional[float] = None):
     (B, Lq, H, D, Lk, bq, bk, Lq_p, Lk_p, to_rows, bias,
      interpret) = _blocks(q, k, v, kv_mask, block_q, block_k, interpret)
     qr, kr, vr = to_rows(q, Lq_p), to_rows(k, Lk_p), to_rows(v, Lk_p)
@@ -360,7 +369,8 @@ def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
     delta = jnp.sum(gr.astype(jnp.float32) * outr.astype(jnp.float32),
                     axis=-1)[:, :, None]                     # (B·H, Lq_p, 1)
 
-    scale = 1.0 / (D ** 0.5)
+    scale = _scale(scale, D)
+    Dv = v.shape[-1]
     dq_kernel = functools.partial(_flash_dq_kernel, block_k=bk, scale=scale,
                                   causal=causal, block_q=bq, prefix=prefix)
     dq = pl.pallas_call(
@@ -370,9 +380,9 @@ def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Lk_p, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Lk_p, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Lk_p, Dv), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, Lk_p, 1), lambda b, i: (b // H, 0, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
         ],
@@ -391,19 +401,19 @@ def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
         in_specs=[
             pl.BlockSpec((1, Lq_p, D), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, bk, 1), lambda b, j: (b // H, j, 0)),
-            pl.BlockSpec((1, Lq_p, D), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, Lq_p, Dv), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, Lq_p, 1), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, Lq_p, 1), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Lk_p, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Lk_p, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H, Lk_p, Dv), v.dtype),
         ],
         interpret=interpret,
         compiler_params=None if interpret else _tpu_params(),
@@ -416,16 +426,18 @@ def _flash_bwd_impl(q, k, v, kv_mask, out, lse, g, causal,
             from_rows(dv, Lk, Lk_p))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, kv_mask, causal, block_q, block_k, interpret, prefix):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, kv_mask, causal, block_q, block_k, interpret, prefix,
+           scale):
     return _flash_impl(q, k, v, kv_mask, causal, block_q, block_k, interpret,
-                       prefix=prefix)
+                       prefix=prefix, scale=scale)
 
 
 def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
-               prefix):
+               prefix, scale):
     out, lse = _flash_impl(q, k, v, kv_mask, causal, block_q, block_k,
-                           interpret, return_lse=True, prefix=prefix)
+                           interpret, return_lse=True, prefix=prefix,
+                           scale=scale)
     # The log-sum is held dense, (B·H, Lq_p): as the kernel writes it, its
     # singleton lane dimension is padded to a 128-lane tile on the chip, and
     # a kept copy would take 128 × the room.
@@ -435,13 +447,14 @@ def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
     return out, (q, k, v, kv_mask, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, prefix, res, g):
+def _flash_bwd(causal, block_q, block_k, interpret, prefix, scale, res, g):
     # Blockwise Pallas backward (FlashAttention-2): probabilities are
     # recomputed tile-by-tile from the saved logsumexp — exact gradients,
     # no (L, L) matrix in either direction.
     q, k, v, kv_mask, out, lse = res
     dq, dk, dv = _flash_bwd_impl(q, k, v, kv_mask, out, lse[..., None], g,
-                                 causal, block_q, block_k, interpret, prefix)
+                                 causal, block_q, block_k, interpret, prefix,
+                                 scale)
     return dq, dk, dv, None
 
 
@@ -459,6 +472,7 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     prefix: int = 0,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Blockwise (flash) attention over ``(B, L, H, D)`` tensors.
 
@@ -472,6 +486,10 @@ def flash_attention(
     ``k`` and ``v`` may have fewer heads than ``q`` (grouped-query
     attention: ``H_q`` a multiple of ``H_kv``); query head ``h`` reads
     key/value head ``h // (H_q // H_kv)``.
+    ``v`` may have a width of its own (latent attention scores over 192
+    and weighs values of 128): the result, its gradient and the kept
+    ``flash_out`` have the values' width.  ``scale`` (static) multiplies
+    the scores; ``None`` is ``D ** -0.5`` of the queries' width.
     ``interpret=None`` auto-selects Pallas interpret mode off-TPU.
     ``block_q``/``block_k`` default per TPU generation (512 on v4+, 128 on
     v2/v3 whose smaller VMEM rejects the large configuration).
@@ -492,5 +510,9 @@ def flash_attention(
         # Every query head is handed its own copy of the head it shares;
         # the copies' gradients sum in the repeat's transpose.
         k, v = (jnp.repeat(a, heads // kv_heads, axis=2) for a in (k, v))
+    if k.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            f"queries of width {q.shape[-1]} cannot score keys of width "
+            f"{k.shape[-1]}")
     return _flash(q, k, v, kv_mask, causal, block_q, block_k, interpret,
-                  prefix)
+                  prefix, scale)

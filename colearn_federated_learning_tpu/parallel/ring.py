@@ -128,14 +128,18 @@ def dense_attention(
     kv_mask: Optional[jax.Array] = None,
     *,
     causal: bool = False,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Single-device reference with the same (B, L, H, D) signature — the
     numerics oracle ring/flash attention are tested against, and the
-    ``attn_impl="dense"`` core in models/attention.py."""
+    ``attn_impl="dense"`` core in models/attention.py.  ``v`` may have a
+    width of its own; ``scale`` multiplies the scores in place of ``D **
+    -0.5``."""
     Lq, Lk = q.shape[1], k.shape[1]
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) / (q.shape[-1] ** 0.5)
+    )
+    logits = logits / (q.shape[-1] ** 0.5) if scale is None else logits * scale
     if kv_mask is not None:
         logits = jnp.where(kv_mask[:, None, None, :], logits, _NEG)
     if causal:

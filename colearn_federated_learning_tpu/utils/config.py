@@ -230,6 +230,38 @@ class ModelConfig:
     routed_scale: float = 5.0
     num_kv_heads: int = 0
     head_dim: int = 0
+    # Latent-attention decoder on a residual path of several streams
+    # (models/xing4.py): ``depth`` layers, the first ``dense_layers`` with
+    # a gated feed-forward of ``ffn_dim``, the others with this chip's
+    # share of ``num_experts`` gated experts (``experts_first``,
+    # ``experts_held``, ``experts_per_token``, ``expert_dim``,
+    # ``shared_expert_dim``, ``routed_scale`` as above).  The path
+    # (models/mhc.py): ``hc_streams`` rows a token, mixed by maps that
+    # ``sinkhorn_iters`` iterations with ``sinkhorn_eps`` make doubly
+    # stochastic from scores clipped to ``res_clamp_*``.  Attention
+    # (models/mla.py): ``num_heads`` heads, the low ranks of the query and
+    # key/value paths, a head's width without and with positions and its
+    # values' width; ``rope_theta`` under yarn (``yarn_factor`` 1: plain
+    # rotary).  ``mtp_modules`` sequential prediction modules (0 or 1):
+    # the logits then have 1 + ``mtp_modules`` heads a position.
+    dense_layers: int = 1
+    hc_streams: int = 4
+    sinkhorn_iters: int = 20
+    sinkhorn_eps: float = 1e-6
+    res_clamp_min: float = -30.0
+    res_clamp_max: float = 30.0
+    q_rank: int = 768
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    yarn_factor: float = 64.0
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 1.0
+    mtp_modules: int = 0
+    norm_eps: float = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -540,6 +572,27 @@ CONFIGS["nemotron_h_fedavg"] = _cfg(
     fed=FedConfig(strategy="fedavg", rounds=20, cohort_size=1,
                   local_steps=2, batch_size=1, lr=0.003, momentum=0.0),
     run=RunConfig(name="nemotron_h_fedavg", eval_every=2),
+)
+
+
+# A sparse latent-attention language model on a widened residual path:
+# Xing4.0-29B-A4B at its published widths as one chip's share of a stated
+# deployment (5 of 40 layers, 1 of them dense; 8 of 64 experts and an
+# eighth of the vocabulary, attention whole; without the prediction module,
+# which the parity probe has no room for: PERF.md section 4).  One example
+# is one sequence of 8,192 tokens with the token after each position as
+# its label (dataset ``tokens_ahead`` at a horizon of 1).
+CONFIGS["xing4_fedavg"] = _cfg(
+    data=DataConfig(dataset="tokens_ahead", num_clients=8, partition="iid"),
+    model=ModelConfig(name="xing4", num_classes=16384, vocab_size=16384,
+                      width=3584, depth=5, num_heads=32, seq_len=8192,
+                      ffn_dim=9216, rope_theta=10000.0, num_experts=64,
+                      experts_per_token=4, expert_dim=1024,
+                      shared_expert_dim=1024, routed_scale=2.0, mtp_modules=0,
+                      dtype="bfloat16", attn_impl="flash", remat=True),
+    fed=FedConfig(strategy="fedavg", rounds=20, cohort_size=1,
+                  local_steps=2, batch_size=1, lr=0.003, momentum=0.0),
+    run=RunConfig(name="xing4_fedavg", eval_every=2),
 )
 
 

@@ -81,6 +81,36 @@ def test_eva_attention_compiles_at_the_published_widths(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 
+def test_latent_attention_kernels_compile_at_the_published_widths(
+        one_chip, as_on_the_chip):
+    """One sequence of 8,192 positions, 32 heads, scores over 192 (128 + 64
+    rotary) and values of 128 under a scale of the caller's: Mosaic takes
+    the 192-wide blocks as the arrays' full last dimension, forward and
+    both backward kernels, and the result has the values' width."""
+    from colearn_federated_learning_tpu.ops.attention import flash_attention
+
+    def shape(width):
+        return jax.ShapeDtypeStruct((1, 8192, 32, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss_and_grads(q, k, v):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, causal=True,
+                                  scale=2.0048 * 192 ** -0.5)
+            assert out.shape == (1, 8192, 32, 128)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(loss_and_grads).lower(
+        shape(192), shape(192), shape(128)).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in text and "tpu_custom_call" in text, name
+    assert "8192,8192]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 def test_a_rematerialised_step_runs_flash_fwd_once_a_layer(one_chip,
                                                            as_on_the_chip):
     """One local step (gradient and SGD, the weights donated) of the

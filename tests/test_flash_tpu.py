@@ -114,3 +114,40 @@ def test_flash_long_bf16_forward_backward_on_tpu():
     assert rel_err(out, ref) < 2e-2
     for a, b in zip(gf, gd):
         assert rel_err(a, b) < 2e-2
+
+
+def test_flash_values_narrower_than_scores_bf16_on_tpu():
+    """Latent attention's shapes on the hardware: L = 2048, scores over 192
+    (one and a half 128-lane tiles) and values of 128, causal, a scale of
+    the caller's, bf16; forward and the three gradients against the
+    written-out scores in float32."""
+    from colearn_federated_learning_tpu.ops.attention import flash_attention
+    from colearn_federated_learning_tpu.parallel.ring import dense_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (1, 2048, 4, 192)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, 2048, 4, 192)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, 2048, 4, 128)).astype(jnp.bfloat16)
+    scale = 2.0048 * 192 ** -0.5
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=True, scale=scale).astype(jnp.float32) ** 2)
+
+    def rel_err(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        return np.abs(a - b).max() / np.abs(b).max()
+
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        ref = dense_attention(*f32, causal=True, scale=scale)
+        gd = jax.grad(loss(dense_attention), argnums=(0, 1, 2))(*f32)
+    flash = lambda q, k, v, **kw: flash_attention(  # noqa: E731
+        q, k, v, interpret=False, **kw)
+    out = jax.jit(lambda q, k, v: flash(q, k, v, causal=True, scale=scale))(
+        q, k, v)
+    gf = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    assert out.shape == (1, 2048, 4, 128)
+    assert rel_err(out, ref) < 2e-2
+    for a, b in zip(gf, gd):
+        assert a.shape == b.shape and rel_err(a, b) < 2e-2

@@ -164,3 +164,83 @@ def test_flash_backward_fully_masked_rows_zero_grad():
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-5)
+
+
+# --- values narrower than the scores, and a scale of the caller's --------------
+
+
+def _oracle(q, k, v, mask, scale, causal, prefix):
+    """The written-out scores on repeated heads: ``scale`` on them, the
+    first ``prefix`` keys seen by every query, the others causally."""
+    share = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, share, axis=2), jnp.repeat(v, share, axis=2)
+    if not prefix:
+        return dense_attention(q, k, v, mask, causal=causal, scale=scale)
+    Lq, Lk = q.shape[1], k.shape[1]
+    seen = (jnp.arange(Lk)[None, :] < prefix) | (
+        jnp.arange(Lq)[:, None] >= jnp.arange(Lk)[None, :] - prefix)
+    if mask is not None:
+        seen = seen[None] & mask[:, None, :]
+    else:
+        seen = seen[None]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    weights = jax.nn.softmax(jnp.where(seen[:, None], scores, -1e30), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+@pytest.mark.parametrize("case", ["plain", "causal", "masked_causal",
+                                  "prefix", "shared_heads"])
+@pytest.mark.parametrize("widths", [(12, 8), (192, 128)],
+                         ids=["12_8", "192_128"])
+def test_flash_values_narrower_than_scores(widths, case):
+    """Scores over ``d`` and values of ``d_v``, under an explicit ``scale``
+    (latent attention's 192 / 128 with yarn's factor, and a small pair):
+    forward and the three gradients against the written-out scores, with
+    ``causal``, a key mask, a ``prefix`` and fewer key/value heads; the
+    result and ``dv`` have the values' width."""
+    d, d_v = widths
+    B, L, H = 2, 24, 4
+    prefix = 8 if case == "prefix" else 0
+    kv_heads = 2 if case == "shared_heads" else H
+    causal = case != "plain"
+    scale = 2.0048 * d ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(d), 3)
+    q = jax.random.normal(ks[0], (B, L, H, d))
+    k = jax.random.normal(ks[1], (B, prefix + L, kv_heads, d))
+    v = jax.random.normal(ks[2], (B, prefix + L, kv_heads, d_v))
+    mask = None
+    if case == "masked_causal":
+        mask = jnp.ones((B, L), bool).at[0, 3].set(False).at[1, 10:13].set(
+            False)
+
+    def total(f):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v))), argnums=(0, 1, 2)))
+
+    got, got_g = total(lambda q, k, v: flash_attention(
+        q, k, v, mask, causal=causal, prefix=prefix, scale=scale, block_q=8,
+        block_k=8))(q, k, v)
+    want, want_g = total(lambda q, k, v: _oracle(
+        q, k, v, mask, scale, causal, prefix))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    out = flash_attention(q, k, v, mask, causal=causal, prefix=prefix,
+                          scale=scale, block_q=8, block_k=8)
+    assert out.shape == (B, L, H, d_v)
+    for name, g, w in zip("qkv", got_g, want_g):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_flash_scale_defaults_to_the_queries_width():
+    """No ``scale`` is ``D ** -0.5``, as it always was; keys of another
+    width than the queries are refused."""
+    q, k, v, mask = _rand(jax.random.PRNGKey(11), B=1, L=16, H=2, D=8)
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, mask, block_q=8, block_k=8),
+        flash_attention(q, k, v, mask, block_q=8, block_k=8, scale=8 ** -0.5),
+        rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        dense_attention(q, k, v, mask),
+        dense_attention(q, k, v, mask, scale=8 ** -0.5), rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="cannot score keys"):
+        flash_attention(q, k[..., :4], v)
