@@ -187,12 +187,14 @@ GAUGES = (
     # experts this chip holds, of how
     # many, a token's choices, the static rows of a layer's grouped
     # products a step (tokens x the most held experts a token can choose),
-    # and the rows of a tile of the loop that visits those the routing filled
+    # the rows of a tile of the loop that visits those the routing filled,
+    # and the keys of the one sort that puts a block's held pairs in rows
     "moe.experts_held",
     "moe.experts_total",
     "moe.top_k",
     "moe.dispatch_rows",
     "moe.row_tile",
+    "moe.pair_sort_keys",
     # models/xing4.py, set at trace time on every build: layers of the
     # stack, labeled {kind=dense|moe}, and sequential prediction modules
     "xing4.layers",

@@ -313,30 +313,77 @@ def _routed_rows_bwd(tile, experts, args, g):
 routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
 
 
+@jax.custom_vjp
+def _sorted_with(keys, values):
+    """``keys`` (N,), no two alike, in rising order and ``values`` (N,) in
+    the order that puts them in: one sort of the two side by side,
+    differentiable in ``values``."""
+    return lax.sort((keys, values), num_keys=1, is_stable=False)
+
+
+def _sorted_with_fwd(keys, values):
+    keys, values = _sorted_with(keys, values)
+    return (keys, values), keys
+
+
+def _sorted_with_bwd(place, g):
+    """``held_pairs``' keys, sorted, name the cell each came from: a second
+    sort on those takes ``g`` back, and nothing is scattered."""
+    return None, lax.sort((lax.rem(place, place.shape[-1]), g[1]), num_keys=1,
+                          is_stable=False)[1]
+
+
+_sorted_with.defvjp(_sorted_with_fwd, _sorted_with_bwd)
+
+
+def picked_scores(scores, chosen):
+    """``scores[t, chosen[t, c]]``, (T, k), to the last bit: over the
+    expert axis, the one score whose expert is the choice added to zeros.
+    Comparisons, selects and a sum that fuse, forward and backward; a
+    gather or a scatter of scalars the chip takes an element at a time."""
+    experts = jnp.arange(scores.shape[-1])
+    return jnp.sum(jnp.where(chosen[..., None] == experts,
+                             scores[..., None, :], 0), axis=-1)
+
+
+def pair_sort_keys(tokens: int, count: int) -> int:
+    """The keys ``held_pairs`` sorts for a block of ``tokens``."""
+    return count * tokens
+
+
 def held_pairs(chosen, weights, first: int, count: int):
     """The (token, choice) pairs that fell on the experts ``first .. first
     + count - 1``, sorted by expert into rows of a static bound.
-    ``chosen``/``weights``: (T, k).  Returns, for ``T * min(k, count)``
-    rows (every held pair is among them: a token falls on a held expert at
-    most that often): each row's token (R,), its weight (R,; 0 past the
-    held pairs), whether it is a held pair (R,), and the experts' group
-    sizes (count,), which sum to the held pairs."""
+    ``chosen``/``weights``: (T, k); a token's choices differ.  Returns, for
+    ``T * min(k, count)`` rows (every held pair is among them: a token
+    falls on a held expert at most that often): each row's token (R,), its
+    weight (R,; 0 past the held pairs), whether it is a held pair (R,), and
+    the experts' group sizes (count,), which sum to the held pairs.
+
+    The rows are the True cells of the membership table (count, T), expert
+    by expert, read in order.  A cell's key is its own index where held and
+    past every index where not, so one sort of ``count * T`` keys puts the
+    held cells in front; a row's token is its key modulo T, and its weight
+    rides through the sort beside the key.  Everything else is comparisons,
+    selects and sums over dense arrays: no scalar is gathered or scattered,
+    forward or backward."""
     tokens, k = chosen.shape
     bound = tokens * min(k, count)
-    local = jnp.where((chosen >= first) & (chosen < first + count),
-                      chosen - first, count).reshape(-1)
-    # One sort of one array: a pair's group in front of its index.
-    pairs = tokens * k
-    if (count + 1) * pairs >= 2 ** 31:
+    cells = pair_sort_keys(tokens, count)
+    if 2 * cells >= 2 ** 31:
         raise ValueError(
-            f"{tokens} tokens x {k} choices x {count} experts do not fit "
-            "one int32 sort key: route fewer tokens at a time")
-    order = jnp.sort(local * pairs + jnp.arange(pairs))[:bound] % pairs
-    held = local[order] < count
-    sizes = jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
-                    dtype=jnp.int32)
-    return (order // k, jnp.where(held, weights.reshape(-1)[order], 0.0),
-            held, sizes)
+            f"{tokens} tokens x {count} experts do not fit one int32 sort "
+            "key: route fewer tokens at a time")
+    on = chosen[None] == (first + jnp.arange(count))[:, None, None]
+    member = on.any(-1)                                     # (count, T)
+    # At most one choice of a token is an expert: its weight plus zeros.
+    table = jnp.sum(jnp.where(on, weights[None], 0), axis=-1)
+    cell = jnp.arange(cells)
+    keys, weight = _sorted_with(
+        jnp.where(member.reshape(-1), cell, cells + cell), table.reshape(-1))
+    keys, weight = keys[:bound], weight[:bound]
+    return (lax.rem(keys, tokens), weight, keys < cells,
+            jnp.sum(member, axis=-1, dtype=jnp.int32))
 
 
 class ExpertShare(nn.Module):
@@ -356,11 +403,15 @@ class ExpertShare(nn.Module):
 
     No token is dropped and no shape depends on the routing.  Tokens go
     through in blocks of ``token_block``; in a block, the (token, choice)
-    pairs are sorted by held expert (pairs that fell elsewhere last) and
-    the first ``token_block * min(top_k, count)`` of them are the block's
-    rows (``held_pairs``; every held pair is among them: a token falls on a
-    held expert at most that often).  That bound is the length of four
-    vectors and of nothing else: the rows are gathered, taken through the
+    pairs that fell on a held expert are put in rows expert by expert by
+    one sort of the block's membership table, ``count * token_block`` keys
+    (``held_pairs``), and the first ``token_block * min(top_k, count)``
+    rows are the block's (every held pair is among them: a token falls on
+    a held expert at most that often).  Which pair goes where is computed
+    from dense arrays by comparisons, selects and sums that fuse, forward
+    and backward: the chip gathers and scatters scalars an element at a
+    time.  That bound is the length of four vectors and of nothing else:
+    the rows are gathered, taken through the
     grouped products over the experts' banks and added back to their
     tokens under their weights a tile of ``row_tile`` at a time, by a loop
     that visits the tiles the held pairs fill and no others
@@ -393,7 +444,7 @@ class ExpertShare(nn.Module):
             u32, self.router.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
         _, chosen = lax.top_k(scores + self.router_bias, self.top_k)
-        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        picked = picked_scores(scores, chosen)
         weights = self.routed_scale * picked / picked.sum(-1, keepdims=True)
         return chosen, weights
 
@@ -417,6 +468,7 @@ class ExpertShare(nn.Module):
         tile = min(self.row_tile, block * bound)
         registry.gauge("moe.dispatch_rows").set(tokens * bound)
         registry.gauge("moe.row_tile").set(tile)
+        registry.gauge("moe.pair_sort_keys").set(pair_sort_keys(block, count))
 
         def blocks(a):
             return a.reshape(tokens // block, block, *a.shape[1:])

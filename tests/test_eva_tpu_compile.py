@@ -193,3 +193,62 @@ def test_the_share_layer_compiles_under_a_client_axis(one_chip,
     assert not any(f"[32768,{width}]" in compiled.as_text()
                    for width in (1024, 2688))
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+SHARE_LAYERS = {
+    "latent_22_of_512": ("LatentMoEShare", dict(
+        embed_dim=4096, latent_dim=1024, expert_dim=2688, shared_dim=5376,
+        experts_total=512, experts_held=(0, 8), top_k=22, routed_scale=5.0),
+        1024),
+    "gated_4_of_64": ("GatedMoEShare", dict(
+        embed_dim=3584, expert_dim=1024, shared_dim=1024, experts_total=64,
+        experts_held=(0, 8), top_k=4, routed_scale=2.0), 3584),
+}
+
+
+@pytest.mark.parametrize("share", sorted(SHARE_LAYERS))
+def test_the_share_layers_routing_gathers_rows_only(share, one_chip,
+                                                    as_on_the_chip):
+    """Both share layers at the published widths, a block of 4,096 tokens,
+    gradient and all under a client axis, as the chip's compiler leaves
+    them: every gather and scatter moves whole rows of the experts' input
+    (the tile's rows and their way back), none a scalar of the scores, the
+    weights or the pairs; the chosen scores' compare-select-sum over
+    (tokens, choices, experts) is fused, so no array of that shape is
+    written; and a block's pairs meet two sorts of 8 x 4,096 keys with the
+    weights beside them, one forward and one backward."""
+    import re
+
+    from colearn_federated_learning_tpu.models import moe
+
+    name, sizes, row_width = SHARE_LAYERS[share]
+    layer = getattr(moe, name)(dtype=jnp.bfloat16, **sizes)
+    u = jnp.zeros((4096, sizes["embed_dim"]), jnp.float32)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), u)["params"])
+
+    def grads(p, u):
+        return jax.grad(lambda p, u: jnp.sum(
+            layer.apply({"params": p}, u).astype(jnp.float32) ** 2),
+            argnums=(0, 1))(p, u)
+
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (1,) + a.shape, a.dtype, sharding=one_chip), (params, u))
+    text = jax.jit(jax.vmap(grads)).lower(*shapes).compile().as_text()
+    moved = re.findall(r"= \w+\[([\d,]*)\]\S* (?:gather|scatter)\(", text)
+    assert moved and all(
+        dims.split(",")[-1] == str(row_width) for dims in moved), moved
+    # Outside a fusion, an instruction's result is an array in memory.
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    wide = f"[4096,{sizes['top_k']},{sizes['experts_total']}]"
+    body = None
+    for line in text.splitlines():
+        opened = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if opened:
+            body = opened.group(1)
+        elif body not in fused and " = " in line:
+            assert wide not in line.split(" = ")[1].split("(")[0].replace(
+                "1,", ""), line
+    pair_sorts = re.findall(
+        r"= \(s32\[1,32768\]\S*, f32\[1,32768\]\S*\) sort\(", text)
+    assert len(pair_sorts) == 2, len(pair_sorts)

@@ -337,6 +337,209 @@ def test_share_layer_refuses_experts_it_cannot_hold():
         _share_layer(token_block=3).init(jax.random.PRNGKey(0), u)
 
 
+# --- the routing, against the forms it replaced ------------------------------
+#
+# What ``route`` and ``held_pairs`` computed until PR 36, as the plain
+# statement of what they give: a gather of the chosen scores, and one sort of
+# every (token, choice) pair with the pairs' vectors gathered in its order.
+
+
+def picked_by_gather(scores, chosen):
+    return jnp.take_along_axis(scores, chosen, axis=-1)
+
+
+def held_pairs_by_pair_sort(chosen, weights, first, count):
+    tokens, k = chosen.shape
+    bound = tokens * min(k, count)
+    local = jnp.where((chosen >= first) & (chosen < first + count),
+                      chosen - first, count).reshape(-1)
+    pairs = tokens * k
+    order = jnp.sort(local * pairs + jnp.arange(pairs))[:bound] % pairs
+    held = local[order] < count
+    sizes = jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    return (order // k, jnp.where(held, weights.reshape(-1)[order], 0.0),
+            held, sizes)
+
+
+def the_forms_replaced(monkeypatch):
+    monkeypatch.setattr(moe, "picked_scores", picked_by_gather)
+    monkeypatch.setattr(moe, "held_pairs", held_pairs_by_pair_sort)
+
+
+# Experts, choices a token, the held range: both share layers' (22 of 512 and
+# 4 of 64, 8 held), and fewer and more choices than held experts.
+ROUTERS = {"latent_22_of_512": (512, 22, 40, 8),
+           "gated_4_of_64": (64, 4, 8, 8), "choices_below_held": (16, 2, 4, 4),
+           "choices_above_held": (16, 6, 4, 4), "all_held": (8, 3, 0, 8)}
+ROUTINGS = ["drawn", "none_held", "every_held", "tied", "all_tied"]
+
+
+def _routing(router, routing, tokens=96):
+    """Scores (T, E) and a correction bias (E,): as drawn; the held experts
+    out of every token's reach, or in front of every token's choice (the
+    rows' bound is met); scores of three values, or of one, so that
+    ``top_k``'s way with ties is what chooses."""
+    total, k, first, count = ROUTERS[router]
+    scores = jax.nn.sigmoid(
+        jax.random.normal(jax.random.PRNGKey(3), (tokens, total)))
+    bias = jnp.zeros((total,))
+    if routing == "none_held" and count < total:
+        bias = bias.at[first:first + count].set(-10.0)
+    elif routing == "every_held":
+        bias = bias.at[first:first + count].set(10.0)
+    elif routing == "tied":
+        scores = jnp.round(2 * scores) / 2
+    elif routing == "all_tied":
+        scores = jnp.full_like(scores, 0.5)
+    return scores, bias, k, first, count
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_routing_is_the_gather_and_the_pair_sort(router, routing):
+    """The chosen scores to the last bit; the rows' weights to the last
+    bit, ``held`` and ``sizes`` exactly, and the held rows' tokens in the
+    pair sort's order (by expert, then by token); rows past the held pairs
+    weigh 0 and name a token of the block.  Under a client axis too."""
+    scores, bias, k, first, count = _routing(router, routing)
+    _, chosen = jax.lax.top_k(scores + bias, k)
+    picked = jax.jit(moe.picked_scores)(scores, chosen)
+    np.testing.assert_array_equal(picked, picked_by_gather(scores, chosen))
+    weights = 5.0 * picked / picked.sum(-1, keepdims=True)
+    want = held_pairs_by_pair_sort(chosen, weights, first, count)
+    got = jax.jit(moe.held_pairs, static_argnums=(2, 3))(
+        chosen, weights, first, count)
+    mapped = jax.vmap(lambda c, w: moe.held_pairs(c, w, first, count))(
+        jnp.stack([chosen, chosen[::-1]]), jnp.stack([weights, weights[::-1]]))
+    for (token, weight, held, sizes) in (got, [a[0] for a in mapped]):
+        np.testing.assert_array_equal(held, want[2])
+        np.testing.assert_array_equal(sizes, want[3])
+        np.testing.assert_array_equal(weight, want[1])
+        np.testing.assert_array_equal(token[want[2]], want[0][want[2]])
+        assert ((0 <= token) & (token < len(chosen))).all()
+    pairs, rows = int(want[3].sum()), len(want[0])
+    assert rows == len(chosen) * min(k, count)
+    if routing == "none_held" and count < ROUTERS[router][0]:
+        assert pairs == 0
+    if routing == "every_held":
+        assert pairs == rows
+    if routing == "all_tied":
+        # The lowest experts win every tie.
+        np.testing.assert_array_equal(chosen[0], np.arange(k))
+
+
+def _primitives(jaxpr, found=None):
+    """Every primitive's name in a jaxpr and in the jaxprs inside it."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append(eqn.primitive.name)
+        for value in eqn.params.values():
+            inside = value if isinstance(value, (list, tuple)) else [value]
+            for inner in inside:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, found)
+    return found
+
+
+def _element_by_element(names):
+    return sorted(n for n in names if "gather" in n or "scatter" in n
+                  or n in ("dynamic_slice", "dynamic_update_slice"))
+
+
+@pytest.mark.parametrize("router", ["latent_22_of_512", "gated_4_of_64"])
+def test_routing_gathers_and_scatters_no_scalar(router):
+    """No gather, no scatter and no slice at a traced index in ``route``'s
+    chosen scores, in ``held_pairs`` or in their pullbacks: comparisons,
+    selects, sums, and one sort of ``count * T`` keys forward and one
+    backward.  The forms replaced have them, and the count finds them."""
+    scores, bias, k, first, count = _routing(router, "drawn")
+    _, chosen = jax.lax.top_k(scores + bias, k)
+    weights = picked_by_gather(scores, chosen)
+
+    def pulled_back(f, x):
+        def run(x, g):
+            out, pull = jax.vjp(f, x)
+            return out, pull(g)
+        return _primitives(jax.make_jaxpr(run)(x, f(x)).jaxpr)
+
+    def rows_weight(held_pairs):
+        return lambda w: held_pairs(chosen, w, first, count)[1]
+
+    new = (pulled_back(lambda s: moe.picked_scores(s, chosen), scores),
+           pulled_back(rows_weight(moe.held_pairs), weights),
+           _primitives(jax.make_jaxpr(
+               lambda c, w: moe.held_pairs(c, w, first, count))(
+               chosen, weights).jaxpr))
+    for names in new:
+        assert not _element_by_element(names), names
+    assert [new[i].count("sort") for i in (0, 1, 2)] == [0, 2, 1]
+    old = (pulled_back(lambda s: picked_by_gather(s, chosen), scores),
+           pulled_back(rows_weight(held_pairs_by_pair_sort), weights))
+    for names in old:
+        found = _element_by_element(names)
+        assert "gather" in found and any("scatter" in n for n in found)
+    # The keys of a block's sort, as the gauge reports them.
+    assert moe.pair_sort_keys(len(chosen), count) == count * len(chosen)
+
+
+def assert_as_with_the_forms_replaced(layer, first, count, biases,
+                                      monkeypatch):
+    """A share layer's choice exactly, ``route``'s weights within 4 ulp of
+    the gather's (the chosen scores are the gather's to the bit; XLA folds
+    their sum into the same pass, so the denominator adds in another
+    order, 2 ulp, and the quotient rounds around it), and a scalar of the
+    layer's answer with its gradient in every weight and in the input as
+    with the forms replaced: the same rows in the same order meet the same
+    tiles.  ``biases``: the correction bias on the held experts, one value a
+    client; several clients go under one ``vmap``."""
+    us = jax.random.normal(jax.random.PRNGKey(0), (len(biases), 64, 32))
+    drawn = layer.init(jax.random.PRNGKey(1), us[0])["params"]
+    clients = [dict(drawn, router_bias=drawn["router_bias"].at[
+        first:first + count].set(bias)) for bias in biases]
+
+    def run():
+        def scalar(p, u):
+            return jnp.sum(jnp.sin(layer.apply({"params": p}, u)))
+        grad = jax.value_and_grad(scalar, argnums=(0, 1))
+        route = lambda p, u: layer.apply({"params": p}, u, method="route")
+        if len(biases) == 1:
+            args = (clients[0], us[0])
+        else:
+            grad, route = jax.vmap(grad), jax.vmap(route)
+            args = (jax.tree.map(lambda *a: jnp.stack(a), *clients), us)
+        return jax.jit(route)(*args), jax.jit(grad)(*args)
+
+    (chosen, weights), (got, got_g) = run()
+    the_forms_replaced(monkeypatch)
+    (chosen_was, weights_was), (want, want_g) = run()
+    np.testing.assert_array_equal(chosen, chosen_was)
+    np.testing.assert_array_max_ulp(weights, weights_was, maxulp=4)
+    # A sum of 2,048 sines that may cancel.
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=5e-4)
+    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want_g),
+                            jax.tree.leaves(got_g)):
+        name = jax.tree_util.keystr(path)
+        assert np.isfinite(g).all(), name
+        if np.asarray(w).any():
+            # The weights' few ulp, through a sum over the tokens.
+            assert _rel(g, w) < 1e-5, name
+        else:
+            assert not np.asarray(g).any(), name
+
+
+@pytest.mark.parametrize("biases", [(0.0,), (10.0,), (-10.0,), (10.0, 0.0)],
+                         ids=["uniform", "every_held", "none_held",
+                              "vmap_bound_and_uniform"])
+def test_share_layer_gradients_are_the_replaced_forms(biases, monkeypatch):
+    """The latent layer's routing, answer and gradient in the router, the
+    latent maps, the banks, the shared expert and the input, against the
+    gather of the chosen scores and the sort of every pair."""
+    assert_as_with_the_forms_replaced(
+        _share_layer(token_block=32), 4, 4, biases, monkeypatch)
+
+
 UNCUT = dict(width=32, mamba_heads=8, mamba_head_dim=4, mamba_groups=4,
              ssm_state_size=8, conv_kernel=4, chunk_size=16, num_experts=128,
              experts_first=0, experts_held=128, experts_per_token=6,
@@ -525,6 +728,8 @@ def test_gauges_say_what_was_built():
     # One sequence of 64 tokens, at most 4 held experts a token; the
     # tile is the module's own, cut to the rows a block has.
     assert got["moe.dispatch_rows"] == got["moe.row_tile"] == 64 * 4
+    # A block's sort: its membership table, 4 held experts x 64 tokens.
+    assert got["moe.pair_sort_keys"] == 4 * 64
     _model_and_batch(layer_pattern="M*")
     assert _snapshot()["hybrid.layers{kind=moe}"] == 0
 

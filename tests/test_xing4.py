@@ -25,6 +25,7 @@ from colearn_federated_learning_tpu.utils.config import (
     RunConfig,
     get_config,
 )
+from tests.test_nemotron_h import assert_as_with_the_forms_replaced
 
 TINY = dict(name="xing4", num_classes=96, vocab_size=96, width=32,
             seq_len=64, depth=3, dense_layers=1, hc_streams=4,
@@ -261,6 +262,24 @@ def test_gated_share_is_the_plain_loop_over_held_experts(biases):
             assert _rel(g, w) < 2e-5, name
 
 
+@pytest.mark.parametrize("biases", [(0.0,), (10.0,), (-10.0,), (10.0, 0.0)],
+                         ids=["uniform", "every_held", "none_held",
+                              "vmap_bound_and_uniform"])
+@pytest.mark.parametrize("top_k,count", [(4, 4), (2, 4), (6, 4)],
+                         ids=["as_many", "fewer", "more_choices_than_held"])
+def test_gated_share_gradients_are_the_replaced_forms(top_k, count, biases,
+                                                      monkeypatch):
+    """The choice exactly, ``route``'s weights within 4 ulp, the gated
+    layer's answer and its gradient in the router, the three banks, the
+    shared expert and the input as with the gather of the chosen scores
+    and the sort of every pair that ``route`` and ``held_pairs`` were until
+    PR 36 (``tests/test_nemotron_h.py`` writes them out), with fewer and
+    more choices than held experts."""
+    assert_as_with_the_forms_replaced(
+        _share_layer(count=count, top_k=top_k, token_block=32), 4, count,
+        biases, monkeypatch)
+
+
 def test_the_shares_of_all_chips_add_up_to_the_uncut_gated_layer():
     """The program's layer, built as one chip's share and given that
     share's slice of an uncut layer's banks, once for each of the 8 shares
@@ -387,6 +406,8 @@ def test_gauges_say_what_was_built():
     assert got["mtp.modules"] == 1
     assert (got["moe.experts_held"], got["moe.experts_total"],
             got["moe.top_k"]) == (4, 16, 4)
+    # A block's sort: its membership table, 4 held experts x 64 tokens.
+    assert got["moe.pair_sort_keys"] == 4 * 64
 
 
 def test_another_family_sets_none_of_the_gauges(monkeypatch):
