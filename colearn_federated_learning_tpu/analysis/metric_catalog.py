@@ -146,6 +146,15 @@ COUNTERS = (
     # the program was loaded / was built and written
     "telemetry.cache_hit_total",
     "telemetry.cache_miss_total",
+    # CompileTracker.scopes, on first ask and never on fit()'s path: tables
+    # made, labeled {fn,how=loaded|built} (built: the compiler ran, because
+    # the compile cache held the program under an older checkout's names);
+    # the seconds that took, {fn}; gauges {fn}: the program's instructions,
+    # and those with no name of their own nor of a loop around them
+    "telemetry.scope_table_total",
+    "telemetry.scope_table_seconds",
+    "telemetry.scope_table_instructions",
+    "telemetry.scope_table_unnamed",
     # ops/attention.py: flash kernels traced, labeled
     # {mode=mosaic|interpret} — which of the two lowerings a run used
     "ops.flash_trace_total",

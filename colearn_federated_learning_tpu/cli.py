@@ -1195,6 +1195,16 @@ def cmd_lint(args: argparse.Namespace) -> int:
 def cmd_trace_summary(args: argparse.Namespace) -> int:
     from colearn_federated_learning_tpu import telemetry
 
+    if os.path.isdir(args.trace_file):
+        # A --profile-dir: device time by the program's own scopes.
+        try:
+            print(telemetry.summarize_profile(args.trace_file,
+                                              top=args.top))
+        except (OSError, ValueError, KeyError) as e:
+            print(f"cannot read profile {args.trace_file}: {e}",
+                  file=sys.stderr)
+            return 2
+        return 0
     try:
         doc = telemetry.load_trace(args.trace_file)
     except (OSError, ValueError) as e:
@@ -1702,10 +1712,17 @@ def main(argv: list[str] | None = None) -> int:
 
     p_trace = sub.add_parser("trace-summary",
                              help="print a per-phase time breakdown of a "
-                                  "--trace-dir Chrome-trace JSON file")
-    p_trace.add_argument("trace_file", help="path to the *_trace.json file")
+                                  "--trace-dir Chrome-trace JSON file, or "
+                                  "of a --profile-dir the device time by "
+                                  "the program's own scopes")
+    p_trace.add_argument("trace_file",
+                         help="path to the *_trace.json file, or to a "
+                              "--profile-dir directory")
     p_trace.add_argument("--root", default="round",
                          help="span name used as the per-round denominator")
+    p_trace.add_argument("--top", type=int, default=15,
+                         help="scope paths listed per program "
+                              "(--profile-dir)")
     p_trace.set_defaults(fn=cmd_trace_summary)
 
     p_pm = sub.add_parser("postmortem",

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from colearn_federated_learning_tpu import telemetry
 from colearn_federated_learning_tpu.fed import losses
 from colearn_federated_learning_tpu.parallel.partition import path_str
 from colearn_federated_learning_tpu.utils import pytrees
@@ -305,7 +306,8 @@ def make_local_update(
         ws = working_set(global_params, x, count, key)
         if ws is not None:
             global_params = _map_leaves(tables, ws.gather, dense_params)
-        opt_state = optimizer.init(global_params)
+        with telemetry.device_scope("local.optimizer"):
+            opt_state = optimizer.init(global_params)
         safe_count = jnp.maximum(count, 1)
 
         def step(carry, inp):
@@ -319,17 +321,22 @@ def make_local_update(
                 grads = jax.tree.map(lambda g: jax.lax.pmean(g, ax), grads)
             if correction is not None:
                 grads = pytrees.tree_add(grads, correction)
-            updates, new_opt_state = optimizer.update(grads, opt_state, params)
-            if lr_scale is not None:
-                # Round-level lr schedule (strategies.lr_scale_for_round):
-                # scaling the UPDATE equals running at lr·scale for SGD
-                # (+momentum, linear in lr from a zero buffer) and for
-                # Adam (update ∝ lr; grad scaling would be a no-op there).
-                updates = pytrees.tree_scale(updates, lr_scale)
-            new_params = optax.apply_updates(params, updates)
-            active = t < step_budget
-            params = _tree_where(active, new_params, params)
-            opt_state = _tree_where(active, new_opt_state, opt_state)
+            # The client's state (its initial value, its update, what it
+            # moved by), apart from the model's passes on the device trace.
+            with telemetry.device_scope("local.optimizer"):
+                updates, new_opt_state = optimizer.update(
+                    grads, opt_state, params)
+                if lr_scale is not None:
+                    # Round-level lr schedule (strategies.
+                    # lr_scale_for_round): scaling the UPDATE equals
+                    # running at lr·scale for SGD (+momentum, linear in lr
+                    # from a zero buffer) and for Adam (update ∝ lr; grad
+                    # scaling would be a no-op there).
+                    updates = pytrees.tree_scale(updates, lr_scale)
+                new_params = optax.apply_updates(params, updates)
+                active = t < step_budget
+                params = _tree_where(active, new_params, params)
+                opt_state = _tree_where(active, new_opt_state, opt_state)
             return (params, opt_state), loss * active
 
         (params, _), step_losses = jax.lax.scan(
@@ -338,7 +345,8 @@ def make_local_update(
         )
         executed = jnp.minimum(step_budget, num_steps).astype(jnp.float32)
         mean_loss = jnp.sum(step_losses) / jnp.maximum(executed, 1.0)
-        delta = pytrees.tree_sub(params, global_params)
+        with telemetry.device_scope("local.optimizer"):
+            delta = pytrees.tree_sub(params, global_params)
         if ws is not None:
             delta = _map_leaves(tables, ws.scatter, delta, dense_params)
         result = LocalResult(

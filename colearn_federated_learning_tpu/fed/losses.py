@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import optax
 
+from colearn_federated_learning_tpu import telemetry
+
 
 def softmax_cross_entropy(logits, labels) -> jnp.ndarray:
     """Mean cross-entropy over every label.  ``logits`` (..., K), ``labels``
@@ -16,9 +18,12 @@ def softmax_cross_entropy(logits, labels) -> jnp.ndarray:
         raise ValueError(
             f"logits {logits.shape} need labels {logits.shape[:-1]}, "
             f"got {labels.shape}")
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), labels
-    ).mean()
+    # With the model's last norm and its logits: the ``head`` of the
+    # device trace.
+    with telemetry.device_scope("head"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), labels
+        ).mean()
 
 
 def accuracy(logits, labels) -> jnp.ndarray:

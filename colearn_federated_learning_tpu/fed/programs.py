@@ -25,6 +25,13 @@ The cohort draw (``draw_cohort``), the per-cohort body (``cohort_step``)
 and the round epilogue (``finish_round``) are shared verbatim between the
 two paths — the mesh builder only adds the cross-device psums between
 them.
+
+A round's device time is told apart by four ``telemetry.device_scope``s,
+in which everything a round program does lies: ``cohort`` (the draw, the
+gather of the cohort's rows, its keys and budgets), ``local`` (the
+clients' local updates; ``fed/local.py`` marks ``local.optimizer`` inside),
+``aggregate`` (norms, clipping and noise, masks, the weighted or robust
+sum, the collectives of the mesh path) and ``server`` (``finish_round``).
 """
 
 from __future__ import annotations
@@ -39,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from colearn_federated_learning_tpu import telemetry
 from colearn_federated_learning_tpu.fed import strategies
 from colearn_federated_learning_tpu.fed.evaluation import per_label
 from colearn_federated_learning_tpu.fed.robust import (
@@ -333,6 +341,31 @@ def cohort_step(plan: RoundPlan, local_update: Callable, params, local_ids,
     with a psum (shard_map path); ``scaffold_extras`` is None or
     ``(delta_c_uniform_sum, n_contributors, updated_cohort_block)``.
     """
+    with telemetry.device_scope("cohort"):
+        rows = _cohort_rows(plan, local_ids, global_ids, x, y, counts, key,
+                            round_idx)
+    with telemetry.device_scope("local"):
+        if plan.scaffold:
+            sres = jax.vmap(
+                local_update,
+                in_axes=(None, 0, 0, 0, 0, 0, 0, None, None),
+            )(params, *rows[:-1], c_blk, control, rows[-1])
+            results = sres.result
+        else:
+            sres = None
+            results = jax.vmap(
+                local_update, in_axes=(None, 0, 0, 0, 0, 0, None)
+            )(params, *rows)
+    with telemetry.device_scope("aggregate"):
+        return _aggregate(plan, results, sres, c_blk, global_ids,
+                          mask_cohort_ids, key, round_idx, clip)
+
+
+def _cohort_rows(plan: RoundPlan, local_ids, global_ids, x, y, counts, key,
+                 round_idx):
+    """What ``local_update`` takes per cohort slot: the cohort's rows of
+    ``x``, ``y`` and ``counts``, its keys and step budgets, and the
+    round's learning-rate factor."""
     c = plan.fed
     cx = jnp.take(x, local_ids, axis=0)
     cy = jnp.take(y, local_ids, axis=0)
@@ -363,19 +396,14 @@ def cohort_step(plan: RoundPlan, local_update: Callable, params, local_ids,
     # Round-level client-lr schedule factor, computed in-graph from
     # the round operand (no retrace, no host sync).
     lr_scale = strategies.lr_scale_for_round(c, round_idx)
+    return cx, cy, ccounts, keys, budgets, lr_scale
 
-    if plan.scaffold:
-        c_i = c_blk                      # already one row per cohort slot
-        sres = jax.vmap(
-            local_update,
-            in_axes=(None, 0, 0, 0, 0, 0, 0, None, None),
-        )(params, cx, cy, ccounts, keys, budgets, c_i, control, lr_scale)
-        results = sres.result
-    else:
-        sres = None
-        results = jax.vmap(
-            local_update, in_axes=(None, 0, 0, 0, 0, 0, None)
-        )(params, cx, cy, ccounts, keys, budgets, lr_scale)
+
+def _aggregate(plan: RoundPlan, results, sres, c_i, global_ids,
+               mask_cohort_ids, key, round_idx, clip):
+    """The cohort's results (``sres``: SCAFFOLD's, with its variates ``c_i``
+    one row per cohort slot) as ``cohort_step`` returns them."""
+    c = plan.fed
     deltas = results.delta
     completed = results.completed
     nova_a = None
@@ -532,6 +560,13 @@ def finish_round(plan: RoundPlan, server_state, wsum, total_w, stats,
     Zero contributors (all stragglers) → no-op update; the explicit gate
     matters under secure_agg, where wsum is not exactly zero but the
     float32 mask-cancellation residual."""
+    with telemetry.device_scope("server"):
+        return _finish_round(plan, server_state, wsum, total_w, stats,
+                             extras, clip, key, round_idx)
+
+
+def _finish_round(plan: RoundPlan, server_state, wsum, total_w, stats,
+                  extras, clip, key, round_idx):
     loss_sum, n_comp, bit_sum, norm_sum, norm_max, nova_sum = stats
     denom = jnp.where(total_w > 0, total_w, 1.0)
     if plan.robust:
@@ -605,9 +640,10 @@ def _build_vmap_round(plan: RoundPlan, local_update: Callable):
                  sel_in, c_cohort, clip_in):
         # SCAFFOLD's cohort was drawn on the host (so its variate rows
         # could be gathered) and arrives as an operand.
-        sel = sel_in if plan.scaffold else draw_cohort(
-            plan, key, round_idx, counts)
-        cohort_global = jnp.take(ids, sel)
+        with telemetry.device_scope("cohort"):
+            sel = sel_in if plan.scaffold else draw_cohort(
+                plan, key, round_idx, counts)
+            cohort_global = jnp.take(ids, sel)
         wsum, total_w, stats, extras = cohort_step(
             plan, local_update, server_state.params, sel, cohort_global,
             cohort_global, x, y, counts, key, round_idx,
@@ -631,30 +667,34 @@ def _build_mesh_round(plan: RoundPlan, local_update: Callable,
 
     def body(server_state, key, round_idx, x_blk, y_blk, counts_blk,
              ids_blk, sel_blk, c_blk, clip_in):
-        sel = sel_blk if plan.scaffold else draw_cohort(
-            plan, key, round_idx, counts_blk, device=jax.lax.axis_index(ax))
-        cohort_global = jnp.take(ids_blk, sel)
-        # Secure-agg masks pair against the FULL mesh-wide cohort: a
-        # cheap all_gather of the (cohort_per_device,) id vectors.
-        mask_cohort = jax.lax.all_gather(cohort_global, ax).reshape(-1)
+        with telemetry.device_scope("cohort"):
+            sel = sel_blk if plan.scaffold else draw_cohort(
+                plan, key, round_idx, counts_blk,
+                device=jax.lax.axis_index(ax))
+            cohort_global = jnp.take(ids_blk, sel)
+            # Secure-agg masks pair against the FULL mesh-wide cohort: a
+            # cheap all_gather of the (cohort_per_device,) id vectors.
+            mask_cohort = jax.lax.all_gather(cohort_global, ax).reshape(-1)
         wsum, total_w, stats, extras = cohort_step(
             plan, local_update, server_state.params, sel, cohort_global,
             mask_cohort, x_blk, y_blk, counts_blk, key, round_idx,
             control=server_state.control, c_blk=c_blk, clip=clip_in,
         )
         loss_sum, n_comp, bit_sum, norm_sum, norm_max, nova_sum = stats
-        # FedAvg across the pod: one psum over ICI per leaf.  (Robust
-        # aggregates are already global+replicated — no psum.)
-        if not plan.robust:
-            wsum = jax.tree.map(lambda l: jax.lax.psum(l, ax), wsum)
-        total_w = jax.lax.psum(total_w, ax)
-        stats = (jax.lax.psum(loss_sum, ax), jax.lax.psum(n_comp, ax),
-                 jax.lax.psum(bit_sum, ax), jax.lax.psum(norm_sum, ax),
-                 jax.lax.pmax(norm_max, ax), jax.lax.psum(nova_sum, ax))
-        if plan.scaffold:
-            dc_sum, n_contrib, new_c = extras
-            extras = (jax.tree.map(lambda l: jax.lax.psum(l, ax), dc_sum),
-                      jax.lax.psum(n_contrib, ax), new_c)
+        with telemetry.device_scope("aggregate"):
+            # FedAvg across the pod: one psum over ICI per leaf.  (Robust
+            # aggregates are already global+replicated — no psum.)
+            if not plan.robust:
+                wsum = jax.tree.map(lambda l: jax.lax.psum(l, ax), wsum)
+            total_w = jax.lax.psum(total_w, ax)
+            stats = (jax.lax.psum(loss_sum, ax), jax.lax.psum(n_comp, ax),
+                     jax.lax.psum(bit_sum, ax), jax.lax.psum(norm_sum, ax),
+                     jax.lax.pmax(norm_max, ax), jax.lax.psum(nova_sum, ax))
+            if plan.scaffold:
+                dc_sum, n_contrib, new_c = extras
+                extras = (
+                    jax.tree.map(lambda l: jax.lax.psum(l, ax), dc_sum),
+                    jax.lax.psum(n_contrib, ax), new_c)
         new_state, metrics = finish_round(
             plan, server_state, wsum, total_w, stats, extras, clip_in,
             key, round_idx)

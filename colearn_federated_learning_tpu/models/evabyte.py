@@ -146,9 +146,11 @@ class EvaByte(nn.Module):
             h = block_cls(self.num_heads, self.ffn_dim, self.window,
                           self.chunk, self.rope_theta, dtype=self.dtype,
                           attn_impl=self.attn_impl, name=f"block_{i}")(h)
-        h = RMSNorm(dtype=jnp.float32, name="norm")(h)
-        logits = nn.Dense(self.num_pred_heads * self.vocab_size,
-                          use_bias=False, dtype=jnp.float32,
-                          kernel_init=nn.initializers.normal(INIT_STD),
-                          name="head")(h)
-        return logits.reshape(B, L, self.num_pred_heads, self.vocab_size)
+        with telemetry.device_scope("head"):
+            h = RMSNorm(dtype=jnp.float32, name="norm")(h)
+            logits = nn.Dense(self.num_pred_heads * self.vocab_size,
+                              use_bias=False, dtype=jnp.float32,
+                              kernel_init=nn.initializers.normal(INIT_STD),
+                              name="head")(h)
+            return logits.reshape(B, L, self.num_pred_heads,
+                                  self.vocab_size)

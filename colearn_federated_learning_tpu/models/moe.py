@@ -297,17 +297,20 @@ def routed_rows(tile: int, experts, latent, weight, banks, token, held,
 def _routed_rows_fwd(tile, experts, latent, weight, banks, token, held,
                      sizes):
     visit = functools.partial(visit_row_tiles, tile, experts=experts)
-    out, _ = _a_client_at_a_time(
-        lambda latent, weight, banks, *rows: visit(
-            latent, weight, *banks, *rows))(
-        latent, weight, banks, token, held, sizes)
+    with telemetry.device_scope("moe.tiles"):
+        out, _ = _a_client_at_a_time(
+            lambda latent, weight, banks, *rows: visit(
+                latent, weight, *banks, *rows))(
+            latent, weight, banks, token, held, sizes)
     return out, (latent, weight, banks, token, held, sizes)
 
 
 def _routed_rows_bwd(tile, experts, args, g):
-    return (*_a_client_at_a_time(functools.partial(
-        _visit_row_tiles_transposed, tile, experts))(*args, g),
-            None, None, None)
+    # The backward pass is traced apart from the forward's scopes.
+    with telemetry.device_scope("moe.tiles"):
+        return (*_a_client_at_a_time(functools.partial(
+            _visit_row_tiles_transposed, tile, experts))(*args, g),
+                None, None, None)
 
 
 routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
@@ -440,12 +443,14 @@ class ExpertShare(nn.Module):
     def route(self, u32):
         """``u32``: (N, D) float32.  The chosen experts (N, top_k) and
         their weights (N, top_k) float32."""
-        scores = nn.sigmoid(jnp.dot(
-            u32, self.router.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        _, chosen = lax.top_k(scores + self.router_bias, self.top_k)
-        picked = picked_scores(scores, chosen)
-        weights = self.routed_scale * picked / picked.sum(-1, keepdims=True)
+        with telemetry.device_scope("moe.route"):
+            scores = nn.sigmoid(jnp.dot(
+                u32, self.router.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST))
+            _, chosen = lax.top_k(scores + self.router_bias, self.top_k)
+            picked = picked_scores(scores, chosen)
+            weights = (self.routed_scale * picked
+                       / picked.sum(-1, keepdims=True))
         return chosen, weights
 
     def routed(self, experts, banks, chosen, weights, rows_in):
@@ -473,16 +478,18 @@ class ExpertShare(nn.Module):
         def blocks(a):
             return a.reshape(tokens // block, block, *a.shape[1:])
 
-        token, weight, held, sizes = lax.map(
-            lambda pairs: held_pairs(*pairs, *self.experts_held),
-            (blocks(chosen), blocks(weights)))
-        # Whole tiles; what is added holds no pair.
-        token, weight, held = (
-            jnp.pad(a, ((0, 0), (0, -a.shape[1] % tile)))
-            for a in (token, weight, held))
-        out = routed_rows(tile, experts, blocks(rows_in), weight, banks,
-                          token, held, sizes)
-        return out.astype(self.dtype).reshape(tokens, -1)
+        with telemetry.device_scope("moe.pairs"):
+            token, weight, held, sizes = lax.map(
+                lambda pairs: held_pairs(*pairs, *self.experts_held),
+                (blocks(chosen), blocks(weights)))
+            # Whole tiles; what is added holds no pair.
+            token, weight, held = (
+                jnp.pad(a, ((0, 0), (0, -a.shape[1] % tile)))
+                for a in (token, weight, held))
+        with telemetry.device_scope("moe.tiles"):
+            out = routed_rows(tile, experts, blocks(rows_in), weight, banks,
+                              token, held, sizes)
+            return out.astype(self.dtype).reshape(tokens, -1)
 
 
 class LatentMoEShare(ExpertShare):
@@ -537,8 +544,10 @@ class LatentMoEShare(ExpertShare):
             jnp.dot(u, self.latent_down.astype(self.dtype)))
 
     def shared(self, u):
-        return jnp.dot(relu2(jnp.dot(u, self.shared_w1.astype(self.dtype))),
-                       self.shared_w2.astype(self.dtype))
+        with telemetry.device_scope("moe.shared"):
+            return jnp.dot(
+                relu2(jnp.dot(u, self.shared_w1.astype(self.dtype))),
+                self.shared_w2.astype(self.dtype))
 
     def __call__(self, u32):
         """``u32``: (..., D), the normed stream in float32 (the router
@@ -599,8 +608,9 @@ class GatedMoEShare(ExpertShare):
             *self.route(u32), u)
 
     def shared(self, u):
-        return gated(u, *(w.astype(self.dtype) for w in (
-            self.shared_gate, self.shared_up, self.shared_down)))
+        with telemetry.device_scope("moe.shared"):
+            return gated(u, *(w.astype(self.dtype) for w in (
+                self.shared_gate, self.shared_up, self.shared_down)))
 
     def __call__(self, u32):
         """``u32``: (..., D), the normed stream in float32.  Returns (...,

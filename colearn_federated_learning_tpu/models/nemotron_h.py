@@ -132,7 +132,7 @@ class Mamba2Mixer(nn.Module):
         a_log = self.param("A_log", _a_log_init, (H,))
         skip = self.param("D", nn.initializers.ones, (H,))
         dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-        with jax.named_scope("ssd"):
+        with telemetry.device_scope("ssd"):
             y = ssd_scan(x, dt, -jnp.exp(a_log), b.reshape(B, L, G, N),
                          c.reshape(B, L, G, N), chunk=self.chunk)
         y = y.astype(jnp.float32) + x.astype(jnp.float32) * skip[:, None]
@@ -163,7 +163,7 @@ class HybridBlock(nn.Module):
                               name="mixer", **self.mixer)(
                 u32.astype(self.dtype))
         elif self.kind == "moe":
-            with jax.named_scope("moe"):
+            with telemetry.device_scope("moe"):
                 out = LatentMoEShare(
                     out_scale=self.out_scale, dtype=self.dtype,
                     init_std=INIT_STD, name="mixer", **self.mixer)(u32)
@@ -253,7 +253,9 @@ class NemotronH(nn.Module):
             h = block_cls(kind, self._mixer(kind), out_scale=out_scale,
                           dtype=self.dtype, attn_impl=self.attn_impl,
                           name=f"layer_{i}")(h)
-        h = RMSNorm(name="norm")(h)
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                        kernel_init=nn.initializers.normal(INIT_STD),
-                        name="head")(h)
+        with telemetry.device_scope("head"):
+            h = RMSNorm(name="norm")(h)
+            return nn.Dense(
+                self.vocab_size, use_bias=False, dtype=jnp.float32,
+                kernel_init=nn.initializers.normal(INIT_STD),
+                name="head")(h)

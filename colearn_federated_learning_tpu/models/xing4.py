@@ -88,20 +88,20 @@ class Xing4Block(nn.Module):
     attn_impl: str = "flash"
 
     def sublayer(self, x, name: str, f):
-        with jax.named_scope("mhc"):
+        with telemetry.device_scope("mhc"):
             pre, post, res = mhc.StreamMaps(
                 norm_eps=self.norm_eps, init_std=INIT_STD,
                 name=f"{name}_maps", **self.maps)(x)
             u32 = RMSNorm(self.norm_eps, name=f"{name}_norm")(
                 mhc.read_stream(x, pre))
         out = f(u32)
-        with jax.named_scope("mhc"):
+        with telemetry.device_scope("mhc"):
             return mhc.write_stream(x, res, post, out)
 
     @nn.compact
     def __call__(self, x):
         def attend(u32):
-            with jax.named_scope("mla"):
+            with telemetry.device_scope("mla"):
                 return LatentAttention(
                     norm_eps=self.norm_eps, dtype=self.dtype,
                     impl=self.attn_impl, init_std=INIT_STD,
@@ -113,7 +113,7 @@ class Xing4Block(nn.Module):
                 return GatedFfn(out_scale=self.out_scale, dtype=self.dtype,
                                 name="ffn", **self.ffn)(
                     u32.astype(self.dtype))
-            with jax.named_scope("moe"):
+            with telemetry.device_scope("moe"):
                 return GatedMoEShare(
                     out_scale=self.out_scale, dtype=self.dtype,
                     init_std=INIT_STD, name="ffn", **self.ffn)(u32)
@@ -218,9 +218,10 @@ class Xing4(nn.Module):
         for i, kind in enumerate(kinds):
             x = self._block(kind, f"layer_{i}")(x)
         h = jnp.sum(x.astype(jnp.float32), axis=1)
-        normed = [RMSNorm(self.norm_eps, name="norm")(h)]
+        with telemetry.device_scope("head"):
+            normed = [RMSNorm(self.norm_eps, name="norm")(h)]
         if self.mtp_modules:
-            with jax.named_scope("mtp"):
+            with telemetry.device_scope("mtp"):
                 ahead = jnp.pad(ids[:, 1:], ((0, 0), (0, 1)))
                 joined = jnp.concatenate([
                     RMSNorm(self.norm_eps, name="mtp_h_norm")(h),
@@ -237,4 +238,5 @@ class Xing4(nn.Module):
         # turned afterwards: the chip is free to keep the heads away from
         # the vocabulary's tiles, where two of them would be padded to
         # eight.
-        return jnp.swapaxes(head(jnp.stack(normed, axis=1)), 1, 2)
+        with telemetry.device_scope("head"):
+            return jnp.swapaxes(head(jnp.stack(normed, axis=1)), 1, 2)

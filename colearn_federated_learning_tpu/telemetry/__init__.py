@@ -4,6 +4,9 @@ Public surface:
 
 - :class:`Tracer` / :func:`get_tracer` — nested spans, monotonic timing,
   cross-process trace propagation (``current_context`` + ``adopt``);
+  :func:`device_scope` names a part of a compiled program, and
+  :func:`program_scopes` (``.runtime``) says which operation of each
+  compiled program lies under which names;
 - :class:`MetricsRegistry` / :func:`get_registry` — process-wide
   counters, gauges, quantile histograms;
 - :mod:`.export` — Chrome-trace/Perfetto JSON writer/loader and the
@@ -28,6 +31,8 @@ from colearn_federated_learning_tpu.telemetry.tracer import (  # noqa: F401
     Span,
     SpanContext,
     Tracer,
+    declared_scopes,
+    device_scope,
     get_tracer,
     new_id,
 )
@@ -42,6 +47,7 @@ from colearn_federated_learning_tpu.telemetry.export import (  # noqa: F401
     default_trace_path,
     load_trace,
     spans_to_chrome,
+    summarize_profile,
     summarize_trace,
     trace_spans,
     write_trace,
@@ -55,7 +61,9 @@ from colearn_federated_learning_tpu.telemetry.runtime import (  # noqa: F401
     CompileTracker,
     EventLog,
     MetricsExporter,
+    Scope,
     compiled_cost,
+    program_scopes,
     prometheus_text,
     sample_device_memory,
     tracked_call,

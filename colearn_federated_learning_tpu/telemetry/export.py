@@ -6,17 +6,30 @@ both ``chrome://tracing`` and https://ui.perfetto.dev open directly.
 Span identity (trace/span/parent ids) rides in each event's ``args`` so
 a loaded trace round-trips back into span dicts, and a ``metrics`` key
 carries the :class:`~..telemetry.registry.MetricsRegistry` snapshot.
+
+A ``--profile-dir`` run leaves a jax profile, whose device plane names
+operations by instruction, and beside it ``program_scopes.json``
+(:func:`write_program_scopes`, the program's scope tables):
+:func:`summarize_profile` joins the two into device time by the program's
+own scopes.
 """
 
 from __future__ import annotations
 
+import bisect
+import glob
 import json
 import os
 from typing import Optional
 
+from colearn_federated_learning_tpu.telemetry.runtime import (
+    Scope,
+    program_scopes,
+)
 from colearn_federated_learning_tpu.telemetry.tracer import Span, Tracer
 
 TRACE_VERSION = 1
+SCOPES_FILE = "program_scopes.json"
 
 
 def spans_to_chrome(spans: list[Span]) -> list[dict]:
@@ -269,3 +282,130 @@ def summarize_trace(doc: dict, root: str = "round") -> str:
         for k in sorted(metrics):
             lines.append(f"  {k}: {json.dumps(metrics[k], sort_keys=True)}")
     return "\n".join(lines)
+
+
+# ------------------------------------------------- device time by scope ----
+def write_program_scopes(profile_dir: str) -> str:
+    """``telemetry.program_scopes()`` as ``<profile_dir>/program_scopes.json``:
+    per program, the distinct scopes and each instruction's index among
+    them.  Builds the tables (a load or a build of each executable): call
+    it once the profiler has stopped, never inside a round."""
+    programs = {}
+    for module, table in program_scopes().items():
+        scopes = sorted(set(table.values()))
+        index = {scope: i for i, scope in enumerate(scopes)}
+        programs[module] = {
+            "scopes": [[scope.phase, list(scope.path)] for scope in scopes],
+            "instructions": {name: index[scope]
+                             for name, scope in table.items()},
+        }
+    path = os.path.join(profile_dir, SCOPES_FILE)
+    os.makedirs(profile_dir, exist_ok=True)
+    with open(path + ".tmp", "w") as f:
+        json.dump({"format_version": TRACE_VERSION, "programs": programs}, f)
+    os.replace(path + ".tmp", path)
+    return path
+
+
+def load_program_scopes(path: str) -> dict[str, dict[str, Scope]]:
+    with open(path) as f:
+        doc = json.load(f)
+    tables = {}
+    for module, program in doc["programs"].items():
+        scopes = [Scope(phase, tuple(names))
+                  for phase, names in program["scopes"]]
+        tables[module] = {name: scopes[i]
+                          for name, i in program["instructions"].items()}
+    return tables
+
+
+def device_seconds_by_scope(ops: list, modules: list, tables: dict) -> dict:
+    """One chip's ``XLA Ops`` and ``XLA Modules`` events, each ``(name,
+    start_ns, duration_ns)`` as the profile gives them, as self seconds by
+    ``(program, Scope)``; ``(program, None)`` holds what ran under an
+    instruction name the program's table lacks.  An operation belongs to
+    the execution it began in, and is looked up in that program's table
+    (two programs share instruction names); a container (a ``while``)
+    counts less its children.  Programs without a table are left out."""
+    modules = sorted(modules, key=lambda m: m[1])
+    starts = [m[1] for m in modules]
+    totals: dict = {}
+    stack: list = []          # [end_ns, key, own_ns] of the open operations
+
+    def close(until: float) -> None:
+        while stack and stack[-1][0] <= until:
+            _, key, own = stack.pop()
+            totals[key] = totals.get(key, 0.0) + max(own, 0.0) / 1e9
+
+    for text, start, dur in sorted(ops, key=lambda e: (e[1], -e[2])):
+        close(start)
+        i = bisect.bisect_right(starts, start)
+        if not i or start >= modules[i - 1][1] + modules[i - 1][2]:
+            continue
+        program = modules[i - 1][0].split("(", 1)[0]
+        table = tables.get(program)
+        if table is None:
+            continue
+        if stack:
+            stack[-1][2] -= dur
+        name = text.partition(" = ")[0].split(" ", 1)[0].lstrip("%")
+        stack.append([start + dur, (program, table.get(name)), dur])
+    close(float("inf"))
+    return totals
+
+
+def summarize_profile(profile_dir: str, top: int = 15,
+                      depth: int = 4) -> str:
+    """Device self time of a ``--profile-dir`` run by phase and by the top
+    scope paths (their first ``depth`` names), per program, on the first
+    chip of the newest profile under ``profile_dir``."""
+    from jax.profiler import ProfileData
+
+    profiles = sorted(glob.glob(os.path.join(
+        profile_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not profiles:
+        raise ValueError(f"{profile_dir}: no profile (*.xplane.pb) inside")
+    tables = load_program_scopes(os.path.join(profile_dir, SCOPES_FILE))
+    # A chip's plane is the one with a line of operations (a TPU's profile
+    # has other ``/device:`` planes beside the chips').
+    chips = sorted(
+        (plane.name, {line.name: line for line in plane.lines})
+        for plane in ProfileData.from_file(profiles[-1]).planes
+        if plane.name.startswith("/device:")
+        and any(line.name == "XLA Ops" for line in plane.lines))
+    lines = [f"profile: {profiles[-1]}"]
+    if not chips:
+        return "\n".join(lines + ["(no device plane: nothing ran on a chip)"])
+    chip, by_line = chips[0]
+    ops, modules = (
+        [(e.name, float(e.start_ns), float(e.duration_ns))
+         for e in by_line[name].events] if name in by_line else []
+        for name in ("XLA Ops", "XLA Modules"))
+    lines.append(f"device time by scope on {chip}")
+    return "\n".join(lines + render_scope_seconds(
+        device_seconds_by_scope(ops, modules, tables), top, depth))
+
+
+def render_scope_seconds(totals: dict, top: int = 15,
+                         depth: int = 4) -> list[str]:
+    lines = []
+    for program in sorted({program for program, _ in totals}):
+        mine = {scope: t for (p, scope), t in totals.items() if p == program}
+        whole = max(sum(mine.values()), 1e-12)
+        lines += ["", f"{program}: {whole:.6f} s in its table's programs, "
+                      f"{100.0 * (1 - mine.get(None, 0.0) / whole):.1f}% "
+                      "under a known instruction"]
+        by_phase: dict = {}
+        by_path: dict = {}
+        for scope, t in mine.items():
+            if scope is None:
+                continue
+            by_phase[scope.phase] = by_phase.get(scope.phase, 0.0) + t
+            key = "/".join(scope.path[:depth]) or "(no name)"
+            by_path[key] = by_path.get(key, 0.0) + t
+        lines.append("  by phase: " + "   ".join(
+            f"{phase} {t:.6f} s ({100.0 * t / whole:.1f}%)"
+            for phase, t in sorted(by_phase.items(), key=lambda kv: -kv[1])))
+        for key, t in sorted(by_path.items(), key=lambda kv: -kv[1])[:top]:
+            lines.append(f"  {t:>12.6f} s{100.0 * t / whole:>7.1f}%  {key}")
+    return lines

@@ -13,7 +13,8 @@ on an exception mid-round):
   reads the profile their parents and attributes.  With neither, nothing
   is kept;
 - the ``jax.profiler`` window (``RunConfig.profile_dir``):
-  :class:`RoundProfiler`.
+  :class:`RoundProfiler`, which leaves the programs' scope tables beside
+  the profile when the call ends.
 
 ``engine.fit`` drives ``before_round``/``after_round``/``end_round``/
 ``close``.  The coordinators, workers and fleetsim hold tracers of their
@@ -43,6 +44,7 @@ class RoundProfiler:
         self.first = first_round
         self.last = first_round + num_rounds - 1
         self._active = False
+        self._scopes_due = False
 
     @property
     def active(self) -> bool:
@@ -66,6 +68,18 @@ class RoundProfiler:
 
             jax.profiler.stop_trace()
             self._active = False
+            self._scopes_due = True
+
+    def write_scopes(self) -> Optional[str]:
+        """Once a window has closed, the programs' scope tables beside its
+        profile (``program_scopes.json``), for ``colearn trace-summary
+        <profile_dir>``.  It compiles or loads each program once more, so
+        it is ``RoundTelemetry.close`` that calls it, after the last
+        round, and never ``after_round``."""
+        if not self._scopes_due:
+            return None
+        self._scopes_due = False
+        return export.write_program_scopes(self.profile_dir)
 
 
 class RoundTelemetry:
@@ -139,6 +153,7 @@ class RoundTelemetry:
         with the call: what the process does between two ``fit()`` calls
         is not kept."""
         self.profiler.close()
+        self.profiler.write_scopes()
         if self.trace_dir and (self._written is None or self.tracer.enabled):
             self.write()
         self.tracer.enabled = False

@@ -26,6 +26,15 @@ answers the production questions the spans cannot:
   ``cost_analysis`` on the AOT-compiled executable (cached per
   signature, so asking twice is free) — the automated replacement for
   the manual lower/compile procedure PERF.md used to prescribe.
+- **Which part of the program is this operation?**  The compiled
+  program's instructions carry ``op_name`` metadata built from what the
+  program wrote: module and method names, ``telemetry.device_scope``s,
+  jax's own wrappers.  :meth:`CompileTracker.scopes` and
+  :func:`program_scopes` read them from the same AOT object into
+  ``{instruction name: Scope(phase, path)}``, on first ask and never
+  before, so that a device trace, which names operations by instruction,
+  can be split by the program's own scopes (``colearn trace-summary``,
+  the benchmark's ``*_ms_per_round`` readers).
 - **Is HBM creeping toward OOM?**  :func:`sample_device_memory` turns
   ``device.memory_stats()`` into live gauges
   (``runtime.hbm_bytes_in_use`` / ``..._limit`` / ``..._peak``).
@@ -49,18 +58,23 @@ import os
 import re
 import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 from colearn_federated_learning_tpu.telemetry.registry import (
     MetricsRegistry,
     get_registry,
 )
+from colearn_federated_learning_tpu.telemetry.tracer import declared_scopes
 
 __all__ = [
     "CompileTracker",
     "EventLog",
     "MetricsExporter",
+    "Scope",
     "compiled_cost",
+    "parse_hlo_text",
+    "parse_op_name",
+    "program_scopes",
     "prometheus_text",
     "sample_device_memory",
     "tracked_call",
@@ -111,6 +125,9 @@ _tracked = threading.local()
 
 
 def _on_cache_event(event: str, **_) -> None:
+    table = getattr(_tracked, "table", None)
+    if table is not None and event == "/jax/compilation_cache/cache_hits":
+        table["loaded"] += 1
     call = getattr(_tracked, "call", None)
     if call is None:
         return                      # not in a call of the program
@@ -122,13 +139,21 @@ def _on_cache_event(event: str, **_) -> None:
         reg.counter("telemetry.cache_miss_total", labels={"fn": fn}).inc()
 
 
+def _on_compile_duration(event: str, duration: float, **_) -> None:
+    table = getattr(_tracked, "table", None)
+    if table is not None and event == (
+            "/jax/core/compile/backend_compile_duration"):
+        table["compiled"] += 1      # an executable was built or loaded
+
+
 @functools.cache
 def _listen_for_cache_events() -> None:
-    """One listener for the life of the process, registered by the first
-    tracked call."""
+    """One pair of listeners for the life of the process, registered by
+    the first tracked call."""
     from jax import monitoring
 
     monitoring.register_event_listener(_on_cache_event)
+    monitoring.register_event_duration_secs_listener(_on_compile_duration)
 
 
 @contextlib.contextmanager
@@ -145,6 +170,202 @@ def tracked_call(fn: str, registry: Optional[MetricsRegistry] = None):
         _tracked.call = previous
 
 
+@contextlib.contextmanager
+def _table_compile():
+    """While the block runs on this thread, no program's cache counters
+    move (a scope table's compile is not the round's), and what jax did
+    for it is counted here: executables built or loaded, and how many of
+    those the persistent cache supplied."""
+    _listen_for_cache_events()
+    previous = (getattr(_tracked, "call", None),
+                getattr(_tracked, "table", None))
+    _tracked.call, _tracked.table = None, {"compiled": 0, "loaded": 0}
+    try:
+        yield _tracked.table
+    finally:
+        _tracked.call, _tracked.table = previous
+
+
+# ------------------------------------------------------------ scope table --
+class Scope(NamedTuple):
+    """Where one instruction of a compiled program lies in the program
+    that was written: ``phase`` is ``forward`` (under ``jvp(``),
+    ``backward`` (under ``transpose(jvp(``), ``remat`` (under
+    ``rematted_computation``, in whichever pass) or ``none``; ``path`` is
+    the program's own names, outermost first."""
+
+    phase: str
+    path: tuple[str, ...]
+
+
+NO_SCOPE = Scope("none", ())
+_WRAPPED = re.compile(r"^([\w.\-]*)\((.*)\)$")
+# Names jax itself puts into an op_name: control flow, calls, checkpoints.
+_JAX_NAMES = re.compile(
+    r"^(while|body|cond|closed_call|core_call|checkpoint|remat|"
+    r"rematted_computation|pjit|jit|scan|shard_map|custom_jvp_call|"
+    r"custom_vjp_call|custom_vjp_call_jaxpr|custom_lin|branch_\d+_fun)$")
+
+
+def parse_op_name(op_name: str) -> Scope:
+    """``jit(round_fn)/local/vmap()/while/body/closed_call/transpose(jvp(
+    NemotronH))/jvp(NemotronH)/checkpoint/rematted_computation/layer_3/moe/
+    mixer/mixer.routed_latent/while/body/closed_call/gather`` ->
+    ``Scope("remat", ("local", "NemotronH", "layer_3", "moe", "mixer",
+    "mixer.routed_latent"))``.
+
+    Dropped: jax's own names (``_JAX_NAMES``), the transformations around
+    a name (``jvp(X)`` is ``X`` in the forward pass; an empty ``vmap()``
+    is nothing), a jitted helper's function name (``jit(_where)``), the
+    primitive at the end, and a name that repeats the one before it
+    (``transpose(jvp(M))/jvp(M)``).  An ``op_name`` XLA made itself
+    (``reduce_sum``, ``copy``) has no path."""
+    # Where XLA merged two operations it joined their names with ";".
+    op_name = op_name.split(";", 1)[0]
+    parts = op_name.split("/")
+    path: list[str] = []
+    primitive_kept = False
+    for part in parts:
+        name = part
+        while (wrapped := _WRAPPED.match(name)) is not None:
+            name = "" if wrapped.group(1) in ("jit", "pjit") else (
+                wrapped.group(2))
+        kept = (bool(name) and _JAX_NAMES.match(name) is None
+                and path[-1:] != [name])
+        if kept:
+            path.append(name)
+        primitive_kept = kept and name == part
+    if primitive_kept:
+        path.pop()                  # the last part, bare: the primitive
+    if "rematted_computation" in parts:
+        phase = "remat"
+    elif "transpose(" in op_name:
+        phase = "backward"
+    elif "jvp(" in op_name:
+        phase = "forward"
+    else:
+        phase = "none"
+    return Scope(phase, tuple(path))
+
+
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# The computations an instruction runs: a loop's body, a fusion's, ...
+_CALLED = re.compile(
+    r"\b(?:body|condition|calls|to_apply|true_computation|"
+    r"false_computation|branch_computations|called_computations)="
+    r"(?:%?([\w.\-]+)|\{([^}]*)\})")
+# A name's location is followed by its call site, a file's by a line.
+_LOCATION = re.compile(r'loc\("([^"]*)"\(')
+
+
+def parse_hlo_text(text: str) -> tuple[str, dict[str, Scope]]:
+    """A compiled program's text (``Compiled.as_text()``: optimized HLO)
+    as its module name and ``{instruction name: Scope}``, every
+    computation's instructions alike (names are unique in a module).  A
+    fusion has the one ``op_name`` XLA gave it, whatever it fused.  An
+    instruction whose own ``op_name`` gives no path (XLA made it: a
+    ``ragged-dot`` custom call, a ``sort``, a ``copy``; or it has none) is
+    filed under the instruction that runs its computation, the nearest
+    with a path: the ``while`` of the loop whose body holds it.  What is
+    left with ``NO_SCOPE`` lies in no named loop."""
+    found = _MODULE.match(text)
+    table: dict[str, Scope] = {}
+    parsed: dict[str, Scope] = {"": NO_SCOPE}   # a few thousand distinct
+    home: dict[str, str] = {}           # instruction -> its computation
+    runs: dict[str, str] = {}           # computation -> what runs it
+    computation = ""
+    for line in text.splitlines():
+        instruction = _INSTRUCTION.match(line)
+        if instruction is None:
+            header = _COMPUTATION.match(line)
+            if header is not None:
+                computation = header.group(1)
+            continue
+        name = instruction.group(1)
+        op_name = _OP_NAME.search(line)
+        op_name = op_name.group(1) if op_name else ""
+        if op_name not in parsed:
+            parsed[op_name] = parse_op_name(op_name)
+        table[name] = parsed[op_name]
+        home[name] = computation
+        for one, several in _CALLED.findall(line):
+            for called in (one or several).replace("%", "").split(","):
+                runs.setdefault(called.strip(), name)
+    for name, scope in table.items():
+        runner = name
+        while not scope.path and (runner := runs.get(home[runner])):
+            scope = table[runner]
+        if scope.path:
+            table[name] = scope
+    return (found.group(1) if found else ""), table
+
+
+def _names(scopes) -> set[str]:
+    """Every name on the path of any of ``scopes``."""
+    return {name for scope in set(scopes) for name in scope.path}
+
+
+@contextlib.contextmanager
+def _metadata_in_cache_key():
+    """jax's persistent cache strips debug information from its key
+    (``jax/_src/cache_key.py``), so a program that differs from an older
+    checkout's in scopes alone loads that checkout's executable, names
+    and all.  Inside this block the key holds them.  Process-wide, like
+    every ``jax.config.update``: a compile another thread makes meanwhile
+    is keyed likewise, which costs it a miss at worst."""
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    previous = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, previous)
+
+
+# A compiler option at its default: with any option ``Lowered.compile``
+# goes to the compiler (and its persistent cache) and not to jax's memo of
+# executables, which holds the one that ran.
+_COMPILE_ANEW = {"xla_dump_hlo_as_text": False}
+
+# The newest tracker called under each name, for ``program_scopes``.
+_programs: dict[str, "CompileTracker"] = {}
+
+
+def _abstract(leaf):
+    """An argument as ``lower`` takes it in an array's place: shape, dtype,
+    weak type and, where the array was committed to one (an uncommitted
+    array lowers as unspecified), its sharding.  ``None`` and Python
+    scalars are leaves jax keeps as they are."""
+    import jax
+
+    if isinstance(leaf, jax.Array):
+        return jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, weak_type=leaf.weak_type,
+            sharding=leaf.sharding if leaf.committed else None)
+    if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+    return leaf
+
+
+def program_scopes() -> dict[str, dict[str, Scope]]:
+    """``{module name: {instruction name: Scope}}`` of every tracked
+    program that has run in this process, under the names a device trace's
+    ``XLA Modules`` line gives them (``jit_round_fn``, ``jit_body``,
+    ``jit_eval_fn``).  Built on first ask (:meth:`CompileTracker.scopes`);
+    nothing on ``fit()``'s path asks."""
+    tables = {}
+    for tracker in list(_programs.values()):
+        module, table = tracker._scope_table()
+        if table:
+            tables[module] = table
+    return tables
+
+
 class CompileTracker:
     """Transparent wrapper around a (jitted) callable that counts the
     distinct call signatures it has seen.
@@ -155,6 +376,13 @@ class CompileTracker:
     number of distinct signatures, plus every executable jit built for
     a signature it had already seen — the executable count a correct
     static-shape pipeline holds at exactly 1 per sweep shape.
+
+    From its first call the tracker keeps that call's arguments in the
+    abstract (no array) and stands under its name in a process-wide map,
+    one entry a name, until the next tracker of that name is called: so
+    :func:`program_scopes` finds the program after its learner is gone,
+    and the jitted function, what it closes over and its executables stay
+    alive until then.
     """
 
     def __init__(self, fn, name: str,
@@ -166,7 +394,9 @@ class CompileTracker:
         self._sig_set: set = set()
         self._jit_entries = 0
         self._placement_recompiles = 0
-        self._cost_cache: dict = {}
+        self._first_call: Optional[tuple] = None   # (args, kwargs), abstract
+        self._aot_cache: dict = {}      # signature -> (lowered, compiled, s)
+        self._scope_tables: dict = {}   # signature -> (module name, table)
         self._lock = threading.Lock()
 
     # -- introspection --------------------------------------------------
@@ -213,6 +443,12 @@ class CompileTracker:
     # -- call surface ---------------------------------------------------
     def __call__(self, *args, **kwargs):
         sig = abstract_signature(args, kwargs)
+        if self._first_call is None:
+            import jax
+
+            # Before the call: it may donate its arguments.
+            self._first_call = jax.tree.map(_abstract, (args, kwargs))
+            _programs[self.name] = self
         with tracked_call(self.name, self._registry):
             t0 = time.perf_counter()
             out = self._fn(*args, **kwargs)
@@ -227,20 +463,106 @@ class CompileTracker:
     def __getattr__(self, attr):
         return getattr(self._fn, attr)
 
-    # -- cost analysis --------------------------------------------------
+    # -- the executable, ahead of time ----------------------------------
+    def _aot(self, args: tuple, kwargs: dict) -> tuple:
+        """``(lowered, compiled, seconds)`` for this signature: lowered and
+        compiled once, whoever asks (cost analysis, scope table)."""
+        sig = abstract_signature(args, kwargs)
+        with self._lock:
+            cached = self._aot_cache.get(sig)
+        if cached is None:
+            cached = _lower_and_compile(self._fn, args, kwargs)
+            with self._lock:
+                self._aot_cache[sig] = cached
+        return cached
+
     def cost_analysis(self, *args, **kwargs) -> dict:
         """XLA ``cost_analysis`` of the executable for THIS signature
         (AOT lower+compile; cached per signature so repeated asks are
         free).  Returns ``{}`` when the wrapped fn has no ``lower``."""
+        if not hasattr(self._fn, "lower"):
+            return {}
+        _, compiled, seconds = self._aot(args, kwargs)
+        return _cost_of(compiled, seconds)
+
+    def scopes(self) -> dict[str, Scope]:
+        """``{instruction name: Scope}`` of the program the first call
+        compiled, from the text of its executable; ``{}`` before any call
+        or where the wrapped fn has no ``lower``.  Built on first ask and
+        kept per signature.
+
+        Of this source, whatever the compile cache holds: the text must
+        show every ``declared_scopes()`` name the lowered program entered.
+        An executable that does not (the persistent cache supplied one an
+        older checkout built from the same program under other names) is
+        compiled once more with the metadata in the cache key.  Stale
+        module names after a rename that leaves the declared set as it was
+        are not caught.  That second executable is another object than the
+        one that runs, compiled from the same program: instruction names
+        agree as far as XLA's passes are deterministic.
+
+        Not the round's compile: it runs outside this tracker's
+        ``__call__`` and every ``tracked_call``, so ``telemetry.
+        compile_total`` and ``cache_miss_total`` of this ``fn`` stay as
+        they were.  Counted on its own: ``telemetry.scope_table_total{fn,
+        how=loaded|built}`` (built: the compiler ran), ``telemetry.
+        scope_table_seconds{fn}``, gauges ``telemetry.
+        scope_table_instructions{fn}`` and ``..._unnamed{fn}``."""
+        return self._scope_table()[1]
+
+    def _scope_table(self) -> tuple[str, dict[str, Scope]]:
+        if self._first_call is None or not hasattr(self._fn, "lower"):
+            return "", {}
+        args, kwargs = self._first_call
         sig = abstract_signature(args, kwargs)
         with self._lock:
-            cached = self._cost_cache.get(sig)
+            cached = self._scope_tables.get(sig)
         if cached is not None:
-            return dict(cached)
-        cost = compiled_cost(self._fn, *args, **kwargs)
+            return cached
+        t0 = time.perf_counter()
+        with _table_compile() as did:
+            lowered, compiled, _ = self._aot(args, kwargs)
+            module, table = parse_hlo_text(compiled.as_text())
+            entered = declared_scopes() & _names(map(parse_op_name, set(
+                _LOCATION.findall(lowered.as_text(debug_info=True)))))
+            if entered - _names(table.values()):
+                with _metadata_in_cache_key():
+                    _, compiled, _ = _lower_and_compile(
+                        self._fn, args, kwargs, _COMPILE_ANEW)
+                module, table = parse_hlo_text(compiled.as_text())
+        labels = {"fn": self.name}
+        reg = self._reg()
+        reg.counter("telemetry.scope_table_total", labels={
+            **labels,
+            "how": "built" if did["compiled"] > did["loaded"] else "loaded",
+        }).inc()
+        reg.counter("telemetry.scope_table_seconds", labels=labels).inc(
+            time.perf_counter() - t0)
+        reg.gauge("telemetry.scope_table_instructions", labels=labels).set(
+            len(table))
+        reg.gauge("telemetry.scope_table_unnamed", labels=labels).set(
+            sum(not scope.path for scope in table.values()))
         with self._lock:
-            self._cost_cache[sig] = cost
-        return dict(cost)
+            self._scope_tables[sig] = (module, table)
+        return module, table
+
+
+def _lower_and_compile(fn, args: tuple, kwargs: dict,
+                       compiler_options: Optional[dict] = None) -> tuple:
+    """The one AOT path: ``(lowered, compiled, seconds)``."""
+    t0 = time.perf_counter()
+    lowered = fn.lower(*args, **kwargs)
+    compiled = lowered.compile(compiler_options)
+    return lowered, compiled, time.perf_counter() - t0
+
+
+def _cost_of(compiled, compile_s: float) -> dict:
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else (cost or {})
+    out = {k: float(v) for k, v in cost.items()
+           if isinstance(v, (int, float))}
+    out["compile_s"] = compile_s
+    return out
 
 
 def compiled_cost(fn, *args, **kwargs) -> dict:
@@ -251,15 +573,8 @@ def compiled_cost(fn, *args, **kwargs) -> dict:
     by the trip count themselves."""
     if not hasattr(fn, "lower"):
         return {}
-    t0 = time.perf_counter()
-    compiled = fn.lower(*args, **kwargs).compile()
-    compile_s = time.perf_counter() - t0
-    cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else (cost or {})
-    out = {k: float(v) for k, v in cost.items()
-           if isinstance(v, (int, float))}
-    out["compile_s"] = compile_s
-    return out
+    _, compiled, seconds = _lower_and_compile(fn, args, kwargs)
+    return _cost_of(compiled, seconds)
 
 
 # ------------------------------------------------------------ HBM gauges --

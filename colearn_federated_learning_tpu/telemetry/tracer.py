@@ -261,6 +261,28 @@ class Tracer:
             self.dropped = 0
 
 
+_declared_scopes: set[str] = set()
+
+
+def device_scope(name: str):
+    """``jax.named_scope(name)``, for the parts of a compiled program whose
+    device time has a reader: the name goes into the ``op_name`` of every
+    operation traced under it (debug information only: the lowered text
+    and the default compile-cache key are what they were), and into
+    ``declared_scopes()``, which the scope table of ``telemetry/runtime.py``
+    checks an executable's names against.  Trace time only: a running
+    program never passes here.  The vocabulary is in README, Observability."""
+    import jax
+
+    _declared_scopes.add(name)
+    return jax.named_scope(name)
+
+
+def declared_scopes() -> frozenset[str]:
+    """Every name a ``device_scope`` was entered under in this process."""
+    return frozenset(_declared_scopes)
+
+
 _default_tracer = Tracer(process="main", enabled=False)
 
 
