@@ -204,6 +204,10 @@ GAUGES = (
     "moe.dispatch_rows",
     "moe.row_tile",
     "moe.pair_sort_keys",
+    # models/nemotron_h.py, models/xing4.py, set on every build: the share
+    # layer's names (models/moe.py SHARE_RESIDUAL_NAMES) whose arrays a
+    # rematerialised layer keeps for its backward (0: ``remat`` is off)
+    "moe.remat_saved_arrays",
     # models/xing4.py, set at trace time on every build: layers of the
     # stack, labeled {kind=dense|moe}, and sequential prediction modules
     "xing4.layers",

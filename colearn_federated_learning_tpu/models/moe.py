@@ -35,9 +35,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.custom_batching import sequential_vmap
 
 from colearn_federated_learning_tpu import telemetry
+from colearn_federated_learning_tpu.ops.attention import FLASH_RESIDUAL_NAMES
 
 
 class MoEFfn(nn.Module):
@@ -288,8 +290,10 @@ def routed_rows(tile: int, experts, latent, weight, banks, token, held,
     """``visit_row_tiles``' answer, differentiable in ``latent``, ``weight``
     and the banks (a tuple): a loop with a trip count on the device has no
     reverse-mode rule of its own.  The backward is handed the arguments
-    and nothing else; ``vmap`` of a gradient meets the two rules below,
-    each a client at a time, and no primitive's own."""
+    and nothing else, so it never needs the loop's output: a rematerialised
+    layer that keeps the arguments and the output (``SHARE_RESIDUAL_NAMES``)
+    runs the backward's loop alone.  ``vmap`` of a gradient meets the two
+    rules below, each a client at a time, and no primitive's own."""
     return _routed_rows_fwd(tile, experts, latent, weight, banks, token,
                             held, sizes)[0]
 
@@ -389,6 +393,71 @@ def held_pairs(chosen, weights, first: int, count: int):
             jnp.sum(member, axis=-1, dtype=jnp.int32))
 
 
+def _pairs_of_blocks(experts_held, tile: int, chosen, weights):
+    token, weight, held, sizes = lax.map(
+        lambda pairs: held_pairs(*pairs, *experts_held), (chosen, weights))
+    # Whole tiles; what is added holds no pair.
+    token, weight, held = (
+        jnp.pad(a, ((0, 0), (0, -a.shape[1] % tile)))
+        for a in (token, weight, held))
+    return token, weight, held, sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def block_pairs(experts_held, tile: int, chosen, weights):
+    """``held_pairs`` of every block, ``chosen``/``weights`` (B, T, k), the
+    rows padded to whole tiles of ``tile``.  Differentiable in ``weights``
+    by ``held_pairs``' own rules; it is a rule of its own for the names'
+    sake: a checkpoint policy sees the names at a block's top level and not
+    those inside ``lax.map``, so the map's pull-back is taken whole
+    (``jax.vjp``) and the arrays it holds, the sorted keys among them, are
+    named beside the four results."""
+    return _pairs_of_blocks(experts_held, tile, chosen, weights)
+
+
+def _block_pairs_fwd(experts_held, tile, chosen, weights):
+    rows, pull = jax.vjp(functools.partial(
+        _pairs_of_blocks, experts_held, tile, chosen), weights)
+    rows = tuple(checkpoint_name(a, name) for a, name in zip(rows, (
+        "moe_row_token", "moe_row_weight", "moe_row_held",
+        "moe_group_sizes")))
+    return rows, jax.tree.map(
+        lambda a: checkpoint_name(a, "moe_pairs_pullback"), pull)
+
+
+def _block_pairs_bwd(experts_held, tile, pull, g):
+    return (None, *pull(g))
+
+
+block_pairs.defvjp(_block_pairs_fwd, _block_pairs_bwd)
+
+
+# What a share layer's backward and the layer around it read of its forward:
+# names a ``jax.checkpoint`` policy may ask for (``save_only_these_names``,
+# as ``ops/attention.py`` ``FLASH_RESIDUAL_NAMES``).  A rematerialised layer
+# that keeps them runs neither the router's product, nor the choice, nor the
+# pairs' sort, nor the loop over the row tiles a second time.  Under any
+# other policy, and outside a checkpoint, a name is the identity.
+SHARE_RESIDUAL_NAMES = (
+    "moe_logits", "moe_chosen", "moe_picked",            # route
+    "moe_row_token", "moe_row_weight", "moe_row_held",   # block_pairs
+    "moe_group_sizes", "moe_pairs_pullback",
+    "moe_routed_rows")                                   # routed
+
+
+def remat_but_for_named(block_cls, remat: bool):
+    """``block_cls``, a layer that may hold a share layer, made again in its
+    backward pass if ``remat``, but for the flash kernel's named results and
+    the share layer's.  The gauge is set at trace time, on every build."""
+    telemetry.get_registry().gauge("moe.remat_saved_arrays").set(
+        len(SHARE_RESIDUAL_NAMES) if remat else 0)
+    if not remat:
+        return block_cls
+    return nn.remat(
+        block_cls, policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUAL_NAMES, *SHARE_RESIDUAL_NAMES))
+
+
 class ExpertShare(nn.Module):
     """What the shares of a sigmoid-routed mixture have in common, whatever
     their experts compute: the router over all the experts, the rows of
@@ -423,6 +492,14 @@ class ExpertShare(nn.Module):
     the device, so the blocks' loops are one ``jax.custom_vjp``
     (``routed_rows``): its backward is handed the forward's arguments and
     runs the same loops, a tile's forward made again inside them.
+
+    Under ``nn.remat`` the layer names what its backward and the layer
+    around it read (``SHARE_RESIDUAL_NAMES``): the router's product, the
+    choice and the chosen scores, the pairs' rows with their pull-back, and
+    the routed rows.  A policy that keeps those names leaves the
+    rematerialised layer the norm, the sigmoid and the maps around the
+    experts; the routing and the tile loop run once forward and once
+    backward.
     """
 
     def setup_router(self):
@@ -444,11 +521,15 @@ class ExpertShare(nn.Module):
         """``u32``: (N, D) float32.  The chosen experts (N, top_k) and
         their weights (N, top_k) float32."""
         with telemetry.device_scope("moe.route"):
-            scores = nn.sigmoid(jnp.dot(
+            logits = checkpoint_name(jnp.dot(
                 u32, self.router.astype(jnp.float32),
-                precision=lax.Precision.HIGHEST))
-            _, chosen = lax.top_k(scores + self.router_bias, self.top_k)
-            picked = picked_scores(scores, chosen)
+                precision=lax.Precision.HIGHEST), "moe_logits")
+            scores = nn.sigmoid(logits)
+            chosen = checkpoint_name(
+                lax.top_k(scores + self.router_bias, self.top_k)[1],
+                "moe_chosen")
+            picked = checkpoint_name(picked_scores(scores, chosen),
+                                     "moe_picked")
             weights = (self.routed_scale * picked
                        / picked.sum(-1, keepdims=True))
         return chosen, weights
@@ -479,17 +560,14 @@ class ExpertShare(nn.Module):
             return a.reshape(tokens // block, block, *a.shape[1:])
 
         with telemetry.device_scope("moe.pairs"):
-            token, weight, held, sizes = lax.map(
-                lambda pairs: held_pairs(*pairs, *self.experts_held),
-                (blocks(chosen), blocks(weights)))
-            # Whole tiles; what is added holds no pair.
-            token, weight, held = (
-                jnp.pad(a, ((0, 0), (0, -a.shape[1] % tile)))
-                for a in (token, weight, held))
+            token, weight, held, sizes = block_pairs(
+                tuple(self.experts_held), tile, blocks(chosen),
+                blocks(weights))
         with telemetry.device_scope("moe.tiles"):
             out = routed_rows(tile, experts, blocks(rows_in), weight, banks,
                               token, held, sizes)
-            return out.astype(self.dtype).reshape(tokens, -1)
+            return checkpoint_name(out.astype(self.dtype),
+                                   "moe_routed_rows").reshape(tokens, -1)
 
 
 class LatentMoEShare(ExpertShare):

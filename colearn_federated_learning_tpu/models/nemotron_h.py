@@ -44,8 +44,10 @@ import jax.numpy as jnp
 
 from colearn_federated_learning_tpu import telemetry
 from colearn_federated_learning_tpu.models.attention import MultiHeadAttention
-from colearn_federated_learning_tpu.models.moe import LatentMoEShare
-from colearn_federated_learning_tpu.ops.attention import FLASH_RESIDUAL_NAMES
+from colearn_federated_learning_tpu.models.moe import (
+    LatentMoEShare,
+    remat_but_for_named,
+)
 from colearn_federated_learning_tpu.ops.ssd import ssd_scan
 
 RMS_NORM_EPS = 1e-5
@@ -204,7 +206,9 @@ class NemotronH(nn.Module):
     dtype: jnp.dtype = jnp.float32
     attn_impl: str = "flash"
     # Rematerialize each layer under autodiff, but for the attention
-    # kernel's output and log-sum (models/evabyte.py does the same).
+    # kernel's output and log-sum (models/evabyte.py does the same) and for
+    # what the share layer names: its routing, its pairs' rows, its routed
+    # rows (models/moe.py SHARE_RESIDUAL_NAMES).
     remat: bool = False
 
     def _mixer(self, kind: str) -> dict:
@@ -241,12 +245,7 @@ class NemotronH(nn.Module):
         h = nn.Embed(self.vocab_size, self.embed_dim, dtype=self.dtype,
                      embedding_init=nn.initializers.normal(INIT_STD),
                      name="embed")(ids)
-        block_cls = HybridBlock
-        if self.remat:
-            block_cls = nn.remat(
-                HybridBlock,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *FLASH_RESIDUAL_NAMES))
+        block_cls = remat_but_for_named(HybridBlock, self.remat)
         out_scale = len(kinds) ** -0.5
         for i, kind in enumerate(kinds):
             # Explicit names pin param paths across remat (models/bert.py).

@@ -34,14 +34,16 @@ values (``models/mhc.py``); rotary pairs ``(i, i + d / 2)``.
 from __future__ import annotations
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from colearn_federated_learning_tpu import telemetry
 from colearn_federated_learning_tpu.models import mhc
 from colearn_federated_learning_tpu.models.mla import LatentAttention, rms_norm
-from colearn_federated_learning_tpu.models.moe import GatedMoEShare, gated
-from colearn_federated_learning_tpu.ops.attention import FLASH_RESIDUAL_NAMES
+from colearn_federated_learning_tpu.models.moe import (
+    GatedMoEShare,
+    gated,
+    remat_but_for_named,
+)
 
 INIT_STD = 0.02
 LAYER_KINDS = ("dense", "moe")
@@ -155,16 +157,13 @@ class Xing4(nn.Module):
     dtype: jnp.dtype = jnp.float32
     attn_impl: str = "flash"
     # Rematerialize each layer under autodiff, but for the attention
-    # kernel's output and log-sum (models/evabyte.py does the same).
+    # kernel's output and log-sum (models/evabyte.py does the same) and for
+    # what the share layer names: its routing, its pairs' rows, its routed
+    # rows (models/moe.py SHARE_RESIDUAL_NAMES).
     remat: bool = False
 
     def _block(self, kind: str, name: str):
-        block_cls = Xing4Block
-        if self.remat:
-            block_cls = nn.remat(
-                Xing4Block,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *FLASH_RESIDUAL_NAMES))
+        block_cls = remat_but_for_named(Xing4Block, self.remat)
         ffn = dict(hidden_dim=self.ffn_dim) if kind == "dense" else dict(
             embed_dim=self.embed_dim, expert_dim=self.expert_dim,
             shared_dim=self.shared_dim, experts_total=self.experts_total,
