@@ -204,6 +204,10 @@ GAUGES = (
     "moe.dispatch_rows",
     "moe.row_tile",
     "moe.pair_sort_keys",
+    # the groups the experts lie in and how many of them a token's choice
+    # is made within (1 and 1: the choice is over all the experts)
+    "moe.groups",
+    "moe.groups_kept",
     # models/nemotron_h.py, models/xing4.py, set on every build: the share
     # layer's names (models/moe.py SHARE_RESIDUAL_NAMES) whose arrays a
     # rematerialised layer keeps for its backward (0: ``remat`` is off)
@@ -212,6 +216,17 @@ GAUGES = (
     # stack, labeled {kind=dense|moe}, and sequential prediction modules
     "xing4.layers",
     "mtp.modules",
+    # models/ling3.py, set at trace time on every build: layers of the
+    # stack, labeled {kind=kda|mla|dense|moe}; the delta-rule mixers: how
+    # many layers have one, their heads, the positions a chunk of the rule
+    # holds (ops/kda.py), and the rule's names (KDA_RESIDUAL_NAMES) whose
+    # arrays a rematerialised layer keeps for its backward (0: ``remat`` is
+    # off)
+    "ling3.layers",
+    "kda.layers",
+    "kda.heads",
+    "kda.chunk",
+    "kda.remat_saved_arrays",
     # models/mhc.py: rows of a token's residual stream, and the Sinkhorn
     # iterations that make their mixing map doubly stochastic
     "mhc.streams",

@@ -65,6 +65,10 @@ SPECS: dict[str, DatasetSpec] = {
                           vocab_size=16_384),
     "tokens_tiny": DatasetSpec("tokens_tiny", "tokens", (64,), 96, 64, 8,
                                vocab_size=96),
+    # The same stream at 4,096 positions over an eighth of a 157,184-word
+    # vocabulary (models/ling3.py).
+    "tokens_4k": DatasetSpec("tokens_4k", "tokens", (4_096,), 19_648, 128, 4,
+                             vocab_size=19_648),
     # The same stream for a model with sequential prediction modules
     # (models/xing4.py): the labels of a position are the ``horizon``
     # tokens after it, one and each module's (the shipped model has none).

@@ -10,6 +10,13 @@ d_r`` and values of ``d_v``::
     scores       = ([q_n | rot(q_r)] . [k_n | rot(k_r)]) * scale
     out          = softmax_causal(scores) v W_o
 
+Three variations a caller may ask for.  ``q_rank`` 0: no query rank, ``[q_n |
+q_r] = u W_q``.  ``qk_norm``: before the rotation, an RMSNorm with a weight
+over each head's ``d_n + d_r`` of ``[q_n | q_r]`` and of ``[k_n | k_r]`` (the
+shared ``k_r`` then differs from head to head by its head's norm).
+``head_gate``: a head's output times ``sigmoid(u W_gate)``, one gate a head,
+before ``W_o``.
+
 ``rot`` turns pairs ``(x_i, x_{i + d_r / 2})`` by the position times yarn's
 frequencies (``yarn_frequencies``); under yarn ``scale = (d_n + d_r) **
 -0.5 * (0.1 mscale_all_dim ln factor + 1) ** 2``, and cos and sin are not
@@ -104,6 +111,8 @@ class LatentAttention(nn.Module):
     impl: str = "flash"
     init_std: float = 0.02
     out_scale: float = 1.0
+    qk_norm: bool = False
+    head_gate: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -129,8 +138,11 @@ class LatentAttention(nn.Module):
             scale = self.param(name, nn.initializers.ones, (x.shape[-1],))
             return rms_norm(x, scale, self.norm_eps).astype(self.dtype)
 
-        q = dense(H * (d_n + d_r), "q_b")(
-            norm(dense(self.q_rank, "q_a")(u), "q_norm"))
+        if self.q_rank:
+            q = dense(H * (d_n + d_r), "q_b")(
+                norm(dense(self.q_rank, "q_a")(u), "q_norm"))
+        else:
+            q = dense(H * (d_n + d_r), "q")(u)
         q = q.reshape(B, L, H, d_n + d_r)
         kv_a = dense(self.kv_rank + d_r, "kv_a")(u)
         kv = dense(H * (d_n + d_v), "kv_b")(
@@ -140,14 +152,27 @@ class LatentAttention(nn.Module):
         factor, original_max, fast, slow, mscale_all = self.yarn
         frequencies = yarn_frequencies(
             d_r, self.rope_theta, factor, original_max, fast, slow)
-        q_r = rotate(q[..., d_n:], frequencies)
-        k_r = rotate(kv_a[..., None, self.kv_rank:], frequencies)
-        q = jnp.concatenate([q[..., :d_n], q_r], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :d_n], jnp.broadcast_to(k_r, (B, L, H, d_r))], axis=-1)
+        if self.qk_norm:
+            k = jnp.concatenate([kv[..., :d_n], jnp.broadcast_to(
+                kv_a[..., None, self.kv_rank:], (B, L, H, d_r))], axis=-1)
+            q, k = norm(q, "q_head_norm"), norm(k, "k_head_norm")
+            q = jnp.concatenate(
+                [q[..., :d_n], rotate(q[..., d_n:], frequencies)], axis=-1)
+            k = jnp.concatenate(
+                [k[..., :d_n], rotate(k[..., d_n:], frequencies)], axis=-1)
+        else:
+            q_r = rotate(q[..., d_n:], frequencies)
+            k_r = rotate(kv_a[..., None, self.kv_rank:], frequencies)
+            q = jnp.concatenate([q[..., :d_n], q_r], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :d_n], jnp.broadcast_to(k_r, (B, L, H, d_r))],
+                axis=-1)
         v = kv[..., d_n:]
         scale = (d_n + d_r) ** -0.5 * yarn_scale(factor, mscale_all)
         core = flash_attention if self.impl == "flash" else dense_attention
         out = core(q, k, v, causal=True, scale=scale)
+        if self.head_gate:
+            gate = nn.sigmoid(dense(H, "gate")(u).astype(jnp.float32))
+            out = (out * gate[..., None]).astype(self.dtype)
         return dense(C, "out", self.init_std * self.out_scale)(
             out.reshape(B, L, H * d_v))

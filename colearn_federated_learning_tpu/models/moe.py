@@ -141,10 +141,17 @@ def relu2(x):
     return jnp.square(nn.relu(x))
 
 
-def gated(u, w_gate, w_up, w_down, product=jnp.dot):
+def gated(u, w_gate, w_up, w_down, product=jnp.dot, limit: float = 0.0):
     """``W_d (silu(W_g u) * W_u u)``; ``product`` multiplies rows by a
-    matrix."""
-    return product(nn.silu(product(u, w_gate)) * product(u, w_up), w_down)
+    matrix.  With a ``limit`` c > 0 the gate's pre-activation is clipped
+    above at c and the up-projection to [-c, c]."""
+    gate = product(u, w_gate)
+    if limit > 0:
+        gate = jnp.minimum(gate, limit)
+    hidden, up = nn.silu(gate), product(u, w_up)
+    if limit > 0:
+        up = jnp.clip(up, -limit, limit)
+    return product(hidden * up, w_down)
 
 
 def tile_sizes(sizes, start, rows: int):
@@ -167,12 +174,21 @@ def relu2_experts(rows, sizes, w1, w2):
                           preferred_element_type=rows.dtype)
 
 
-def gated_experts(rows, sizes, w_gate, w_up, w_down):
+def gated_experts(rows, sizes, w_gate, w_up, w_down, limit: float = 0.0):
     """``W_d (silu(W_g u) * W_u u)`` for rows sorted by expert: three banks
-    (G, C, F), (G, C, F), (G, F, C) in the stream's own width."""
+    (G, C, F), (G, C, F), (G, F, C) in the stream's own width; ``limit`` as
+    ``gated`` takes it."""
     return gated(rows, w_gate, w_up, w_down, product=lambda a, bank:
                  lax.ragged_dot(a, bank, sizes,
-                                preferred_element_type=rows.dtype))
+                                preferred_element_type=rows.dtype),
+                 limit=limit)
+
+
+@functools.lru_cache(maxsize=None)
+def gated_experts_within(limit: float):
+    """``gated_experts`` under a clamp, one function a limit (it is a static
+    argument of ``routed_rows``)."""
+    return functools.partial(gated_experts, limit=limit)
 
 
 def _tile(experts, acc, latent, weight, banks, token, held, sizes):
@@ -445,17 +461,18 @@ SHARE_RESIDUAL_NAMES = (
     "moe_routed_rows")                                   # routed
 
 
-def remat_but_for_named(block_cls, remat: bool):
+def remat_but_for_named(block_cls, remat: bool, more_names=()):
     """``block_cls``, a layer that may hold a share layer, made again in its
-    backward pass if ``remat``, but for the flash kernel's named results and
-    the share layer's.  The gauge is set at trace time, on every build."""
+    backward pass if ``remat``, but for the flash kernel's named results,
+    the share layer's and ``more_names`` (another mixer's, ``ops/kda.py``).
+    The gauge is set at trace time, on every build."""
     telemetry.get_registry().gauge("moe.remat_saved_arrays").set(
         len(SHARE_RESIDUAL_NAMES) if remat else 0)
     if not remat:
         return block_cls
     return nn.remat(
         block_cls, policy=jax.checkpoint_policies.save_only_these_names(
-            *FLASH_RESIDUAL_NAMES, *SHARE_RESIDUAL_NAMES))
+            *FLASH_RESIDUAL_NAMES, *SHARE_RESIDUAL_NAMES, *more_names))
 
 
 class ExpertShare(nn.Module):
@@ -464,12 +481,14 @@ class ExpertShare(nn.Module):
     the held ones, the loop over their tiles.  A subclass declares the
     sizes (``embed_dim``, ``experts_total``, ``experts_held``, ``top_k``,
     ``routed_scale``, ``token_block``, ``row_tile``, ``dtype``,
-    ``init_std``) and, in ``setup``, calls ``setup_router``.
+    ``init_std``, ``n_group``, ``topk_group``) and, in ``setup``, calls
+    ``setup_router``.
 
     The router scores all ``experts_total`` experts in float32, ``s =
     sigmoid(u W_r)``; a token's ``top_k`` experts are the largest of ``s +
     b`` (``b``, the correction bias, enters the choice alone, so its
-    gradient is 0) and weigh ``w_e = scale * s_e / sum over all chosen of
+    gradient is 0), with ``n_group`` > 1 among the experts of its
+    ``topk_group`` best groups (``within_kept_groups``), and weigh ``w_e = scale * s_e / sum over all chosen of
     s`` (``norm_topk``), held here or not.  This chip holds the experts
     ``experts_held = (first, count)``.
 
@@ -511,11 +530,33 @@ class ExpertShare(nn.Module):
                 f"{self.experts_total} experts")
         if self.top_k > self.experts_total:
             raise ValueError(f"top_k {self.top_k} of {self.experts_total}")
+        per_group, left = divmod(self.experts_total, self.n_group)
+        if (left or not 1 <= self.topk_group <= self.n_group
+                or (self.n_group > 1 and per_group < 2)
+                or self.top_k > self.topk_group * per_group):
+            raise ValueError(
+                f"{self.top_k} of {self.experts_total} experts cannot be "
+                f"chosen within {self.topk_group} of {self.n_group} groups")
         self.router = self.param(
             "router", nn.initializers.normal(self.init_std),
             (self.embed_dim, self.experts_total))
         self.router_bias = self.param(
             "router_bias", nn.initializers.zeros, (self.experts_total,))
+
+    def within_kept_groups(self, biased):
+        """``biased`` (N, experts_total), the scores the choice is made on,
+        with minus infinity outside a token's ``topk_group`` best groups of
+        ``n_group``: the experts are ``n_group`` groups one after another,
+        and a group's score is the sum of its two largest (DeepSeek-V3's
+        ``noaux_tc``).  One group: as it came."""
+        if self.n_group == 1:
+            return biased
+        grouped = biased.reshape(biased.shape[0], self.n_group, -1)
+        group_scores = lax.top_k(grouped, 2)[0].sum(-1)       # (N, n_group)
+        best = lax.top_k(group_scores, self.topk_group)[1]
+        kept = (best[..., None] == jnp.arange(self.n_group)).any(-2)
+        return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
+            biased.shape)
 
     def route(self, u32):
         """``u32``: (N, D) float32.  The chosen experts (N, top_k) and
@@ -526,7 +567,8 @@ class ExpertShare(nn.Module):
                 precision=lax.Precision.HIGHEST), "moe_logits")
             scores = nn.sigmoid(logits)
             chosen = checkpoint_name(
-                lax.top_k(scores + self.router_bias, self.top_k)[1],
+                lax.top_k(self.within_kept_groups(
+                    scores + self.router_bias), self.top_k)[1],
                 "moe_chosen")
             picked = checkpoint_name(picked_scores(scores, chosen),
                                      "moe_picked")
@@ -550,6 +592,8 @@ class ExpertShare(nn.Module):
         registry.gauge("moe.experts_held").set(count)
         registry.gauge("moe.experts_total").set(self.experts_total)
         registry.gauge("moe.top_k").set(self.top_k)
+        registry.gauge("moe.groups").set(self.n_group)
+        registry.gauge("moe.groups_kept").set(self.topk_group)
         bound = min(self.top_k, count)
         tile = min(self.row_tile, block * bound)
         registry.gauge("moe.dispatch_rows").set(tokens * bound)
@@ -598,6 +642,8 @@ class LatentMoEShare(ExpertShare):
     dtype: jnp.dtype = jnp.float32
     init_std: float = 0.02
     out_scale: float = 1.0          # on the maps back into the stream
+    n_group: int = 1                # the choice within topk_group of them
+    topk_group: int = 1
 
     def setup(self):
         self.setup_router()
@@ -662,6 +708,12 @@ class GatedMoEShare(ExpertShare):
     dtype: jnp.dtype = jnp.float32
     init_std: float = 0.02
     out_scale: float = 1.0          # on the maps back into the stream
+    n_group: int = 1                # the choice within topk_group of them
+    topk_group: int = 1
+    # Clamps on the experts' and the shared expert's gate and up-projection
+    # (``gated``); 0: none.
+    expert_limit: float = 0.0
+    shared_limit: float = 0.0
 
     def setup(self):
         self.setup_router()
@@ -680,15 +732,17 @@ class GatedMoEShare(ExpertShare):
     def routed_part(self, u, u32):
         """``sum over e chosen and held of w_e E_e(u)``, (N, D): the part
         of the layer that differs from share to share."""
+        experts = (gated_experts_within(self.expert_limit)
+                   if self.expert_limit > 0 else gated_experts)
         return self.routed(
-            gated_experts,
-            (self.experts_gate, self.experts_up, self.experts_down),
+            experts, (self.experts_gate, self.experts_up, self.experts_down),
             *self.route(u32), u)
 
     def shared(self, u):
         with telemetry.device_scope("moe.shared"):
             return gated(u, *(w.astype(self.dtype) for w in (
-                self.shared_gate, self.shared_up, self.shared_down)))
+                self.shared_gate, self.shared_up, self.shared_down)),
+                limit=self.shared_limit)
 
     def __call__(self, u32):
         """``u32``: (..., D), the normed stream in float32.  Returns (...,

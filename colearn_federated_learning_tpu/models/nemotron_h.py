@@ -73,7 +73,7 @@ class RMSNorm(nn.Module):
         return rms(x) * g
 
 
-def _dt_bias_init(key, shape, dtype=jnp.float32):
+def dt_bias_init(key, shape, dtype=jnp.float32):
     low, high, floor = TIME_STEP
     dt = jnp.exp(jax.random.uniform(key, shape, dtype)
                  * (math.log(high) - math.log(low)) + math.log(low))
@@ -81,7 +81,7 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))             # softplus^-1(dt)
 
 
-def _a_log_init(key, shape, dtype=jnp.float32):
+def a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
@@ -130,8 +130,8 @@ class Mamba2Mixer(nn.Module):
         x, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
         x = x.reshape(B, L, H, P)
 
-        dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
-        a_log = self.param("A_log", _a_log_init, (H,))
+        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
+        a_log = self.param("A_log", a_log_init, (H,))
         skip = self.param("D", nn.initializers.ones, (H,))
         dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
         with telemetry.device_scope("ssd"):

@@ -11,7 +11,7 @@ from colearn_federated_learning_tpu.utils.config import ModelConfig
 # Families whose blocks can be rematerialised, and those of them that run
 # inside ``shard_map`` on a shard of the sequence.
 REMAT_FAMILIES = ("bert", "moe_bert", "vit_b16", "evabyte", "nemotron_h",
-                  "xing4")
+                  "xing4", "ling3")
 SEQ_PARALLEL_FAMILIES = ("bert", "moe_bert")
 
 
@@ -146,6 +146,34 @@ def build_model(cfg: ModelConfig, seq_axis_name: str | None = None):
             shared_dim=cfg.shared_expert_dim, routed_scale=cfg.routed_scale,
             mtp_modules=cfg.mtp_modules, norm_eps=cfg.norm_eps, dtype=dtype,
             attn_impl=cfg.attn_impl, remat=cfg.remat)
+    if cfg.name == "ling3":
+        from colearn_federated_learning_tpu.models.ling3 import Ling3
+        from colearn_federated_learning_tpu.models.mla import MLA_IMPLS
+
+        if cfg.attn_impl not in MLA_IMPLS:
+            raise ValueError(
+                f"ling3's attention runs as {MLA_IMPLS} on one device, not "
+                f"{cfg.attn_impl!r}")
+        return Ling3(
+            vocab_size=cfg.vocab_size, embed_dim=cfg.width, depth=cfg.depth,
+            first_layer=cfg.first_layer,
+            layer_group_size=cfg.layer_group_size,
+            dense_layers=cfg.dense_layers, num_heads=cfg.num_heads,
+            head_dim=cfg.head_dim or cfg.width // cfg.num_heads,
+            conv_kernel=cfg.conv_kernel, chunk=cfg.chunk_size,
+            lower_bound=cfg.kda_lower_bound, kv_rank=cfg.kv_rank,
+            nope_dim=cfg.nope_dim, rope_dim=cfg.rope_dim, v_dim=cfg.v_dim,
+            rope_theta=cfg.rope_theta, ffn_dim=cfg.ffn_dim,
+            experts_total=cfg.num_experts,
+            experts_held=(cfg.experts_first, cfg.experts_held),
+            top_k=cfg.experts_per_token, n_group=cfg.expert_groups,
+            topk_group=cfg.expert_groups_kept, expert_dim=cfg.expert_dim,
+            shared_dim=cfg.shared_expert_dim, routed_scale=cfg.routed_scale,
+            token_block=cfg.moe_token_block, row_tile=cfg.moe_row_tile,
+            expert_limits=tuple(cfg.expert_limits),
+            shared_limits=tuple(cfg.shared_expert_limits),
+            norm_eps=cfg.norm_eps, dtype=dtype, attn_impl=cfg.attn_impl,
+            remat=cfg.remat)
     raise KeyError(f"unknown model {cfg.name!r}")
 
 

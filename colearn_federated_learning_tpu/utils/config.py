@@ -262,6 +262,29 @@ class ModelConfig:
     yarn_mscale_all_dim: float = 1.0
     mtp_modules: int = 0
     norm_eps: float = 1e-6
+    # Hybrid of Kimi delta attention and latent attention with gated
+    # experts (models/ling3.py): ``depth`` layers held from the published
+    # layer ``first_layer`` on; layer i of the published stack mixes by
+    # latent attention where (i + 1) % ``layer_group_size`` is 0 (no query
+    # rank; ``kv_rank``, ``nope_dim``, ``rope_dim``, ``v_dim``,
+    # ``rope_theta`` as above) and by the delta rule elsewhere
+    # (``num_heads`` heads of ``head_dim``, ``conv_kernel``, ``chunk_size``
+    # positions a chunk, a log-decay a channel above ``kda_lower_bound``).
+    # The experts are chosen within ``expert_groups_kept`` of
+    # ``expert_groups`` groups; the share layer takes its tokens a
+    # ``moe_token_block`` and its rows a ``moe_row_tile`` at a time;
+    # ``expert_limits`` / ``shared_expert_limits``: a held layer's clamps
+    # on its experts' and its shared expert's gate and up-projection
+    # (empty: none).
+    first_layer: int = 0
+    layer_group_size: int = 6
+    kda_lower_bound: float = -5.0
+    expert_groups: int = 1
+    expert_groups_kept: int = 1
+    moe_token_block: int = 4096
+    moe_row_tile: int = 4096
+    expert_limits: tuple = ()
+    shared_expert_limits: tuple = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -593,6 +616,33 @@ CONFIGS["xing4_fedavg"] = _cfg(
     fed=FedConfig(strategy="fedavg", rounds=20, cohort_size=1,
                   local_steps=2, batch_size=1, lr=0.003, momentum=0.0),
     run=RunConfig(name="xing4_fedavg", eval_every=2),
+)
+
+
+# A hybrid linear-attention / latent-attention sparse language model:
+# Ling-3.0-flash's language model at its published widths as one chip's
+# share of a stated deployment (published layers 1-7 of 42: one dense layer,
+# one whole period of five delta-rule layers to one of latent attention; 8
+# of 512 experts chosen within 4 of 8 groups, an eighth of the vocabulary:
+# PERF.md section 4).  One example is one sequence of 4,096 tokens with the
+# next token as each position's label (dataset ``tokens_4k``).
+CONFIGS["ling3_fedavg"] = _cfg(
+    data=DataConfig(dataset="tokens_4k", num_clients=8, partition="iid"),
+    model=ModelConfig(name="ling3", num_classes=19648, vocab_size=19648,
+                      width=2560, depth=7, first_layer=1, layer_group_size=6,
+                      dense_layers=1, num_heads=32, head_dim=128,
+                      seq_len=4096, conv_kernel=4, chunk_size=64,
+                      kda_lower_bound=-5.0, kv_rank=512, nope_dim=128,
+                      rope_dim=64, v_dim=128, rope_theta=6e6, ffn_dim=6144,
+                      num_experts=512, experts_per_token=8, expert_groups=8,
+                      expert_groups_kept=4, expert_dim=768,
+                      shared_expert_dim=768, routed_scale=2.5,
+                      moe_token_block=2048, moe_row_tile=1024,
+                      dtype="bfloat16",
+                      attn_impl="flash", remat=True),
+    fed=FedConfig(strategy="fedavg", rounds=20, cohort_size=1,
+                  local_steps=2, batch_size=1, lr=0.01, momentum=0.0),
+    run=RunConfig(name="ling3_fedavg", eval_every=2),
 )
 
 

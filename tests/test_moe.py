@@ -134,3 +134,120 @@ def test_moe_expert_parallel_matches_single_device(cpu_devices):
     p2 = np.concatenate([np.ravel(np.asarray(a))
                          for a in jax.tree.leaves(ref.server_state.params)])
     np.testing.assert_allclose(p1, p2, atol=2e-6)
+
+
+# --- the share layer's group-limited choice (models/moe.py ExpertShare) -----
+
+
+def _gated_share(total, groups, kept, top_k, first=0, count=4, **kw):
+    from colearn_federated_learning_tpu.models.moe import GatedMoEShare
+
+    return GatedMoEShare(
+        embed_dim=32, expert_dim=24, shared_dim=40, experts_total=total,
+        experts_held=(first, count), top_k=top_k, routed_scale=2.5,
+        init_std=0.3, row_tile=16, n_group=groups, topk_group=kept, **kw)
+
+
+def _route_model(total, groups, kept, top_k, first=0):
+    return dict(experts_per_token=top_k, routed_scale=2.5,
+                experts_first=first, expert_groups=groups,
+                expert_groups_kept=kept)
+
+
+@pytest.mark.parametrize("total,groups,kept,top_k", [
+    (16, 4, 2, 4), (64, 8, 4, 8), (64, 8, 1, 8), (24, 3, 3, 5)])
+@pytest.mark.parametrize("biased", [False, True], ids=["no_bias", "bias"])
+def test_group_limited_choice_is_the_sorted_one(total, groups, kept, top_k,
+                                                biased):
+    """``route`` against the reference's choice by sorting: a group's score
+    is the sum of its two largest ``s + b``, the best groups are kept, the
+    experts are the largest ``s + b`` within them; the weights are of the
+    scores without the bias.  Every choice lies in a kept group, and with
+    every group kept the choice is the plain one."""
+    from benchmarks.reference import ling3 as reference
+
+    layer = _gated_share(total, groups, kept, top_k)
+    u = jax.random.normal(jax.random.PRNGKey(0), (96, 32))
+    params = layer.init(jax.random.PRNGKey(1), u)["params"]
+    if biased:
+        params["router_bias"] = 0.2 * jax.random.normal(
+            jax.random.PRNGKey(2), (total,))
+    chosen, weights = layer.apply({"params": params}, u, method="route")
+    want, want_w = reference.route(
+        u, params, _route_model(total, groups, kept, top_k))
+    order, want_order = np.argsort(chosen, -1), np.argsort(want, -1)
+    np.testing.assert_array_equal(
+        np.take_along_axis(np.asarray(chosen), order, -1),
+        np.take_along_axis(np.asarray(want), want_order, -1))
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(weights), order, -1),
+        np.take_along_axis(np.asarray(want_w), want_order, -1), rtol=1e-6)
+    np.testing.assert_allclose(weights.sum(-1), 2.5, rtol=1e-6)
+    per_group = total // groups
+    assert (np.array([len(set(row)) for row in np.asarray(chosen)
+                      // per_group]) <= kept).all()
+    if kept == groups:
+        plain = _gated_share(total, 1, 1, top_k).apply(
+            {"params": params}, u, method="route")[0]
+        np.testing.assert_array_equal(np.sort(chosen, -1), np.sort(plain, -1))
+    else:
+        # The limit binds: the plain choice would have left the groups.
+        plain = _gated_share(total, 1, 1, top_k).apply(
+            {"params": params}, u, method="route")[0]
+        assert (np.sort(chosen, -1) != np.sort(plain, -1)).any()
+
+
+def test_one_group_routes_as_before():
+    """``n_group`` 1 passes the scores on as they came: the lowered router
+    holds the one ``top_k`` it held, and the grouped one three."""
+    u = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
+
+    def lowered(layer):
+        params = layer.init(jax.random.PRNGKey(1), u)["params"]
+        return jax.jit(lambda p, u: layer.apply(
+            {"params": p}, u, method="route")).lower(params, u).as_text()
+
+    plain, stated, grouped = (lowered(_gated_share(16, *groups, 4))
+                              for groups in ((1, 1), (1, 1), (4, 2)))
+    assert plain == stated
+    assert plain.count("top_k") < grouped.count("top_k")
+    assert "0xFF800000" not in plain and "0xFF800000" in grouped   # -inf
+    layer = _gated_share(16, 1, 1, 4)
+    scores = jnp.arange(32.0).reshape(2, 16)
+    assert layer.within_kept_groups(scores) is scores
+
+
+@pytest.mark.parametrize("total,groups,kept,top_k,count", [
+    (64, 8, 4, 8, 4), (16, 4, 2, 4, 4)], ids=["16_shares", "4_shares"])
+def test_the_shares_add_up_under_the_group_limited_choice(total, groups, kept,
+                                                          top_k, count):
+    """The program's layer, built as one chip's share and given that
+    share's slice of an uncut layer's banks, once for each share (in the
+    first case a group spans two shares, as the deployment's spans eight
+    chips): the routed parts summed, with what every chip computes alike
+    (the router, the shared expert) counted once, are the uncut reference's
+    layer, and every (token, choice) pair lies on exactly one share."""
+    from benchmarks.reference import ling3 as reference
+    from colearn_federated_learning_tpu.models import moe
+
+    u = jax.random.normal(jax.random.PRNGKey(0), (48, 32))
+    sizes = {"router": (32, total), "router_bias": (total,),
+             "experts_gate": (total, 32, 24), "experts_up": (total, 32, 24),
+             "experts_down": (total, 24, 32), "shared_gate": (32, 40),
+             "shared_up": (32, 40), "shared_down": (40, 32)}
+    keys = jax.random.split(jax.random.PRNGKey(1), len(sizes))
+    p = {name: 0.4 * jax.random.normal(k, shape)
+         for k, (name, shape) in zip(keys, sizes.items())}
+    want = reference.moe(u, p, _route_model(total, groups, kept, top_k), 0)
+    parts, pairs = [], 0
+    for first in range(0, total, count):
+        layer = _gated_share(total, groups, kept, top_k, first, count)
+        own = dict(p, **{name: p[name][first:first + count] for name in (
+            "experts_gate", "experts_up", "experts_down")})
+        parts.append(layer.apply({"params": own}, u, u, method="routed_part"))
+        chosen, weights = layer.apply({"params": own}, u, method="route")
+        pairs += int(moe.held_pairs(chosen, weights, first, count)[3].sum())
+    assert pairs == 48 * top_k
+    shared = layer.apply({"params": own}, u, method="shared")
+    got = sum(parts) + shared
+    assert float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want)) < 1e-5
