@@ -158,6 +158,10 @@ COUNTERS = (
     # ops/attention.py: flash kernels traced, labeled
     # {mode=mosaic|interpret} — which of the two lowerings a run used
     "ops.flash_trace_total",
+    # ops/kda.py: the delta rule's kernels traced (forward and backward
+    # each), labeled {mode=mosaic|interpret} — a chip run that traced the
+    # interpreter is a fault
+    "ops.kda_trace_total",
     # flash calls traced with a prefix of keys every causal query sees
     "attention.flash_prefix_calls",
     "flight.dumps_total",            # flight-recorder dump writes

@@ -1,8 +1,11 @@
-"""The chunked gated delta rule (ops/kda.py) against the recurrence one
-position at a time: values and gradients in every argument, lengths that
-are no multiple of the chunk, every gate at its bound (where the decays of
-a pair, split over a whole chunk, are no float32), chunk sizes alike, and
-the names a rematerialised layer may keep."""
+"""The gated delta rule's kernels (ops/kda.py, interpreted off the TPU)
+against the recurrence one position at a time: values and gradients in
+every argument, lengths that are no multiple of the chunk, every gate at
+its bound (where the decays of a pair, split over a whole chunk, are no
+float32), chunk sizes alike, and the names a rematerialised layer may keep
+so that neither kernel runs again."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -130,10 +133,16 @@ def test_a_chunk_is_whole_sub_blocks():
         kda.kda_chunked(*inputs(6, 16), chunk=12, sub_block=8)
 
 
-def test_a_policy_that_keeps_the_names_runs_the_scan_once():
-    """Under ``save_only_these_names`` the rematerialised forward holds no
-    loop over the chunks: the states and pseudo-values are kept, and what
-    is made again needs neither."""
+def _kernels(jaxpr) -> list[str]:
+    """The names of the Pallas kernels a jaxpr's text calls."""
+    return re.findall(r"\bname=(kda_(?:fwd|bwd))\b", str(jaxpr))
+
+
+def test_a_policy_that_keeps_the_names_runs_each_kernel_once():
+    """Under ``save_only_these_names`` the rematerialised forward runs no
+    rule kernel: the states, the pseudo-values and the output are kept, so
+    the gradient holds one forward kernel and one backward; keeping nothing
+    runs the forward kernel again.  The gradients are the same."""
     args = inputs(7, 32)
 
     def loss(policy):
@@ -145,12 +154,22 @@ def test_a_policy_that_keeps_the_names_runs_the_scan_once():
     keep = jax.checkpoint_policies.save_only_these_names(
         *kda.KDA_RESIDUAL_NAMES)
     nothing = jax.checkpoint_policies.nothing_saveable
-    whiles = {
-        name: str(jax.make_jaxpr(jax.grad(loss(policy), argnums=range(5)))(
-            *args)).count("scan[")
+    kernels = {
+        name: sorted(_kernels(jax.make_jaxpr(
+            jax.grad(loss(policy), argnums=range(5)))(*args)))
         for name, policy in (("keep", keep), ("nothing", nothing))}
-    assert whiles["keep"] < whiles["nothing"]
+    assert kernels["keep"] == ["kda_bwd", "kda_fwd"]
+    assert kernels["nothing"] == ["kda_bwd", "kda_fwd", "kda_fwd"]
     got = jax.grad(loss(keep), argnums=range(5))(*args)
     want = jax.grad(loss(nothing), argnums=range(5))(*args)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def test_the_primal_call_writes_the_output_alone():
+    """Not differentiated, as in the evaluation program, the rule is one
+    forward kernel with one result: no state or pseudo-value is written."""
+    text = str(jax.make_jaxpr(
+        lambda *a: kda.kda_chunked(*a, chunk=8, sub_block=4))(*inputs(8, 16)))
+    assert _kernels(text) == ["kda_fwd"]
+    assert "kda_states" not in text and "kda_pseudo_values" not in text

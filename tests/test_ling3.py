@@ -6,6 +6,7 @@ clamp, the model against the reference with and without rematerialisation,
 its gauges, and ``fit()``."""
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -328,7 +329,7 @@ def test_model_matches_the_plain_reference(impl, remat, limits):
 
 def test_remat_on_and_off_alike():
     """The same loss and gradients, and a rematerialised layer keeps the
-    rule's two names beside the share layer's nine."""
+    rule's three names beside the share layer's nine."""
     answers = []
     for remat in (False, True):
         model, params, ids, y, _ = _model_and_batch(remat=remat)
@@ -344,6 +345,18 @@ def test_remat_on_and_off_alike():
     np.testing.assert_allclose(loss, loss_r, rtol=1e-6)
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_r)):
         assert not np.asarray(b).any() or _rel(a, b) < 1e-3
+
+
+def test_a_rematerialised_layer_runs_each_rule_kernel_once():
+    """With ``remat`` the gradient holds one forward and one backward rule
+    kernel a delta-rule layer (six of seven here): a layer keeps the rule's
+    states, pseudo-values and output, so what it makes again runs none."""
+    model, params, ids, y, _ = _model_and_batch(remat=True)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: losses.softmax_cross_entropy(
+            model.apply({"params": p}, ids, train=True), y)))(params))
+    kernels = re.findall(r"\bname=(kda_(?:fwd|bwd))\b", text)
+    assert kernels.count("kda_fwd") == kernels.count("kda_bwd") == 6
 
 
 def test_model_logits_and_the_references():
@@ -380,6 +393,16 @@ def test_gauges_say_what_was_built():
                  "kda.remat_saved_arrays", "moe.groups", "moe.groups_kept",
                  "ling3.layers"):
         assert name in metric_catalog.GAUGES, name
+    assert "ops.kda_trace_total" in metric_catalog.COUNTERS
+    # Rematerialised, a layer keeps the rule's states, pseudo-values and
+    # output; off the TPU the rule's kernels were interpreted, never built
+    # for Mosaic.
+    before = got.get("ops.kda_trace_total{mode=interpret}", 0)
+    _model_and_batch(remat=True)
+    got = _snapshot()
+    assert got["kda.remat_saved_arrays"] == 3 == len(kda.KDA_RESIDUAL_NAMES)
+    assert got["ops.kda_trace_total{mode=interpret}"] > before
+    assert "ops.kda_trace_total{mode=mosaic}" not in got
 
 
 def test_registry_guards_name_the_family():
