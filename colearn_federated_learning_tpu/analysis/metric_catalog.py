@@ -162,6 +162,9 @@ COUNTERS = (
     # each), labeled {mode=mosaic|interpret} — a chip run that traced the
     # interpreter is a fault
     "ops.kda_trace_total",
+    # ops/kda_conv.py: the short convolution's kernels traced (forward and
+    # backward each), labeled {mode=mosaic|interpret}
+    "ops.kda_conv_trace_total",
     # flash calls traced with a prefix of keys every causal query sees
     "attention.flash_prefix_calls",
     "flight.dumps_total",            # flight-recorder dump writes

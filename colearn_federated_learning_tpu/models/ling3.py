@@ -35,8 +35,9 @@ positions, without a bias; ``g`` is a log-decay a channel in (``lower_bound``,
 0); ``beta`` one a head; the norm is over a head's ``d`` with one weight
 shared by the heads; the output gate is a channel's.  The five maps of ``u``
 are one product (``in_proj``: q, k, v, f, the gate, beta) whose float32 sums
-the convolution, the norms and the gates read unrounded.  The rule runs chunk
-by chunk (``ops/kda.py``).
+the convolution, the norms and the gates read unrounded.  The convolution,
+its SiLU and the norms of q and k are one kernel each way
+(``ops/kda_conv.py``); the rule runs chunk by chunk (``ops/kda.py``).
 
 **Latent attention** (``models/mla.py``): no query rank, an RMSNorm over
 each head's scores' width of q and of k before plain rotary on the last
@@ -72,6 +73,7 @@ from colearn_federated_learning_tpu.ops.kda import (
     KDA_RESIDUAL_NAMES,
     kda_chunked,
 )
+from colearn_federated_learning_tpu.ops.kda_conv import conv_qkv
 
 INIT_STD = 0.02
 L2_EPS = 1e-6
@@ -81,52 +83,6 @@ FFN_KINDS = ("dense", "moe")
 
 def mixer_kind(published_index: int, layer_group_size: int) -> str:
     return "mla" if (published_index + 1) % layer_group_size == 0 else "kda"
-
-
-def unit(x):
-    """``x / |x|_2`` over the last axis, float32."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(
-        jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
-
-
-def _taps_over(x, taps, before: int, after: int, offsets):
-    """``sum_j pad(x)[offsets[j] : offsets[j] + L] * taps[j]`` over positions
-    (axis 1) padded with ``before`` and ``after`` zeros: one fused pass."""
-    length = x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (before, after), (0, 0)))
-    return sum(padded[:, o:o + length] * taps[j]
-               for j, o in enumerate(offsets))
-
-
-@jax.custom_vjp
-def causal_conv(x, taps):
-    """The causal depthwise convolution ``y_t = sum_j taps[j] x_{t - (K - 1)
-    + j}``: ``x`` (B, L, C), ``taps`` (K, C).  A rule of its own for the
-    backward's form: ``dx_t = sum_j taps[j] g_{t + (K - 1) - j}`` is the
-    same sum of shifted slices looking ahead, one fused pass, where the
-    transpose of the forward's slices is a padded array added to K times
-    (19 ms a layer and round of a 948 ms round on the v5e, PERF.md section
-    6, PR 39)."""
-    k = taps.shape[0]
-    return _taps_over(x, taps, k - 1, 0, range(k))
-
-
-def _causal_conv_fwd(x, taps):
-    return causal_conv(x, taps), (x, taps)
-
-
-def _causal_conv_bwd(kept, g):
-    x, taps = kept
-    k, length = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    d_taps = jnp.stack([jnp.sum(padded[:, j:j + length] * g, axis=(0, 1))
-                        for j in range(k)])
-    return (_taps_over(g, taps, 0, k - 1, range(k - 1, -1, -1)),
-            d_taps.astype(taps.dtype))
-
-
-causal_conv.defvjp(_causal_conv_fwd, _causal_conv_bwd)
 
 
 class KdaMixer(nn.Module):
@@ -155,8 +111,6 @@ class KdaMixer(nn.Module):
                             (D, 5 * inner + H))
         projected = jnp.dot(u, kernel.astype(self.dtype),
                             preferred_element_type=jnp.float32)
-        qkv, f, gate, beta = jnp.split(
-            projected, [3 * inner, 4 * inner, 5 * inner], axis=-1)
 
         with telemetry.device_scope("kda.conv"):
             bound = self.conv_kernel ** -0.5
@@ -165,11 +119,10 @@ class KdaMixer(nn.Module):
                 lambda key, shape: jax.random.uniform(
                     key, shape, jnp.float32, -bound, bound),
                 (self.conv_kernel, 3 * inner))
-            qkv = nn.silu(causal_conv(qkv, taps))
-            q, k, v = (a.reshape(B, L, H, d)
-                       for a in jnp.split(qkv, 3, axis=-1))
-            q = (unit(q) * d ** -0.5).astype(self.dtype)
-            k, v = unit(k).astype(self.dtype), v.astype(self.dtype)
+            q, k, v, rest = conv_qkv(projected, taps, heads=H, head_dim=d,
+                                     eps=L2_EPS, dtype=self.dtype)
+            q, k, v = (a.reshape(B, L, H, d) for a in (q, k, v))
+        f, gate, beta = jnp.split(rest, [inner, 2 * inner], axis=-1)
 
         with telemetry.device_scope("kda.gate"):
             dt_bias = self.param("dt_bias", dt_bias_init, (inner,))

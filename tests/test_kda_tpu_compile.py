@@ -1,5 +1,6 @@
-"""The gated delta rule's kernels (ops/kda.py) compiled for a described TPU
-v5e at the cell's widths, forward and backward, with no chip attached:
+"""The gated delta rule's kernels (ops/kda.py) and the short convolution's
+(ops/kda_conv.py) compiled for a described TPU v5e at the cell's widths,
+forward and backward, with no chip attached:
 Mosaic refuses here what it would refuse there (an alignment, too much
 VMEM, a product it cannot lower), which interpret mode cannot show.
 Skipped where no v5e can be described.
@@ -8,6 +9,8 @@ The topology is described inside a fixture, after this file's tests have
 started, and in this file alone: a process that loads the TPU's library
 keeps it until it exits.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,15 +32,16 @@ def one_chip():
 
 @pytest.fixture
 def as_on_the_chip(monkeypatch):
-    """``ops/kda.py`` asks jax for its backend, which here is the CPU: hand
-    it the chip's answer (Mosaic, not the interpreter), and keep what is
-    compiled out of the persistent cache (an executable for an absent chip
-    cannot be read back)."""
+    """``ops/kda.py`` and ``ops/kda_conv.py`` ask jax for its backend, which
+    here is the CPU: hand them the chip's answer (Mosaic, not the
+    interpreter), and keep what is compiled out of the persistent cache (an
+    executable for an absent chip cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    from colearn_federated_learning_tpu.ops import kda
+    from colearn_federated_learning_tpu.ops import kda, kda_conv
 
     monkeypatch.setattr(kda, "_interpret", lambda: False)
+    monkeypatch.setattr(kda_conv, "_interpret", lambda: False)
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -101,3 +105,39 @@ def test_the_evaluations_rule_keeps_nothing(one_chip, as_on_the_chip):
     kernels = _kernels(compiled.as_text())
     assert len(kernels) == 1 and "kda_fwd" in kernels[0]
     assert kernels[0].split(" = ")[1].startswith("bf16[1,4096,4096]")
+
+
+def test_the_convolution_compiles_at_the_cells_widths(one_chip,
+                                                      as_on_the_chip):
+    """The maps' float32 sums of one sequence, (1, 4,096, 20,512), four taps
+    over q's, k's and v's 3 x 32 heads of 128, bf16 q, k and v: value and
+    both gradients, one forward and one backward kernel, and the kernels
+    read q's, k's and v's columns where they lie: no float32 copy of them
+    is sliced out of the sums."""
+    from colearn_federated_learning_tpu import telemetry
+    from colearn_federated_learning_tpu.ops.kda_conv import conv_qkv
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss_and_grads(x, taps):
+        def loss(x, taps):
+            q, k, v, rest = conv_qkv(x, taps, heads=32, head_dim=128,
+                                     eps=1e-6, dtype=jnp.bfloat16)
+            assert q.shape == (1, 4096, 4096) and q.dtype == jnp.bfloat16
+            return sum(jnp.sum(a.astype(jnp.float32) ** 2)
+                       for a in (q, k, v, rest))
+
+        return jax.value_and_grad(loss, argnums=(0, 1))(x, taps)
+
+    counter = telemetry.get_registry().counter(
+        "ops.kda_conv_trace_total", labels={"mode": "mosaic"})
+    traced = counter.value
+    compiled = jax.jit(loss_and_grads).lower(
+        shape(1, 4096, 20512), shape(4, 12288)).compile()
+    assert counter.value - traced == 2
+    text = compiled.as_text()
+    kernels = _kernels(text)
+    for name in ("kda_conv_fwd", "kda_conv_bwd"):
+        assert sum(name in line for line in kernels) == 1, name
+    assert not re.search(r"= f32\[1,4096,12288\]\S* slice\(", text)

@@ -18,6 +18,7 @@ from colearn_federated_learning_tpu import telemetry
 from colearn_federated_learning_tpu.fed import FederatedLearner, losses
 from colearn_federated_learning_tpu.models import ling3, mla, moe, registry
 from colearn_federated_learning_tpu.ops import kda
+from colearn_federated_learning_tpu.ops.kda_conv import conv_qkv
 from colearn_federated_learning_tpu.utils.config import (
     DataConfig,
     ExperimentConfig,
@@ -149,27 +150,44 @@ def test_kda_mixer_is_the_reference_one_position_at_a_time():
 
 
 def test_the_convolution_is_causal_and_its_rule_is_autodiffs():
-    """``causal_conv`` against the written-out taps, answer and both
-    gradients (its backward is a rule of its own), and position ``t`` reads
-    ``t - 3 .. t`` alone."""
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 19, 6))
-    taps = jax.random.normal(jax.random.PRNGKey(1), (4, 6))
-    probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    """The mixer's convolution (``ops/kda_conv.py``, with its SiLU and
+    norms) against the written-out taps, answer and both gradients (its
+    backward is a kernel of its own), and position ``t`` reads ``t - 3 ..
+    t`` alone."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 19, 3 * 16 + 5))
+    taps = jax.random.normal(jax.random.PRNGKey(1), (4, 3 * 16))
+    probe = jax.random.normal(jax.random.PRNGKey(2), (2, 19, 3 * 16))
+
+    def unit(a):
+        return a * jax.lax.rsqrt(
+            jnp.sum(a * a, axis=-1, keepdims=True) + ling3.L2_EPS)
 
     def plain(x, taps):
-        return sum(jnp.pad(x, ((0, 0), (3 - j, 0), (0, 0)))[:, :19] * taps[j]
-                   for j in range(4))
+        s = jax.nn.silu(sum(
+            jnp.pad(x[..., :48], ((0, 0), (3 - j, 0), (0, 0)))[:, :19]
+            * taps[j] for j in range(4)))
+        q, k, v = (a.reshape(2, 19, 2, 8) for a in jnp.split(s, 3, -1))
+        return jnp.concatenate([(unit(q) * 8 ** -0.5).reshape(2, 19, 16),
+                                unit(k).reshape(2, 19, 16),
+                                v.reshape(2, 19, 16)], axis=-1)
 
-    np.testing.assert_allclose(ling3.causal_conv(x, taps), plain(x, taps),
-                               rtol=1e-6, atol=1e-6)
-    got = jax.grad(lambda *a: jnp.sum(ling3.causal_conv(*a) * probe),
+    def conv(x, taps):
+        return jnp.concatenate(conv_qkv(x, taps, heads=2, head_dim=8,
+                                        eps=ling3.L2_EPS,
+                                        dtype=jnp.float32)[:3], axis=-1)
+
+    np.testing.assert_array_equal(conv_qkv(
+        x, taps, heads=2, head_dim=8, eps=ling3.L2_EPS,
+        dtype=jnp.float32)[3], x[..., 48:])
+    np.testing.assert_allclose(conv(x, taps), plain(x, taps),
+                               rtol=1e-5, atol=1e-6)
+    got = jax.grad(lambda *a: jnp.sum(conv(*a) * probe),
                    argnums=(0, 1))(x, taps)
     want = jax.grad(lambda *a: jnp.sum(plain(*a) * probe),
                     argnums=(0, 1))(x, taps)
     for a, b in zip(got, want):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-    moved = ling3.causal_conv(x.at[:, 10].add(1.0), taps) - ling3.causal_conv(
-        x, taps)
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    moved = conv(x.at[:, 10].add(1.0), taps) - conv(x, taps)
     assert not np.asarray(moved[:, :10]).any()
     assert not np.asarray(moved[:, 14:]).any()
     assert np.asarray(moved[:, 10:14]).all()
@@ -359,6 +377,20 @@ def test_a_rematerialised_layer_runs_each_rule_kernel_once():
     assert kernels.count("kda_fwd") == kernels.count("kda_bwd") == 6
 
 
+def test_a_rematerialised_layer_runs_the_convolution_forward_twice():
+    """With ``remat`` the gradient holds, a delta-rule layer, the fused
+    convolution's forward kernel in the forward pass and again where the
+    layer is made again (the rule reads q, k and v, which are not kept),
+    and one backward kernel."""
+    model, params, ids, y, _ = _model_and_batch(remat=True)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: losses.softmax_cross_entropy(
+            model.apply({"params": p}, ids, train=True), y)))(params))
+    kernels = re.findall(r"\bname=(kda_conv_(?:fwd|bwd))\b", text)
+    assert kernels.count("kda_conv_fwd") == 12
+    assert kernels.count("kda_conv_bwd") == 6
+
+
 def test_model_logits_and_the_references():
     model, params, ids, _, config = _model_and_batch(**LIMITS)
     logits = model.apply({"params": params}, ids)
@@ -393,16 +425,25 @@ def test_gauges_say_what_was_built():
                  "kda.remat_saved_arrays", "moe.groups", "moe.groups_kept",
                  "ling3.layers"):
         assert name in metric_catalog.GAUGES, name
-    assert "ops.kda_trace_total" in metric_catalog.COUNTERS
+    for name in ("ops.kda_trace_total", "ops.kda_conv_trace_total"):
+        assert name in metric_catalog.COUNTERS, name
     # Rematerialised, a layer keeps the rule's states, pseudo-values and
-    # output; off the TPU the rule's kernels were interpreted, never built
-    # for Mosaic.
-    before = got.get("ops.kda_trace_total{mode=interpret}", 0)
+    # output; off the TPU the rule's and the convolution's kernels were
+    # interpreted, never built for Mosaic (counted before and after the
+    # build: a test of another file in this process may have compiled them
+    # for a described chip).
+    counts = [f"{name}{{mode={mode}}}"
+              for name in ("ops.kda_trace_total", "ops.kda_conv_trace_total")
+              for mode in ("interpret", "mosaic")]
+    before = {key: got.get(key, 0) for key in counts}
     _model_and_batch(remat=True)
     got = _snapshot()
     assert got["kda.remat_saved_arrays"] == 3 == len(kda.KDA_RESIDUAL_NAMES)
-    assert got["ops.kda_trace_total{mode=interpret}"] > before
-    assert "ops.kda_trace_total{mode=mosaic}" not in got
+    for key, count in before.items():
+        if "interpret" in key:
+            assert got[key] > count, key
+        else:
+            assert got.get(key, 0) == count, key
 
 
 def test_registry_guards_name_the_family():
